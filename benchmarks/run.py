@@ -21,6 +21,8 @@ def main(argv=None) -> None:
                     help="write every lane's detail JSON under DIR "
                          "(default: results/bench or $REPRO_BENCH_OUT)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import init_compile_cache
+    init_compile_cache()
     from . import common
     if args.json_dir:
         common.OUT_DIR = args.json_dir

@@ -47,7 +47,6 @@ import numpy as np
 
 from ..core import CEAZ, CEAZConfig
 from ..io import engine as E
-from ..runtime import compat
 from ..runtime.sharding import ShardingPlan, leaf_sharding
 
 LATEST = "LATEST"
@@ -81,7 +80,7 @@ class CheckpointConfig:
 def _flatten(tree) -> Dict[str, np.ndarray]:
     flat = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        key = compat.keystr(path)
+        key = jax.tree_util.keystr(path, simple=True, separator="/")
         flat[key] = np.asarray(leaf)
     return flat
 
@@ -122,6 +121,23 @@ def _decode_leaf(payload: bytes, meta: Dict, comp: CEAZ) -> np.ndarray:
 
 
 _np_dtype = E._np_dtype            # ml_dtypes-aware dtype resolver
+
+
+def read_manifest(step_dir: str) -> Dict:
+    """The step directory's manifest. An unreadable or malformed one is
+    checkpoint corruption and raises OSError, the one error class the
+    restore paths treat as "try an older step" (StreamCorruptionError is
+    an OSError too)."""
+    path = os.path.join(step_dir, "manifest.json")
+    try:
+        with open(path) as f:
+            manifest = json.load(f)
+    except ValueError as e:                 # not JSON
+        raise OSError(f"bad checkpoint manifest {path}: {e}") from e
+    if not (isinstance(manifest, dict)
+            and {"step", "leaves"} <= set(manifest)):
+        raise OSError(f"bad checkpoint manifest {path}: no step/leaves")
+    return manifest
 
 
 def save_checkpoint(directory: str, state: Any, step: int,
@@ -221,7 +237,9 @@ def restore_checkpoint(directory: str, step: Optional[int] = None,
                        template: Any = None,
                        leaf_transform=None
                        ) -> Optional[Tuple[Any, Dict]]:
-    """Restore (state, meta). Falls back to earlier steps on corruption.
+    """Restore (state, meta). Falls back to earlier steps on corruption
+    (an OSError: a failed stream check, a bad manifest, a read error);
+    any other error, such as a device or compile failure, propagates.
 
     Format-2 leaf streams restore through the engine-fed decode
     pipeline: the prefetch thread reads+deserializes leaf records while
@@ -255,8 +273,7 @@ def restore_checkpoint(directory: str, step: Optional[int] = None,
     for s in reversed(steps):
         d = os.path.join(directory, f"step_{s:08d}")
         try:
-            with open(os.path.join(d, "manifest.json")) as f:
-                manifest = json.load(f)
+            manifest = read_manifest(d)
             flat = {}
             if manifest.get("format", 1) >= 2:
                 stream = os.path.join(d, manifest.get("file",
@@ -276,7 +293,7 @@ def restore_checkpoint(directory: str, step: Optional[int] = None,
             state = _unflatten_like(flat, template)
             return state, {"step": manifest["step"],
                            **manifest.get("extra", {})}
-        except Exception as e:                      # corrupted -> try older
+        except OSError as e:         # corrupted or unreadable -> try older
             print(f"checkpoint {d} unusable ({e}); trying previous")
             continue
     return None

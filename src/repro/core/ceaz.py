@@ -160,9 +160,10 @@ class CEAZConfig:
     speculation: int | str = "auto"
     # Inner-loop implementation for the fused pipeline's two hot loops,
     # resolved through kernels/dispatch.py: 'jnp' (XLA-compiled
-    # jax.numpy), 'pallas' (explicit kernels; interpret=True off-TPU) or
-    # 'auto' (per-backend table: jnp on cpu/gpu, pallas on tpu). An
-    # unknown name raises ValueError at first compress/decompress.
+    # jax.numpy), 'pallas' (explicit kernels; interpreted off-TPU) or
+    # 'auto' (the per-backend table in kernels/dispatch.py: jnp on every
+    # backend today). An unknown name raises ValueError at first
+    # compress/decompress.
     kernel_impl: str = "auto"
     # Decode-side megakernel (kernels/megakernel/decode_kernel.py):
     # 'auto'/'mega' run eligible fused decodes through `ceaz_chunk_dec`

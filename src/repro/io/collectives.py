@@ -23,9 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..kernels.dualquant import ops as dq_ops
 from ..optim.grad_compress import pack_jnp, unpack_jnp
-from ..runtime import compat
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,9 +76,9 @@ def compressed_all_gather(x, mesh: Mesh, axis: str,
         return dec.reshape((-1,) + x_loc.shape)
 
     spec = P(axis, *([None] * (len(shape) - 1)))
-    return compat.shard_map(per_rank, mesh=mesh, in_specs=spec,
-                            out_specs=P(None, axis),
-                            axis_names={axis})(x)
+    return jax.shard_map(per_rank, mesh=mesh, in_specs=spec,
+                         out_specs=P(None, axis),
+                         axis_names={axis})(x)
 
 
 def ceaz_gather(shards, eb_rel: float = 1e-4, plan=None,

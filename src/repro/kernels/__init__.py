@@ -14,8 +14,10 @@ explicit BlockSpec VMEM tiling), ops.py (jit'd public wrapper), ref.py
   megakernel — the bank-mode encode hot path as ONE program per chunk
                (quantize -> histogram -> bank-select -> pack)
 
-All kernels run under interpret=True on CPU (validation) and are written
-with TPU tiling constraints (8x128 f32 / lane-dim multiples of 128).
+Off-TPU every kernel runs in the Pallas interpreter (its `interpret`
+argument defaults to None, resolved by dispatch.resolve_interpret). None
+of them compiles for a TPU yet, so the dispatch auto table names the
+jnp twins there (see the table at the bottom of dispatch.py).
 
 ``dispatch`` is the backend-dispatch registry the fused runtime resolves
 its inner loops through: (op, impl) -> callable with an (op, backend)

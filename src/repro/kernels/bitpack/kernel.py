@@ -11,10 +11,13 @@ is VPU-parallel, unlike variable-length Huffman.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..dispatch import resolve_interpret
 
 LANES = 128
 SUBLANES = 8
@@ -43,7 +46,7 @@ def _unpack_kernel(words_ref, out_ref, *, bits: int):
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
-def pack(vals: jax.Array, bits: int, *, interpret: bool = True) -> jax.Array:
+def pack(vals: jax.Array, bits: int, *, interpret: Optional[bool] = None) -> jax.Array:
     """vals: (n_words, 32//bits, LANES)-collapsible i32 in [0, 2^bits).
 
     Input shape (R, 32//bits, LANES) with R % SUBLANES == 0;
@@ -57,12 +60,12 @@ def pack(vals: jax.Array, bits: int, *, interpret: bool = True) -> jax.Array:
         in_specs=[pl.BlockSpec((SUBLANES, per, LANES), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, LANES), jnp.uint32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(vals)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
-def unpack(words: jax.Array, bits: int, *, interpret: bool = True) -> jax.Array:
+def unpack(words: jax.Array, bits: int, *, interpret: Optional[bool] = None) -> jax.Array:
     """words: (R, LANES) u32 -> (R, 32//bits, LANES) i32."""
     r, lanes = words.shape
     assert lanes == LANES and r % SUBLANES == 0
@@ -73,5 +76,5 @@ def unpack(words: jax.Array, bits: int, *, interpret: bool = True) -> jax.Array:
         in_specs=[pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((SUBLANES, per, LANES), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((r, per, LANES), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(words)

@@ -6,7 +6,7 @@ input shape, which is what the compressed collectives need.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +26,7 @@ def packed_rows(n: int, bits: int) -> int:
     return _layout(n, bits)[0]
 
 
-def pack_flat(x: jax.Array, bits: int, *, interpret: bool = True) -> jax.Array:
+def pack_flat(x: jax.Array, bits: int, *, interpret: Optional[bool] = None) -> jax.Array:
     flat = jnp.asarray(x, jnp.int32).reshape(-1)
     n = flat.shape[0]
     rows, per = _layout(n, bits)
@@ -36,6 +36,6 @@ def pack_flat(x: jax.Array, bits: int, *, interpret: bool = True) -> jax.Array:
 
 
 def unpack_flat(words: jax.Array, n: int, bits: int,
-                *, interpret: bool = True) -> jax.Array:
+                *, interpret: Optional[bool] = None) -> jax.Array:
     vals = K.unpack(words, bits, interpret=interpret)
     return vals.reshape(-1)[:n]

@@ -5,12 +5,13 @@ gather-pack (`hufenc`) and the decode-side canonical-table walk
 (`hufdec`). Each has interchangeable implementations with one calling
 convention and a bit-exact output contract:
 
-  * ``'jnp'``    — pure jax.numpy, XLA-compiled; the default on CPU/GPU
-    where XLA vectorizes the gathers well (and the reference the Pallas
-    sweeps compare against);
+  * ``'jnp'``    — pure jax.numpy, XLA-compiled; what ``'auto'`` runs on
+    every backend today (and the reference the Pallas sweeps compare
+    against);
   * ``'pallas'`` — explicit Pallas kernels (kernels/hufenc gather-pack,
-    kernels/hufdec table decode); compiled on TPU, ``interpret=True``
-    everywhere else so CI exercises the kernel path on CPU.
+    kernels/hufdec table decode); compiled on TPU, interpreted
+    everywhere else so CI exercises the kernel path on CPU. None of
+    them compiles for the TPU yet (see the auto table at the bottom).
 
 Callers never import an implementation directly — they resolve through
 the registry:
@@ -66,7 +67,7 @@ from __future__ import annotations
 import contextlib
 import os
 import time
-from typing import Callable, Dict, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 
@@ -83,6 +84,13 @@ def default_interpret() -> bool:
     through the interpreter). Shared by every */ops.py wrapper so the
     policy cannot drift between ops."""
     return jax.default_backend() != "tpu"
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """A kernel's `interpret` argument: as given, or the backend policy
+    of `default_interpret` when None (every kernel's default, so no
+    kernel runs interpreted on a TPU unless a caller asks for it)."""
+    return default_interpret() if interpret is None else bool(interpret)
 
 _LOADERS: Dict[Tuple[str, str], Callable[[], Callable]] = {}
 _RESOLVED: Dict[Tuple[str, str], Callable] = {}
@@ -261,19 +269,39 @@ def _ceaz_chunk_dec_pallas() -> Callable:
     return ops.ceaz_chunk_dec
 
 
-# auto policy: on CPU and GPU the XLA-compiled jnp path wins (a Pallas
-# kernel would run interpreted there); on TPU the explicit VMEM-resident
-# kernels are the point. GPU-specialized variants (Mosaic-GPU / Triton)
-# slot in as register("hufdec", "pallas_gpu", ..., auto_for=("gpu",)).
-register("hufenc", "jnp", _hufenc_jnp, auto_for=("cpu", "gpu"))
-register("hufenc", "pallas", _hufenc_pallas, auto_for=("tpu",))
-register("hufdec", "jnp", _hufdec_jnp, auto_for=("cpu", "gpu"))
-register("hufdec", "pallas", _hufdec_pallas, auto_for=("tpu",))
-register("dq_center", "jnp", _dq_center_jnp, auto_for=("cpu", "gpu"))
-register("dq_center", "pallas", _dq_center_pallas, auto_for=("tpu",))
-register("ceaz_chunk", "jnp", _ceaz_chunk_jnp, auto_for=("cpu", "gpu"))
-register("ceaz_chunk", "pallas", _ceaz_chunk_pallas, auto_for=("tpu",))
+# auto policy: the XLA-compiled jnp twin on every backend. On CPU and GPU
+# a Pallas kernel would run interpreted. On TPU no Pallas kernel
+# compiles yet. Compiled for a v5e with interpret=False at the main
+# path's shapes (one chunk row of 2^17 and of 2^23 values, block_size
+# 4096, a 12-book bank), Mosaic refuses each one:
+#   hufenc          "The Pallas TPU lowering currently requires that the
+#                   last two dimensions of your block shape are
+#                   divisible by 8 and 128 respectively, or be equal to
+#                   the respective dimensions of the overall array"
+#                   (the (1, 16) block-sum blocks of gather_pack_tiled)
+#   hufdec          the same rule, for the (1, 2^16) decode-table rows
+#   dq_center       "Unimplemented primitive in Pallas TPU lowering for
+#                   KernelType.TC: cumsum" (rows past 2^20 values take
+#                   the jnp sort inside the Pallas wrapper)
+#   ceaz_chunk      2^17: "NotImplementedError: Only float32 is
+#                   supported"; 2^23: the block-shape rule, for the
+#                   (1, Element(32769)) window of lorenzo_tiles
+#   ceaz_chunk_dec  the block-shape rule: the (1, 2^16) table rows at
+#                   2^17, the (1, Element(16387)) word window of
+#                   hufdec_tiles at 2^23
+# A kernel returns to auto_for=("tpu",) once it compiles for the chip
+# and is bit-identical to its jnp twin there; tests/test_tpu_compile.py
+# then compiles it with interpret=False. GPU-specialized variants
+# (Mosaic-GPU / Triton) slot in as register("hufdec", "pallas_gpu", ...,
+# auto_for=("gpu",)).
+register("hufenc", "jnp", _hufenc_jnp, auto_for=("cpu", "gpu", "tpu"))
+register("hufenc", "pallas", _hufenc_pallas)
+register("hufdec", "jnp", _hufdec_jnp, auto_for=("cpu", "gpu", "tpu"))
+register("hufdec", "pallas", _hufdec_pallas)
+register("dq_center", "jnp", _dq_center_jnp, auto_for=("cpu", "gpu", "tpu"))
+register("dq_center", "pallas", _dq_center_pallas)
+register("ceaz_chunk", "jnp", _ceaz_chunk_jnp, auto_for=("cpu", "gpu", "tpu"))
+register("ceaz_chunk", "pallas", _ceaz_chunk_pallas)
 register("ceaz_chunk_dec", "jnp", _ceaz_chunk_dec_jnp,
-         auto_for=("cpu", "gpu"))
-register("ceaz_chunk_dec", "pallas", _ceaz_chunk_dec_pallas,
-         auto_for=("tpu",))
+         auto_for=("cpu", "gpu", "tpu"))
+register("ceaz_chunk_dec", "pallas", _ceaz_chunk_dec_pallas)

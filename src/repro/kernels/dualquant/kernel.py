@@ -21,11 +21,14 @@ not recompile (on real TPU this lands in SMEM).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+from ..dispatch import resolve_interpret
 
 RADIUS = 512
 NUM_SYMBOLS = 1024
@@ -98,7 +101,7 @@ def _out_specs():
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def dq1d(x: jax.Array, eb: jax.Array, *, interpret: bool = True):
+def dq1d(x: jax.Array, eb: jax.Array, *, interpret: Optional[bool] = None):
     """x: (rows, cols) f32, rows % ROWS == 0, cols % COLS == 0.
 
     Lorenzo along axis 1 (each row an independent stream).
@@ -122,13 +125,13 @@ def dq1d(x: jax.Array, eb: jax.Array, *, interpret: bool = True):
             jax.ShapeDtypeStruct((rows, cols), jnp.int32),
             jax.ShapeDtypeStruct((rows, cols), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     return tuple(kernel(eb_arr, x, x))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def dq2d(x: jax.Array, eb: jax.Array, *, interpret: bool = True):
+def dq2d(x: jax.Array, eb: jax.Array, *, interpret: Optional[bool] = None):
     """x: (rows, cols) f32 — GLOBAL 2-D Lorenzo via halo views."""
     rows, cols = x.shape
     grid = (rows // ROWS, cols // COLS)
@@ -150,7 +153,7 @@ def dq2d(x: jax.Array, eb: jax.Array, *, interpret: bool = True):
             jax.ShapeDtypeStruct((rows, cols), jnp.int32),
             jax.ShapeDtypeStruct((rows, cols), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     return tuple(kernel(eb_arr, x, x, x, x))
 
@@ -217,7 +220,7 @@ def _dq_center_kernel(q_ref, valid_ref, c_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def dq_center(q2: jax.Array, valid2: jax.Array, *, interpret: bool = True):
+def dq_center(q2: jax.Array, valid2: jax.Array, *, interpret: Optional[bool] = None):
     """q2 (C, V) i32, valid2 (C, V) -> centers (C,) i32; one radix-select
     program per chunk row (the row must fit VMEM: V <= ~1M values)."""
     C, V = q2.shape
@@ -230,6 +233,6 @@ def dq_center(q2: jax.Array, valid2: jax.Array, *, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((1, 1), lambda c: (c, 0)),
         out_shape=jax.ShapeDtypeStruct((C, 1), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q2.astype(jnp.int32), valid2.astype(jnp.int32))
     return centers[:, 0]

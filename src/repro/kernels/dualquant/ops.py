@@ -16,6 +16,8 @@ Two entry points with DIFFERENT prediction semantics (both faithful):
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -34,7 +36,7 @@ def _pad2d(x, mr, mc):
     return x, r, c
 
 
-def dual_quantize(x: jax.Array, eb, ndim: int, *, interpret: bool = True):
+def dual_quantize(x: jax.Array, eb, ndim: int, *, interpret: Optional[bool] = None):
     """Returns (codes i32, outlier bool, delta i32) with x's shape.
 
     Global Lorenzo semantics for every ndim (kernel used when ndim==2).
@@ -57,7 +59,7 @@ def _stream_layout(n: int, pipelines: int):
 
 
 def stream_quantize(x: jax.Array, eb, pipelines: int = 64,
-                    *, interpret: bool = True):
+                    *, interpret: Optional[bool] = None):
     """Flat stream -> (codes, outlier, delta), row-local prediction.
 
     Returns arrays flattened back to x's shape. Prediction resets
@@ -112,14 +114,10 @@ def dq_center(q2: jax.Array, valid2: jax.Array, *, interpret=None):
     """The `dq_center` dispatch op's 'pallas' implementation: per-row
     radix-select median kernel, bit-identical to :func:`chunk_center`
     (rows larger than VMEM fall back to it)."""
-    from ..dispatch import default_interpret
     q2 = jnp.asarray(q2)
     if q2.shape[1] > _CENTER_ROW_LIMIT:
         return chunk_center(q2, jnp.asarray(valid2))
-    if interpret is None:
-        interpret = default_interpret()
-    return K.dq_center(q2, jnp.asarray(valid2),
-                       interpret=bool(interpret))
+    return K.dq_center(q2, jnp.asarray(valid2), interpret=interpret)
 
 
 def stream_dequantize(delta: jax.Array, eb, pipelines: int = 64):

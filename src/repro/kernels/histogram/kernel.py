@@ -10,10 +10,13 @@ sequential => safe accumulation; first step zero-initializes).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..dispatch import resolve_interpret
 
 NUM_SYMBOLS = 1024
 ROWS = 8
@@ -36,7 +39,7 @@ def _hist_kernel(codes_ref, hist_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def histogram(codes: jax.Array, *, interpret: bool = True) -> jax.Array:
+def histogram(codes: jax.Array, *, interpret: Optional[bool] = None) -> jax.Array:
     """codes: (rows, cols) int32 in [0, 1024); returns (1024,) int32."""
     rows, cols = codes.shape
     grid = (rows // ROWS, cols // COLS)
@@ -46,6 +49,6 @@ def histogram(codes: jax.Array, *, interpret: bool = True) -> jax.Array:
         in_specs=[pl.BlockSpec((ROWS, COLS), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((1, NUM_SYMBOLS), lambda i, j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, NUM_SYMBOLS), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes)
     return out[0]

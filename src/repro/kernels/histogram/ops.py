@@ -6,6 +6,8 @@ one-hot compare naturally drops it.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -14,7 +16,7 @@ from . import kernel as K
 _SENTINEL = -1
 
 
-def histogram(codes: jax.Array, *, interpret: bool = True) -> jax.Array:
+def histogram(codes: jax.Array, *, interpret: Optional[bool] = None) -> jax.Array:
     flat = jnp.asarray(codes, jnp.int32).reshape(-1)
     n = flat.shape[0]
     cols = K.COLS

@@ -31,6 +31,7 @@ int32 (not uint16/uint8) so the layout respects f32-class tiling.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +39,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.huffman import DEFAULT_MAX_LEN
+from ..dispatch import resolve_interpret
 
 MAX_CODE_BITS = DEFAULT_MAX_LEN      # table depth the caller stages at
 TBL = 1 << MAX_CODE_BITS
@@ -84,7 +86,7 @@ def _hufdec_kernel(cb_idx_ref, words_ref, nbits_ref, count_ref, sym_ref,
 @functools.partial(jax.jit, static_argnames=("block_size", "interpret"))
 def hufdec(words2: jax.Array, nbits2: jax.Array, counts: jax.Array,
            sym2: jax.Array, len2: jax.Array, cb_idx: jax.Array,
-           *, block_size: int, interpret: bool = True):
+           *, block_size: int, interpret: Optional[bool] = None):
     """words2 (C, W) u32; nbits2 (C, NB) i32; counts (C,) i32;
     sym2/len2 (K, 2^16) i32 stacked decode tables; cb_idx (C,) i32.
 
@@ -110,6 +112,6 @@ def hufdec(words2: jax.Array, nbits2: jax.Array, counts: jax.Array,
         _hufdec_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, NB, block_size), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(cb_idx.astype(jnp.int32), words2, nbits2.astype(jnp.int32), counts2,
       sym2, len2)

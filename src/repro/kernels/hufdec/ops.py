@@ -15,19 +15,18 @@ from typing import Optional
 
 import jax.numpy as jnp
 
-from ..dispatch import default_interpret
+from ..dispatch import resolve_interpret
 from . import kernel as K
 
 
 def decode_blocks(words2, nbits2, counts, sym_flat, len_flat, cb_idx,
                   block_size: int, *, interpret: Optional[bool] = None):
     """Same signature and bit-exact output as ``ref.decode_blocks``."""
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     sym2 = jnp.asarray(sym_flat).reshape(-1, K.TBL).astype(jnp.int32)
     len2 = jnp.asarray(len_flat).reshape(-1, K.TBL).astype(jnp.int32)
     out = K.hufdec(jnp.asarray(words2), jnp.asarray(nbits2),
                    jnp.asarray(counts), sym2, len2, jnp.asarray(cb_idx),
-                   block_size=block_size, interpret=bool(interpret))
+                   block_size=block_size, interpret=interpret)
     C = out.shape[0]
     return out.reshape(C, -1).astype(jnp.uint16)

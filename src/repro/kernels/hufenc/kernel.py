@@ -19,12 +19,15 @@ block (worst case 16 bits/symbol); `nbits[b]` gives the valid bit count.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..dispatch import resolve_interpret
 
 BLOCK = 4096                   # symbols per block (bitstream unit)
 MAX_CODE_LEN = 16
@@ -163,7 +166,7 @@ def _gather_pack_kernel(codes_ref, valid_ref, ln_ref, cw_ref, words_ref,
                                     "interpret"))
 def gather_pack(codes2: jax.Array, valid2: jax.Array, lengths_tbl: jax.Array,
                 cwords_tbl: jax.Array, *, block_size: int, w32: int,
-                cands: int = 33, interpret: bool = True):
+                cands: int = 33, interpret: Optional[bool] = None):
     """codes2/valid2 (C, cv); lengths_tbl (C, 1024) i32; cwords_tbl
     (C, 1024) u32 — one codebook row per chunk.
 
@@ -192,7 +195,7 @@ def gather_pack(codes2: jax.Array, valid2: jax.Array, lengths_tbl: jax.Array,
             jax.ShapeDtypeStruct((C, w32), jnp.uint32),
             jax.ShapeDtypeStruct((C, nblocks), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes2.astype(jnp.int32), valid2.astype(jnp.int32),
       lengths_tbl.astype(jnp.int32), cwords_tbl.astype(jnp.uint32))
     return words, nbits
@@ -215,7 +218,7 @@ def gather_pack(codes2: jax.Array, valid2: jax.Array, lengths_tbl: jax.Array,
 #              O(nblocks + tiles) work, never O(values);
 #   pack     — a (C, tiles) Pallas grid. Each program owns TILE_WORDS
 #              u32 words and reads ONE bounded symbol window placed by
-#              scalar-prefetched element offsets (pl.unblocked indexing).
+#              scalar-prefetched element offsets (pl.Element indexing).
 #              `base` makes the window's local prefix sum globally
 #              exact, so words compose bit-identically to the untiled
 #              kernel.
@@ -267,7 +270,7 @@ def _tiled_pack_kernel(foff_ref, base_ref, codes_ref, valid_ref, ln_ref,
 def gather_pack_tiled(codes2: jax.Array, valid2: jax.Array,
                       lengths_tbl: jax.Array, cwords_tbl: jax.Array, *,
                       block_size: int, w32: int, cands: int = 33,
-                      tile: int = TILE_WORDS, interpret: bool = True):
+                      tile: int = TILE_WORDS, interpret: Optional[bool] = None):
     """Word-tiled twin of :func:`gather_pack`: same signature and
     bit-exact output, VMEM per program bounded by (tile, block_size)
     instead of (cv, w32). Requires prefix-valid rows (see module note).
@@ -297,7 +300,7 @@ def gather_pack_tiled(codes2: jax.Array, valid2: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, sbb), lambda c, s: (c, s)),
         out_shape=jax.ShapeDtypeStruct((C, nbp), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes_p, valid_p, ln)
 
     # glue: O(nblocks) prefix sums place each tile's symbol window
@@ -317,12 +320,10 @@ def gather_pack_tiled(codes2: jax.Array, valid2: jax.Array,
         num_scalar_prefetch=2,
         grid=(C, wt),
         in_specs=[
-            pl.BlockSpec((1, wb * block_size),
-                         lambda c, t, foff, base: (c, foff[c, t]),
-                         indexing_mode=pl.unblocked),
-            pl.BlockSpec((1, wb * block_size),
-                         lambda c, t, foff, base: (c, foff[c, t]),
-                         indexing_mode=pl.unblocked),
+            pl.BlockSpec((1, pl.Element(wb * block_size)),
+                         lambda c, t, foff, base: (c, foff[c, t])),
+            pl.BlockSpec((1, pl.Element(wb * block_size)),
+                         lambda c, t, foff, base: (c, foff[c, t])),
             pl.BlockSpec((1, ln.shape[1]),
                          lambda c, t, foff, base: (c, 0)),
             pl.BlockSpec((1, cw.shape[1]),
@@ -335,14 +336,14 @@ def gather_pack_tiled(codes2: jax.Array, valid2: jax.Array,
                           cands=min(cands, wb * block_size + 1)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, wt * tile), jnp.uint32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(foff, base, codes_p, valid_p, ln, cw)
     return words[:, :w32], nbits_p[:, :nblocks]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def hufenc(codes: jax.Array, codewords: jax.Array, lengths: jax.Array,
-           *, interpret: bool = True):
+           *, interpret: Optional[bool] = None):
     """codes: (nblocks, BLOCK) i32; codewords/lengths: (1024,) u32/i32.
 
     Returns (words (nblocks, WORDS) u32, nbits (nblocks,) i32).
@@ -366,6 +367,6 @@ def hufenc(codes: jax.Array, codewords: jax.Array, lengths: jax.Array,
             jax.ShapeDtypeStruct((nblocks, WORDS), jnp.uint32),
             jax.ShapeDtypeStruct((nblocks, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes, cw, ln)
     return words, nbits[:, 0]

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..dispatch import default_interpret
+from ..dispatch import resolve_interpret
 from . import kernel as K
 
 
@@ -33,16 +33,15 @@ def encode_pack(codes2, valid2, lengths_tbl, cwords_tbl, block_size: int,
     The untiled one-program-per-chunk ``K.gather_pack`` stays available
     as the small-chunk comparison point (kernel microbench, tests).
     """
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     return K.gather_pack_tiled(
         jnp.asarray(codes2), jnp.asarray(valid2), jnp.asarray(lengths_tbl),
         jnp.asarray(cwords_tbl), block_size=block_size, w32=w32,
-        cands=cands, interpret=bool(interpret))
+        cands=cands, interpret=interpret)
 
 
 def hufenc_flat(codes: jax.Array, codewords, lengths, pad_sym: int = 512,
-                *, interpret: bool = True
+                *, interpret: Optional[bool] = None
                 ) -> Tuple[jax.Array, jax.Array, int]:
     flat = jnp.asarray(codes, jnp.int32).reshape(-1)
     n = int(flat.shape[0])

@@ -64,7 +64,9 @@ def _encode_one(codes, valid, lengths, cwords, block_size, w32, cands):
     each OUTPUT word, searchsorted on the cumulative bit offsets finds
     the first overlapping symbol and the `cands`-candidate window is
     gathered and OR-composed. Gathers vectorize on every backend; the
-    scatter formulation serializes on CPU XLA.
+    scatter formulation serializes on CPU XLA. The window is walked one
+    candidate at a time, so temporaries stay O(w32) instead of
+    O(w32 * cands) — a paper-scale 2^23-value chunk fits one chip.
     """
     cv = codes.shape[0]
     lens = jnp.where(valid, lengths[codes], 0)
@@ -74,20 +76,23 @@ def _encode_one(codes, valid, lengths, cwords, block_size, w32, cands):
 
     w_bit = jnp.arange(w32, dtype=jnp.int32) * 32
     first = jnp.searchsorted(ends, w_bit, side="right")   # covers bit w_bit
-    cand = first[:, None] + jnp.arange(cands, dtype=jnp.int32)[None, :]
-    in_range = cand < cv
-    ci = jnp.clip(cand, 0, cv - 1)
-    off = starts[ci] - w_bit[:, None]
-    ln = lens[ci]
-    v = vals[ci]
-    left = 32 - off - ln
-    live = in_range & (off < 32) & (off + ln > 0)
-    ls = jnp.clip(left, 0, 31).astype(jnp.uint32)
-    rs = jnp.clip(-left, 0, 31).astype(jnp.uint32)
-    shifted = jnp.where(left >= 0, v << ls, v >> rs)
-    # live contributions are bit-disjoint => sum == or
-    words = jnp.where(live, shifted, jnp.uint32(0)).sum(
-        axis=1, dtype=jnp.uint32)
+
+    def add_candidate(j, words):
+        cand = first + j
+        ci = jnp.clip(cand, 0, cv - 1)
+        off = starts[ci] - w_bit
+        ln = lens[ci]
+        v = vals[ci]
+        left = 32 - off - ln
+        live = (cand < cv) & (off < 32) & (off + ln > 0)
+        ls = jnp.clip(left, 0, 31).astype(jnp.uint32)
+        rs = jnp.clip(-left, 0, 31).astype(jnp.uint32)
+        shifted = jnp.where(left >= 0, v << ls, v >> rs)
+        # live contributions are bit-disjoint => sum == or
+        return words + jnp.where(live, shifted, jnp.uint32(0))
+
+    words = jax.lax.fori_loop(0, cands, add_candidate,
+                              jnp.zeros((w32,), jnp.uint32))
 
     nblocks = -(-cv // block_size)
     lens_p = jnp.pad(lens, (0, nblocks * block_size - cv))

@@ -268,9 +268,8 @@ def hufdec_tiles(words2, nbits2, counts, sym2, len2, cb_idx, *,
         num_scalar_prefetch=3,
         grid=(C, nt),
         in_specs=[
-            pl.BlockSpec((1, win),
-                         lambda c, t, cb, foff, tbit: (c, foff[c, t]),
-                         indexing_mode=pl.unblocked),
+            pl.BlockSpec((1, pl.Element(win)),
+                         lambda c, t, cb, foff, tbit: (c, foff[c, t])),
             pl.BlockSpec((1, tb), lambda c, t, cb, foff, tbit: (c, t)),
             pl.BlockSpec((1, 1), lambda c, t, cb, foff, tbit: (c, 0)),
             pl.BlockSpec((1, tbl),

@@ -197,7 +197,7 @@ def ceaz_chunk_fused(work2, prev2, valid2, ebs, bank_lengths, bank_cwords,
 #
 # Same quantize/hist bodies as the fused kernel, on TILE_SEG windows.
 # The Lorenzo kernel reads a (SEG+1)-value raw window whose first
-# element is the segment's predecessor (pl.unblocked-style shifted
+# element is the segment's predecessor (element-indexed shifted
 # BlockSpec, the dq1d line-buffer trick); the chunk head instead
 # substitutes the chunk's prev halo, so the tiled rows quantize
 # bitwise-identically to the fused kernel. Histograms accumulate into
@@ -264,9 +264,8 @@ def lorenzo_tiles(work_p, prev2, valid_p, ebs2, *, seg: int,
         in_specs=[
             pl.BlockSpec((1, 1), lambda c, s: (c, 0)),
             pl.BlockSpec((1, 1), lambda c, s: (c, 0)),
-            pl.BlockSpec((1, seg + 1),
-                         lambda c, s: (c, jnp.maximum(s * seg - 1, 0)),
-                         indexing_mode=pl.unblocked),
+            pl.BlockSpec((1, pl.Element(seg + 1)),
+                         lambda c, s: (c, jnp.maximum(s * seg - 1, 0))),
             pl.BlockSpec((1, seg), lambda c, s: (c, s)),
         ],
         out_specs=[

@@ -26,7 +26,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..dispatch import default_interpret
+from ..dispatch import resolve_interpret
 from ..dualquant import ops as dq_ops
 from ..hufenc import kernel as hufenc_k
 from . import decode_kernel as DK
@@ -83,8 +83,7 @@ def ceaz_chunk(work2, prev2, valid2, ebs, bank_lengths, bank_cwords,
                predictor: str = "lorenzo", *,
                interpret: Optional[bool] = None):
     """Same signature and bit-exact outputs as ``ref.ceaz_chunk``."""
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     work2 = jnp.asarray(work2, jnp.float32)
     prev2 = jnp.asarray(prev2, jnp.float32)
     valid2 = jnp.asarray(valid2)
@@ -95,12 +94,12 @@ def ceaz_chunk(work2, prev2, valid2, ebs, bank_lengths, bank_cwords,
         out = K.ceaz_chunk_fused(
             work2, prev2, valid2, ebs, bank_lengths, bank_cwords,
             block_size=block_size, w32=w32, cands=cands,
-            predictor=predictor, interpret=bool(interpret))
+            predictor=predictor, interpret=interpret)
     else:
         out = _ceaz_chunk_tiled(
             work2, prev2, valid2, ebs, bank_lengths, bank_cwords,
             block_size=block_size, w32=w32, cands=cands,
-            predictor=predictor, interpret=bool(interpret))
+            predictor=predictor, interpret=interpret)
     (q2, codes2, outl2, delta2, centers, hists, sel, totals, words,
      nbits) = out
     return (q2, codes2, outl2.astype(bool), delta2, centers, hists, sel,
@@ -117,8 +116,7 @@ def ceaz_chunk_dec(words2, nbits2, counts, sym_flat, len_flat, cb_idx,
     row counts past `decode_kernel._DEC_FUSE_LIMIT` switch to the
     word-tiled walk + the shared jnp patch/inverse tail.
     """
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     words2 = jnp.asarray(words2, jnp.uint32)
     nbits2 = jnp.asarray(nbits2, jnp.int32)
     counts = jnp.asarray(counts, jnp.int32)
@@ -133,8 +131,8 @@ def ceaz_chunk_dec(words2, nbits2, counts, sym_flat, len_flat, cb_idx,
         return DK.ceaz_chunk_dec_fused(
             words2, nbits2, counts, sym2, len2, cb_idx, odelta2, base,
             seg0, islor, block_size=block_size,
-            interpret=bool(interpret))
+            interpret=interpret)
     codes = DK.hufdec_tiles(words2, nbits2, counts, sym2, len2, cb_idx,
                             block_size=block_size,
-                            interpret=bool(interpret))
+                            interpret=interpret)
     return R.patch_and_inverse(codes, counts, odelta2, base, seg0, islor)

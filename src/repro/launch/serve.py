@@ -9,7 +9,6 @@ jointly and batch stays replicated — all 256 chips hold context slices.
 from __future__ import annotations
 
 import dataclasses
-import json
 import os
 from typing import Any, Dict, Optional, Tuple
 
@@ -19,7 +18,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models import transformer as T
-from ..runtime import compat
 from ..runtime.sharding import ShardingPlan
 
 
@@ -40,7 +38,7 @@ def cache_shardings(cache, plan: ShardingPlan, batch_sharded: bool = True):
     msize = plan.model_size
 
     def leaf_spec(path, leaf) -> P:
-        keys = compat.keystr(path)
+        keys = jax.tree_util.keystr(path, simple=True, separator="/")
         nd = len(leaf.shape)
         name = keys.split("/")[-1]
         shape = leaf.shape
@@ -166,7 +164,8 @@ def paged_serving_store(directory: str, plan: ShardingPlan,
     and PARAM_RULES placement fused in). Extra `paged_kw` forward to
     the store (``cache_bytes``, ``group``, ...).
 
-    Returns (store, meta) or None when no usable checkpoint exists.
+    Returns (store, meta) or None when no usable checkpoint exists
+    (corruption is an OSError; any other error propagates).
     The store's decode facade mirrors `restore_checkpoint`'s compressor
     config, so paged leaves are bit-identical to a full restore.
     """
@@ -177,19 +176,18 @@ def paged_serving_store(directory: str, plan: ShardingPlan,
         return None
     d, s = found
     try:
-        with open(os.path.join(d, "manifest.json")) as f:
-            manifest = json.load(f)
+        manifest = C.read_manifest(d)
         if manifest.get("format", 1) < 2:
-            raise ValueError("paged serving needs a format-2 leaf stream")
+            print(f"checkpoint {d} has no format-2 leaf stream to page")
+            return None
         stream = os.path.join(d, manifest.get("file", C.LEAVES_STREAM))
         cfg = ckpt_cfg or C.CheckpointConfig()
-        keys = list(manifest.get("leaves", {}))
         prefix = "params/" if any(
-            k.startswith("params/") for k in keys) else None
+            k.startswith("params/") for k in manifest["leaves"]) else None
         store = PagedParamStore(stream, plan=plan, dtype=dtype,
                                 comp=C._compressor(cfg), prefix=prefix,
                                 **paged_kw)
-    except Exception as e:
+    except OSError as e:          # corrupted stream or manifest
         print(f"checkpoint {d} unusable for paged serving ({e})")
         return None
     return store, {"step": manifest.get("step", s),

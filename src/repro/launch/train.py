@@ -23,7 +23,6 @@ from ..data.synthetic import DataConfig, ShardedDataset, batch_for_step
 from ..models import transformer as T
 from ..optim import (AdamWConfig, CompressionConfig, adamw_init,
                      adamw_update, compressed_cross_pod_mean, ef_init)
-from ..runtime import compat
 from ..runtime.sharding import ShardingPlan, make_plan, param_shardings
 from . import mesh as mesh_lib
 
@@ -105,24 +104,28 @@ def make_train_step(model_cfg, train_cfg: TrainConfig, plan: ShardingPlan):
     def train_step(state, batch):
         params = state["params"]
         if use_comp:
-            inner_plan = dataclasses.replace(plan, batch_axes=("data",))
+            # the map is manual over EVERY mesh axis (XLA's SPMD
+            # partitioner check-fails on a pod-only manual region): each
+            # device computes its pod's gradient on the pod's whole batch,
+            # with no sharding constraints inside
+            inner_plan = dataclasses.replace(plan, mesh=None)
 
             def per_pod(params, residual, batch):
                 (loss, metr), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params, batch, inner_plan)
                 grads, new_res = compressed_cross_pod_mean(
-                    grads, residual, train_cfg.comp, plan)
+                    grads, residual, train_cfg.comp)
                 loss = jax.lax.pmean(loss, "pod")
                 return loss, metr, grads, new_res
 
             batch_specs = jax.tree.map(
                 lambda x: P(*("pod",) + (None,) * (x.ndim - 1)), batch)
-            loss, metr, grads, new_res = compat.shard_map(
+            loss, metr, grads, new_res = jax.shard_map(
                 per_pod,
                 mesh=plan.mesh,
                 in_specs=(P(), P(), batch_specs),
                 out_specs=(P(), P(), P(), P()),
-                axis_names={"pod"},
+                axis_names=set(plan.mesh.axis_names),
                 check_vma=False,
             )(params, state["residual"], batch)
         else:

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..runtime import compat
 from ..runtime.sharding import ShardingPlan
 
 Dtype = jnp.dtype
@@ -693,13 +692,13 @@ def moe_apply(p, cfg: MoEConfig, x, plan: ShardingPlan):
         # the one passed down.
         manual = set(plan.batch_axes) | {plan.model_axis}
         mesh_arg = plan.mesh
-        ctx = compat.get_abstract_mesh()
+        ctx = jax.sharding.get_abstract_mesh()
         if ctx is not None and not ctx.empty and any(
                 t == jax.sharding.AxisType.Manual
                 for t in getattr(ctx, "axis_types", ())):
             mesh_arg = None     # nested: bind only our axis_names on the
             # ambient (partially-manual) mesh
-        y, aux = compat.shard_map(
+        y, aux = jax.shard_map(
             shard_fn, mesh=mesh_arg,
             in_specs=(P(plan.batch, None), P(None, None),
                       P(plan.model_axis, None, None),

@@ -7,7 +7,7 @@ shape static under jit (the same property the paper needs for constant
 FPGA throughput), and uniform payload sizes remove size-stragglers from
 the gather.
 
-Scheme per leaf (inside shard_map over 'pod', other axes auto):
+Scheme per leaf (inside a shard_map that is manual over every axis):
   1. error-feedback: g += residual (carried in optimizer state) — makes the
      quantization bias vanish over steps (Karimireddy et al. 2019);
   2. prequantize with per-leaf eb = max|g| / 2^(bits-1)  (this IS the
@@ -27,8 +27,7 @@ The Pallas kernel remains the explicit-offload path (I/O benchmarks).
 from __future__ import annotations
 
 import dataclasses
-import functools
-from typing import Any, Optional, Tuple
+from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -91,51 +90,27 @@ def compress_decompress_leaf(g, bits: int):
     return rec.reshape(g.shape), packed, scale
 
 
-def compressed_cross_pod_mean(grads, residual, cfg: CompressionConfig,
-                              plan=None) -> Tuple[Any, Any]:
+def compressed_cross_pod_mean(grads, residual,
+                              cfg: CompressionConfig) -> Tuple[Any, Any]:
     """Inside shard_map over cfg.axis: per-pod grads -> pod-mean grads.
 
     Returns (mean_grads, new_residual). Caller guarantees `cfg.axis` is a
-    live shard_map axis name. Each leaf is FIRST resharded flat over the
-    intra-pod (data, model) axes so the quantize/pack pipeline is
-    shard-local — without this the pack's reshape makes GSPMD replicate
-    the gradient before packing and the pod hop moves MORE than the
-    uncompressed exchange (measured on glm4-9b multi-pod; EXPERIMENTS.md
-    §Perf cell 3). Intra-pod resharding rides the fast ICI; only packed
-    payloads cross the DCI pod axis.
+    live shard_map axis name; each leaf arrives whole (the train step's
+    map is manual over every mesh axis). Only packed payloads and scales
+    cross the pod axis.
     """
-    from ..runtime import compat
-    n_pods = compat.axis_size(cfg.axis)  # noqa: F841 — asserts axis is live
+    jax.lax.axis_size(cfg.axis)          # raises unless the axis is live
     per = 32 // cfg.bits
-    # Sharding constraints inside a partially-manual shard_map are only
-    # supported on new jax (old XLA check-fails on IsManualSubgroup);
-    # without them the pack replicates first — slower wire, same math.
-    if (plan is not None and plan.mesh is not None
-            and compat.supports_partial_manual_constraints()):
-        local = int(np.prod([plan.axis_size(a)
-                             for a in plan.mesh.axis_names
-                             if a != cfg.axis]))
-        flat_sharding = jax.sharding.NamedSharding(
-            plan.mesh, jax.sharding.PartitionSpec(
-                tuple(a for a in plan.mesh.axis_names if a != cfg.axis)))
-    else:
-        local = 1
-        flat_sharding = None
-    quantum = per * local
 
     def leaf(g, r):
         g32 = g.astype(jnp.float32)
         if cfg.error_feedback:
             g32 = g32 + r
         n = g.size
-        npad = -(-n // quantum) * quantum
+        npad = -(-n // per) * per
         flat = jnp.pad(g32.reshape(-1), (0, npad - n))
-        if flat_sharding is not None:
-            flat = jax.lax.with_sharding_constraint(flat, flat_sharding)
         q, scale = _quantize_leaf(flat, cfg.bits)
         packed = pack_jnp(q, cfg.bits)
-        if flat_sharding is not None:
-            packed = jax.lax.with_sharding_constraint(packed, flat_sharding)
         # local reconstruction for error feedback
         rec = _dequantize_leaf(unpack_jnp(packed, npad, cfg.bits), scale,
                                cfg.bits)
@@ -143,14 +118,6 @@ def compressed_cross_pod_mean(grads, residual, cfg: CompressionConfig,
                  if cfg.error_feedback else r)
         # exchange ONLY the packed payload + scale across pods (DCI hop)
         all_packed = jax.lax.all_gather(packed, cfg.axis)      # (P, ...)
-        if flat_sharding is not None:
-            # keep the gathered payload intra-pod-sharded: without this the
-            # partitioner fuses a full replication into the gather
-            all_packed = jax.lax.with_sharding_constraint(
-                all_packed, jax.sharding.NamedSharding(
-                    plan.mesh, jax.sharding.PartitionSpec(
-                        None, tuple(a for a in plan.mesh.axis_names
-                                    if a != cfg.axis))))
         all_scale = jax.lax.all_gather(scale, cfg.axis)        # (P,)
         vals = jax.vmap(lambda pk, sc: _dequantize_leaf(
             unpack_jnp(pk, npad, cfg.bits), sc, cfg.bits))(
@@ -200,11 +167,10 @@ def snapshot_grads(grads, eb_rel: float = 1e-3,
     value-direct host path, smooth ones to the fused Lorenzo path);
     small leaves are stored raw.
     """
-    from ..runtime import compat
     comp = _grad_compressor(eb_rel, chunk_bytes)
     out = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(grads)[0]:
-        key = compat.keystr(path)
+        key = jax.tree_util.keystr(path, simple=True, separator="/")
         arr = np.asarray(leaf)
         out[key] = (comp.compress(arr)
                     if _compressible(arr, min_compress) else arr)
@@ -235,7 +201,6 @@ def snapshot_grads_to_stream(path: str, grads, eb_rel: float = 1e-3,
     the engine stats dict (raw/stored bytes, overlap efficiency).
     """
     from ..io import engine as E
-    from ..runtime import compat
     comp = _grad_compressor(eb_rel, chunk_bytes)
 
     def encode(keys, items):
@@ -249,7 +214,8 @@ def snapshot_grads_to_stream(path: str, grads, eb_rel: float = 1e-3,
     with eng:
         for p, leaf in jax.tree_util.tree_flatten_with_path(grads)[0]:
             arr = np.asarray(leaf)
-            eng.submit(compat.keystr(p), arr,
+            eng.submit(jax.tree_util.keystr(p, simple=True, separator="/"),
+                       arr,
                        meta={"shape": list(arr.shape),
                              "dtype": str(arr.dtype),
                              "raw_nbytes": int(arr.nbytes)})

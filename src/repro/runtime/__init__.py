@@ -1,2 +1,1 @@
-from . import compat  # noqa: F401
 from .sharding import ShardingPlan  # noqa: F401

@@ -303,7 +303,9 @@ def _run_value_pass1(work: jnp.ndarray, eb: float, chunk_values: int,
     n = int(work.size)
     n_chunks, _ = chunk_layout(n, chunk_values)
     q2, valid2 = _value_prequantize(work, eb, n_chunks, chunk_values)
-    centers = dispatch.resolve("dq_center", kernel_impl)(q2, valid2)
+    with dispatch.measure("dq_center", kernel_impl) as m:
+        centers = m.done(dispatch.resolve("dq_center", kernel_impl)(
+            q2, valid2))
     codes2, outl2, delta2 = _value_finalize(q2, centers, valid2)
     q = q2.reshape(-1)[:n]
     centers_np = np.asarray(centers).astype(np.int64)
@@ -1235,7 +1237,8 @@ def batch_compress(shards: Sequence[np.ndarray], eb_rel: float,
             lambda w, e: _value_prequantize(w, e, n_chunks, chunk_values)
         )(work, ebs_j)
         center_fn = dispatch.resolve("dq_center", kernel_impl)
-        centers2 = jax.vmap(center_fn)(q3, valid3)
+        with dispatch.measure("dq_center", kernel_impl) as m:
+            centers2 = m.done(jax.vmap(center_fn)(q3, valid3))
         codes3, outl3, delta3 = jax.vmap(_value_finalize)(q3, centers2,
                                                           valid3)
         q2 = q3.reshape(nshards, -1)[:, :n]

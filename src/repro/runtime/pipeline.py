@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from . import compat
 
 
 def pipeline_apply(stage_fn: Callable, params_stacked, microbatches,
@@ -43,10 +42,10 @@ def pipeline_apply(stage_fn: Callable, params_stacked, microbatches,
         buf_shape = mb_local.shape[1:]
         # pvary: the loop state is stage-VARYING from tick 1 on; the zeros
         # init must carry the same varying-manual-axes type
-        outputs = compat.pvary(jnp.zeros_like(mb_local), stage_axis)
-        carry_in = compat.pvary(jnp.zeros(buf_shape, mb_local.dtype),
-                                stage_axis)
-        mb_local = compat.pvary(mb_local, stage_axis)
+        outputs = jax.lax.pvary(jnp.zeros_like(mb_local), stage_axis)
+        carry_in = jax.lax.pvary(jnp.zeros(buf_shape, mb_local.dtype),
+                                 stage_axis)
+        mb_local = jax.lax.pvary(mb_local, stage_axis)
 
         def tick(t, state):
             carry, outputs = state
@@ -72,7 +71,7 @@ def pipeline_apply(stage_fn: Callable, params_stacked, microbatches,
             jnp.where(sid == n_stages - 1, outputs, 0.0), stage_axis)
         return outputs
 
-    return compat.shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),

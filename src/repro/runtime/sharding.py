@@ -20,7 +20,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import compat
 
 
 @dataclasses.dataclass
@@ -245,6 +244,7 @@ def param_shardings(params, plan: ShardingPlan):
     if plan.mesh is None:
         return jax.tree.map(lambda _: None, params)
     return jax.tree_util.tree_map_with_path(
-        lambda path, leaf: leaf_sharding(compat.keystr(path),
-                                         getattr(leaf, "shape", ()), plan),
+        lambda path, leaf: leaf_sharding(
+            jax.tree_util.keystr(path, simple=True, separator="/"),
+            getattr(leaf, "shape", ()), plan),
         params)

@@ -99,3 +99,28 @@ def test_training_continues_after_lossy_restore(tmp_ckpt):
         state2, m2 = step(state2, b)
     assert np.isfinite(float(m2["loss"]))
     assert float(m2["loss"]) < loss_before * 1.5
+
+
+def test_bad_manifest_falls_back(tmp_ckpt):
+    cfg, state = _state()
+    C.save_checkpoint(tmp_ckpt, state, step=1)
+    C.save_checkpoint(tmp_ckpt, state, step=2)
+    with open(os.path.join(tmp_ckpt, "step_00000002", "manifest.json"),
+              "w") as f:
+        f.write("{not json")
+    restored, meta = C.restore_checkpoint(tmp_ckpt)
+    assert meta["step"] == 1
+
+
+def test_non_corruption_error_propagates(tmp_ckpt, monkeypatch):
+    """A decode failure that is not corruption (a device or compile
+    error) must surface, not read as a bad checkpoint."""
+    cfg, state = _state()
+    C.save_checkpoint(tmp_ckpt, state, step=1)
+
+    def broken(self, comps):
+        raise RuntimeError("device program failed to compile")
+
+    monkeypatch.setattr(C.CEAZ, "decompress_batch", broken)
+    with pytest.raises(RuntimeError, match="failed to compile"):
+        C.restore_checkpoint(tmp_ckpt)

@@ -6,7 +6,6 @@ import textwrap
 import pytest
 
 from conftest import run_with_devices
-from repro.runtime.compat import supports_partial_manual_constraints
 
 
 @pytest.mark.slow
@@ -47,10 +46,6 @@ def test_tp_dp_matches_single_device():
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not supports_partial_manual_constraints(),
-    reason="partial-manual with_sharding_constraint hard-crashes old-jax "
-           "XLA (IsManualSubgroup check); needs new-style jax.shard_map")
 def test_compressed_pod_exchange_tracks_baseline():
     out = run_with_devices(textwrap.dedent("""
         import jax, jax.numpy as jnp

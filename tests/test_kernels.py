@@ -330,14 +330,15 @@ def test_ceaz_chunk_megakernel_vs_ref(predictor, cv, rng):
 
 def test_ceaz_chunk_dispatch_registration():
     """Both impls resolve through the registry; 'auto' picks the jnp
-    twin off-TPU and the Pallas megakernel on TPU."""
+    twin on every backend (the Pallas megakernel does not compile for
+    the TPU yet)."""
     from repro.kernels import dispatch as D
     from repro.kernels.megakernel import ops as MO
     from repro.kernels.megakernel import ref as MR
     assert D.resolve("ceaz_chunk", "jnp") is MR.ceaz_chunk
     assert D.resolve("ceaz_chunk", "pallas") is MO.ceaz_chunk
     assert D.auto_impl("ceaz_chunk", "cpu") == "jnp"
-    assert D.auto_impl("ceaz_chunk", "tpu") == "pallas"
-    assert D.auto_impl("dq_center", "tpu") == "pallas"
+    assert D.auto_impl("ceaz_chunk", "tpu") == "jnp"
+    assert D.auto_impl("dq_center", "tpu") == "jnp"
     from repro.kernels.dualquant import ops as DQO
     assert D.resolve("dq_center", "pallas") is DQO.dq_center

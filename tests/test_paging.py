@@ -275,3 +275,24 @@ def test_duplicate_key_stream_refused_for_paging(tmp_path):
     w.close()
     with pytest.raises(E.StreamCorruptionError, match="duplicate"):
         PagedParamStore(path, plan=PLAN, comp=_ckpt_comp())
+
+
+def test_paged_open_non_corruption_error_propagates(tmp_path, monkeypatch):
+    """Only corruption (OSError) reads as "no usable checkpoint"; any
+    other failure while opening the paged store propagates."""
+    import repro.serve.paging as PG
+    d = _save(tmp_path, 3)
+
+    def broken(self, *a, **kw):
+        raise RuntimeError("device program failed to compile")
+
+    monkeypatch.setattr(PG.PagedParamStore, "__init__", broken)
+    with pytest.raises(RuntimeError, match="failed to compile"):
+        S.paged_serving_store(d, PLAN)
+
+
+def test_paged_open_corrupt_manifest_is_unusable(tmp_path):
+    d = _save(tmp_path, 3)
+    with open(os.path.join(d, "step_00000003", "manifest.json"), "w") as f:
+        f.write("[]")
+    assert S.paged_serving_store(d, PLAN) is None

@@ -1,0 +1,130 @@
+"""Compile-only checks against a described TPU v5e (no chip needed).
+
+Each dispatch op compiles as `'auto'` resolves it on a TPU, at the
+paper-scale chunk the facade's default `chunk_bytes` gives (2^23 f32
+values per row, block 4096, a 12-book bank), for the chip's compiler.
+An op whose TPU route is a Pallas kernel compiles with
+``interpret=False``, so a kernel Mosaic refuses fails here, not on the
+chip. Every compiled program must fit one v5e's 16 GB of HBM.
+
+The topology is described inside a module fixture: only one process at
+a time may load the TPU compiler's library, and the test workers import
+every test file.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import dispatch
+from repro.runtime import fused
+
+V5E_HBM_BYTES = 16 * 10**9
+BLOCK = 4096
+BOOKS = 12                       # the shipped codebook bank's size
+CHUNK = 1 << 23                  # CEAZConfig's default 32 MB f32 chunk
+TABLE = 1 << 16                  # decode table rows per codebook
+# chunk rows per field at the default chunk: HACC 2^23 values, NYX 256^3
+ROWS = {"hacc": 1, "nyx": 2}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """A sharding on one described chip, with JAX's persistent cache off
+    (a program compiled for an absent chip cannot be read back)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _op_call(op: str, rows: int):
+    """(fn, arg dtypes/shapes) of one `op` call at `rows` x CHUNK values,
+    with the shapes the fused runtime hands it."""
+    nb = CHUNK // BLOCK
+    w32 = fused.words_capacity(CHUNK)              # 16 bits/value worst case
+    k_out = fused._k_outlier(CHUNK)
+    impl = dispatch.auto_impl(op, "tpu")
+    fn = dispatch.resolve(op, impl)
+    kw = {"interpret": False} if impl == "pallas" else {}
+    if op == "ceaz_chunk":
+        bank_w32 = fused._bank_w32(fused.BANK_PROVISION_BITS, CHUNK)
+        return (lambda *a: fn(*a, BLOCK, bank_w32, 33, "lorenzo", **kw),
+                [((rows, CHUNK), jnp.float32), ((rows, 1), jnp.float32),
+                 ((rows, CHUNK), jnp.bool_), ((rows,), jnp.float32),
+                 ((BOOKS, 1024), jnp.int32), ((BOOKS, 1024), jnp.uint32)])
+    if op == "hufenc":
+        return (lambda *a: fn(*a, BLOCK, w32, 33, **kw),
+                [((rows, CHUNK), jnp.int32), ((rows, CHUNK), jnp.bool_),
+                 ((rows, 1024), jnp.int32), ((rows, 1024), jnp.uint32)])
+    if op == "dq_center":
+        return (lambda *a: fn(*a, **kw),
+                [((rows, CHUNK), jnp.int32), ((rows, CHUNK), jnp.bool_)])
+    stream = [((rows, w32), jnp.uint32), ((rows, nb), jnp.int32),
+              ((rows,), jnp.int32), ((BOOKS * TABLE,), jnp.uint16),
+              ((BOOKS * TABLE,), jnp.uint8), ((rows,), jnp.int32)]
+    if op == "hufdec":
+        return lambda *a: fn(*a, BLOCK, **kw), stream
+    assert op == "ceaz_chunk_dec", op
+    return (lambda *a: fn(*a, BLOCK, **kw),
+            stream + [((rows, k_out), jnp.int32), ((rows,), jnp.int32),
+                      ((rows,), jnp.int32), ((rows,), jnp.int32)])
+
+
+def _compile(op: str, rows: int, sharding):
+    fn, specs = _op_call(op, rows)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in specs]
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("op,field", [
+    ("ceaz_chunk", "hacc"), ("ceaz_chunk", "nyx"),
+    ("hufenc", "hacc"), ("hufenc", "nyx"),
+    ("ceaz_chunk_dec", "hacc"), ("hufdec", "hacc"), ("dq_center", "hacc"),
+])
+def test_tpu_auto_route_compiles_and_fits(op, field, one_chip):
+    """What 'auto' runs on a TPU compiles for a v5e at the field's
+    chunk rows and fits one chip's HBM; the program holds a Mosaic
+    kernel exactly when the route is a Pallas kernel."""
+    compiled = _compile(op, ROWS[field], one_chip)
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert total < V5E_HBM_BYTES, (op, field, mem)
+    has_kernel = "tpu_custom_call" in compiled.as_text()
+    assert has_kernel == (dispatch.auto_impl(op, "tpu") == "pallas"), op
+
+
+def test_tpu_hacc_bank_pass_fits(one_chip):
+    """The whole single-pass bank encode of a HACC field (quantize ->
+    select -> pack -> escapes, one traced program) at the default chunk
+    compiles for a v5e and fits its HBM."""
+    n = CHUNK
+    run = fused._mega_pass_fn(
+        dispatch.auto_impl("ceaz_chunk", "tpu"), "lorenzo", 1, n, BLOCK,
+        fused._bank_w32(fused.BANK_PROVISION_BITS, n), 33,
+        fused._k_outlier(n), max(256, n // 256), True)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    mem = run.lower(s((n,), jnp.float32), s((), jnp.float32),
+                    s((BOOKS, 1024), jnp.int32),
+                    s((BOOKS, 1024), jnp.uint32)).compile().memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert total < V5E_HBM_BYTES, mem
+    # a paper-scale chunk should leave most of the chip free
+    assert total < np.int64(V5E_HBM_BYTES) // 8, mem
