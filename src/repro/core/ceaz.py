@@ -366,9 +366,11 @@ class CEAZ:
                          use_fused: bool, coder) -> CEAZCompressed:
         """mode/predictor routing for one array, under a given coder."""
         if self.cfg.mode in ("abs", "rel"):
-            pred = self._pick_predictor(x, self._abs_eb(x))
+            with ot.span("ceaz.policy"):
+                eb = self._abs_eb(x)
+                pred = self._pick_predictor(x, eb)
             if use_fused:
-                return self._compress_eb_fused(x, pred, coder=coder)
+                return self._compress_eb_fused(x, pred, coder=coder, eb=eb)
             if pred == "none":
                 return self._compress_eb_direct(x, word_bits, coder=coder)
             return self._compress_eb(x, word_bits, coder=coder)
@@ -457,20 +459,25 @@ class CEAZ:
 
     def _compress_eb_fused(self, x: np.ndarray,
                            predictor: str = "lorenzo",
-                           coder=None) -> CEAZCompressed:
+                           coder=None, eb: Optional[float] = None
+                           ) -> CEAZCompressed:
         """Policy stays here; all per-value work runs device-resident.
         With a BankCoder the whole encode runs as ONE traced pass
-        (quantize -> select -> encode -> pack, no host tree build)."""
+        (quantize -> select -> encode -> pack, no host tree build).
+        `eb` is the absolute bound when the caller already has it."""
         from ..runtime import fused
         coder = coder if coder is not None else self._coder()
+        if eb is None:
+            with ot.span("ceaz.policy"):
+                eb = self._abs_eb(x)
         if isinstance(coder, BankCoder):
             return fused.compress_error_bounded_bank(
-                x, self._abs_eb(x), self.cfg.mode, coder,
+                x, eb, self.cfg.mode, coder,
                 self._chunk_values(x.dtype.itemsize * 8),
                 self.cfg.block_size, kernel_impl=self.cfg.kernel_impl,
                 predictor=predictor)
         return fused.compress_error_bounded(
-            x, self._abs_eb(x), self.cfg.mode, coder,
+            x, eb, self.cfg.mode, coder,
             self._chunk_values(x.dtype.itemsize * 8), self.cfg.block_size,
             adaptive=self.cfg.adaptive, exact_build=self.cfg.exact_build,
             kernel_impl=self.cfg.kernel_impl, predictor=predictor)

@@ -1098,17 +1098,18 @@ class AsyncCompressWriteEngine:
             self._writer.abort()
             self._check_error()
         self.stats.add("write_s", self._writer.write_s)
-        if self._telemetry:
-            self.manifest = _manifest.build_manifest(
-                stats=self.stats.as_dict(), config=self._config,
-                records=self._rec_rows, batches=self._batch_rows)
-            extra_meta = dict(extra_meta or {})
-            extra_meta.setdefault(_manifest.META_KEY, self.manifest)
-        try:
-            self._writer.close(extra_meta)
-        except BaseException:       # footer/fsync failed: no orphan .tmp
-            self._writer.abort()
-            raise
+        with ot.span("engine.finalize"):
+            if self._telemetry:
+                self.manifest = _manifest.build_manifest(
+                    stats=self.stats.as_dict(), config=self._config,
+                    records=self._rec_rows, batches=self._batch_rows)
+                extra_meta = dict(extra_meta or {})
+                extra_meta.setdefault(_manifest.META_KEY, self.manifest)
+            try:
+                self._writer.close(extra_meta)
+            except BaseException:   # footer/fsync failed: no orphan .tmp
+                self._writer.abort()
+                raise
         return self.stats
 
     def abort(self):
@@ -1176,7 +1177,14 @@ def write_stream(path: str, shards: Sequence[np.ndarray], comp=None,
         config=comp.cfg if comp is not None else None,
         telemetry=telemetry)
     with eng:
-        shards = [np.asarray(s) for s in shards]
+        with ot.span("engine.stage_in"):
+            pulled = [np.asarray(s) for s in shards]
+        # bytes that crossed device->host: shards not already numpy
+        om.add(om.D2H_BYTES,
+               sum(int(a.nbytes) for src, a in zip(shards, pulled)
+                   if not isinstance(src, np.ndarray)),
+               side="encode", site="engine.stage_in")
+        shards = pulled
         group = max(1, group)
         for s in range(0, len(shards), group):
             grp = shards[s:s + group]

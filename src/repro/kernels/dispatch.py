@@ -65,8 +65,6 @@ Op calling conventions (all array args jax-compatible):
 from __future__ import annotations
 
 import contextlib
-import os
-import time
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -161,60 +159,19 @@ def resolve_name(op: str, impl: str = "auto",
 # only — per-invocation accounting has to happen at the host-level pass
 # call sites (runtime/fused.py, runtime/fused_decode.py). Those sites
 # wrap each pass in `measure(op, impl)`, which bumps the per-(op, impl)
-# ceaz_kernel_calls_total counter and opens a `kernel.<op>` span. Wall
-# timing of a device pass needs a sync (jax dispatch is async), so it is
-# OPT-IN: the default hot path stays sync-free, and with timing on the
-# pass blocks on its outputs and feeds ceaz_kernel_pass_seconds.
-
-_TIMING = os.environ.get("CEAZ_KERNEL_TIMING", "") not in ("", "0")
-
-
-def timing_enabled() -> bool:
-    """Whether `measure` syncs and records per-pass wall time (off by
-    default; CEAZ_KERNEL_TIMING=1 or set_timing(True))."""
-    return _TIMING
-
-
-def set_timing(on: bool) -> None:
-    global _TIMING
-    _TIMING = bool(on)
-
-
-class _Measured:
-    """Handle yielded by `measure`: the caller passes its pass outputs
-    through `done(out)` so the opt-in sync knows what to block on."""
-    __slots__ = ("out",)
-
-    def __init__(self):
-        self.out = None
-
-    def done(self, out):
-        self.out = out
-        return out
-
+# ceaz_kernel_calls_total counter and opens a `kernel.<op>` span around
+# the pass's (asynchronous) enqueue. Device time comes from a
+# jax.profiler trace, which needs no sync.
 
 @contextlib.contextmanager
 def measure(op: str, impl: str = "auto", backend: str | None = None):
-    """Account one host-level device-pass invocation of `op`.
-
-    Always: per-(op, impl) call counter + a `kernel.<op>` trace span.
-    With timing enabled: blocks on the outputs handed to `done()` and
-    observes the synced wall time into ceaz_kernel_pass_seconds.
-    """
+    """Account one host-level device-pass invocation of `op`: the
+    per-(op, impl) call counter and a `kernel.<op>` trace span over the
+    enqueue of the pass."""
     impl = resolve_name(op, impl, backend)
     om.add(om.KERNEL_CALLS, op=op, impl=impl)
-    m = _Measured()
-    if not _TIMING:
-        with ot.span("kernel." + op, impl=impl):
-            yield m
-        return
-    t0 = time.perf_counter()
-    with ot.span("kernel." + op, impl=impl, timed=True):
-        yield m
-        if m.out is not None:
-            jax.block_until_ready(m.out)
-    om.observe(om.KERNEL_SECONDS, time.perf_counter() - t0,
-               op=op, impl=impl)
+    with ot.span("kernel." + op, impl=impl):
+        yield
 
 
 # -- default implementations -------------------------------------------------

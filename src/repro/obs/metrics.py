@@ -40,7 +40,8 @@ __all__ = [
     "DECODED_BYTES", "SPEC_HITS", "SPEC_MISSES", "SPEC_WINDOW",
     "BANK_DRIFT",
     "BANK_FALLBACKS", "BANK_REPACKS", "QUEUE_DEPTH", "CORRUPTION",
-    "KERNEL_CALLS", "KERNEL_SECONDS",
+    "KERNEL_CALLS", "H2D_BYTES", "D2H_BYTES", "PASS_VALUES",
+    "PASS_LIVE_VALUES",
     "PAGE_HITS", "PAGE_MISSES", "PAGE_EVICTIONS", "PAGE_CACHE_BYTES",
 ]
 
@@ -65,7 +66,14 @@ QUEUE_DEPTH = "ceaz_engine_queue_depth"            # gauge, labels: queue=
 CORRUPTION = "ceaz_stream_corruption_total"        # StreamCorruptionError raised
 # kernel dispatch (kernels/dispatch.py), labels: op=, impl=
 KERNEL_CALLS = "ceaz_kernel_calls_total"
-KERNEL_SECONDS = "ceaz_kernel_pass_seconds"        # histogram; opt-in timing
+# host<->device traffic of the bank encode pass and the fused decode,
+# labels: side="encode"|"decode", site= (the leaf span it crosses in)
+H2D_BYTES = "ceaz_h2d_bytes_total"
+D2H_BYTES = "ceaz_d2h_bytes_total"
+# values a device pass was sized for vs the real values it carried,
+# labels: side=, op= (the dispatch op of the pass)
+PASS_VALUES = "ceaz_pass_values_total"
+PASS_LIVE_VALUES = "ceaz_pass_live_values_total"
 # decode-on-demand parameter paging (serve/paging.py)
 PAGE_HITS = "ceaz_page_hits_total"                 # cache hits (layer reads)
 PAGE_MISSES = "ceaz_page_misses_total"             # decode-on-demand page-ins

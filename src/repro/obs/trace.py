@@ -19,7 +19,19 @@ Design constraints (why this module looks the way it does):
     export emits ``thread_name`` metadata so Perfetto lays the overlap
     out one track per stage;
   * nestable — spans are plain "X" (complete) events; nesting falls out
-    of the timestamps, no per-thread stack is kept.
+    of the timestamps, no per-thread stack is kept;
+  * one clock with the device — while a tracer is installed and JAX is
+    already imported, each span also opens a
+    ``jax.profiler.TraceAnnotation`` of the same name, so a
+    ``jax.profiler`` trace shows the program's spans on its
+    ``/host:CPU`` plane beside the device's ``XLA Ops``. The module
+    itself never imports JAX.
+
+Leaf spans — the innermost spans of the dump and load paths, each
+covering one kind of host-side work — are listed with their kind in
+:data:`LEAF_KINDS`: ``transfer`` (bytes crossing host<->device),
+``host`` (host compute or I/O) or ``wait`` (the host blocked on the
+device).
 
 The span taxonomy (which names mean what, and their units) is normative
 in ``docs/OBSERVABILITY.md``.
@@ -29,12 +41,35 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
-__all__ = ["Tracer", "span", "traced", "enable", "disable", "active",
-           "save"]
+__all__ = ["Tracer", "span", "enable", "disable", "active", "save",
+           "LEAF_KINDS"]
+
+# leaf span -> kind (docs/OBSERVABILITY.md, "Leaf spans")
+LEAF_KINDS: Dict[str, str] = {
+    # write side: write_stream -> engine.compress -> ceaz.compress
+    "engine.stage_in": "transfer",      # device->host pull of the shards
+    "ceaz.policy": "host",              # error bound + predictor choice
+    "fused.h2d": "transfer",            # upload of the work + bank tables
+    "fused.device_wait": "wait",        # block on the bank pass outputs
+    "fused.d2h": "transfer",            # pull of the pass results
+    "fused.host_select": "host",        # bank-selection replay
+    "fused.assemble": "host",           # chunk records, literal check
+    "engine.serialize": "host",
+    "engine.commit": "host",
+    "engine.finalize": "host",          # footer, fsync, rename
+    # read side: read_stream_arrays -> reader.decode_group -> decode
+    "reader.prefetch": "host",          # record read, crc, unpickle
+    "fused_decode.stage": "host",       # padded host staging
+    "fused_decode.h2d": "transfer",     # upload of the staged arrays
+    "fused_decode.device_wait": "wait",
+    "fused_decode.d2h": "transfer",     # pull of the decoded q rows
+    "fused_decode.finish": "host",      # float64 scale + literal patch
+}
 
 
 class _NoopSpan:
@@ -54,9 +89,20 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+def _annotation(name: str):
+    """A started ``jax.profiler.TraceAnnotation`` named `name`, or None
+    when JAX has not been imported (this module never imports it)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 class _Span:
     """One live span; records a complete ("X") event when it exits."""
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
@@ -69,12 +115,15 @@ class _Span:
         return self
 
     def __enter__(self):
+        self._ann = _annotation(self.name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tracer._record(self.name, self._t0, time.perf_counter(),
-                             self.args)
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._tracer._record(self.name, self._t0, t1, self.args)
         return False
 
 
@@ -163,26 +212,6 @@ def span(name: str, **args):
     if t is None:
         return _NOOP
     return t.span(name, **args)
-
-
-def traced(name: Optional[str] = None):
-    """Decorator form: ``@traced()`` / ``@traced("my.name")`` wraps the
-    call in a span (function qualname when no name is given)."""
-    def deco(fn):
-        label = name or fn.__qualname__
-
-        def wrapper(*a, **kw):
-            t = _tracer
-            if t is None:
-                return fn(*a, **kw)
-            with t.span(label):
-                return fn(*a, **kw)
-        wrapper.__name__ = fn.__name__
-        wrapper.__qualname__ = fn.__qualname__
-        wrapper.__doc__ = fn.__doc__
-        wrapper.__wrapped__ = fn
-        return wrapper
-    return deco
 
 
 def enable(path: Optional[str] = None, *,

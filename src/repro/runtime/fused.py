@@ -204,6 +204,25 @@ def _u32_to_u64(u32: np.ndarray) -> np.ndarray:
             | u32[1::2].astype(np.uint64))
 
 
+def to_device(side: str, site: str, *arrays):
+    """`arrays` (already handed to the device) once they are there: the
+    blocking wait makes the enclosing span cover the copy, not only its
+    enqueue. Their bytes count under ceaz_h2d_bytes_total."""
+    jax.block_until_ready(arrays)
+    om.add(om.H2D_BYTES, sum(int(a.nbytes) for a in arrays),
+           side=side, site=site)
+    return arrays
+
+
+def to_host(side: str, site: str, *arrays):
+    """numpy copies of device `arrays` (None passes through); their bytes
+    count under ceaz_d2h_bytes_total."""
+    out = [None if a is None else np.asarray(a) for a in arrays]
+    om.add(om.D2H_BYTES, sum(int(a.nbytes) for a in out if a is not None),
+           side=side, site=site)
+    return out
+
+
 @dataclasses.dataclass
 class _Pass1:
     """State between the two fused passes.
@@ -303,9 +322,8 @@ def _run_value_pass1(work: jnp.ndarray, eb: float, chunk_values: int,
     n = int(work.size)
     n_chunks, _ = chunk_layout(n, chunk_values)
     q2, valid2 = _value_prequantize(work, eb, n_chunks, chunk_values)
-    with dispatch.measure("dq_center", kernel_impl) as m:
-        centers = m.done(dispatch.resolve("dq_center", kernel_impl)(
-            q2, valid2))
+    with dispatch.measure("dq_center", kernel_impl):
+        centers = dispatch.resolve("dq_center", kernel_impl)(q2, valid2)
     codes2, outl2, delta2 = _value_finalize(q2, centers, valid2)
     q = q2.reshape(-1)[:n]
     centers_np = np.asarray(centers).astype(np.int64)
@@ -441,10 +459,10 @@ def _encode_rows(hists: np.ndarray, codes2, valid2, chunk_values: int,
     w32 = _w32_bucket(totals, chunk_values)
     cands = _cand_window(lengths_np[lengths_np > 0].min())
     encode_pack = dispatch.resolve("hufenc", kernel_impl)
-    with dispatch.measure("hufenc", kernel_impl) as m:
-        words, block_nbits = m.done(encode_pack(
+    with dispatch.measure("hufenc", kernel_impl):
+        words, block_nbits = encode_pack(
             codes2, valid2, jnp.asarray(lengths_np),
-            jnp.asarray(cwords_np), block_size, w32, cands))
+            jnp.asarray(cwords_np), block_size, w32, cands)
     return np.asarray(words), np.asarray(block_nbits), totals
 
 
@@ -727,12 +745,10 @@ def compress_error_bounded_bank(x: np.ndarray, eb: float, mode: str,
     n = int(x.size)
     n_chunks, _ = chunk_layout(n, chunk_values)
     if predictor == "none":
-        ndim = 1
-        work = jnp.asarray(x.reshape(-1), jnp.float32)
+        ndim, work_shape = 1, (-1,)
     else:
         ndim = min(x.ndim, 3)
         work_shape = x.shape if x.ndim <= 3 else (-1,) + x.shape[-2:]
-        work = jnp.asarray(x.reshape(work_shape), jnp.float32)
     w32 = _bank_w32(min(int(bank.lengths.max()), BANK_PROVISION_BITS),
                     chunk_values)
     w32_full = _bank_w32(int(bank.lengths.max()), chunk_values)
@@ -741,6 +757,7 @@ def compress_error_bounded_bank(x: np.ndarray, eb: float, mode: str,
     # one raw value — 1-D streams and value-direct; higher-rank Lorenzo
     # keeps the stage-composed trace (same outputs either way)
     use_mega = predictor == "none" or ndim == 1
+    op = "ceaz_chunk" if use_mega else "hufenc"
     if use_mega:
         run = _mega_pass_fn(
             kernel_impl, predictor, n_chunks, chunk_values, block_size,
@@ -751,63 +768,85 @@ def compress_error_bounded_bank(x: np.ndarray, eb: float, mode: str,
             kernel_impl, predictor, ndim, n_chunks, chunk_values,
             block_size, w32, cands, _k_outlier(chunk_values),
             min(n, max(256, n // 256)), stats_on_device)
-    with dispatch.measure("ceaz_chunk" if use_mega else "hufenc",
-                          kernel_impl) as _m:
-        (hists, sel, totals, words, block_nbits, oidx, odelta, ocount,
-         lit_idx, lit_q, lit_count, codes2, outl2, delta2, valid2, q,
-         centers) = _m.done(run(
-            work, eb, jnp.asarray(bank.lengths, jnp.int32),
-            jnp.asarray(bank.code_table(), jnp.uint32)))
-    # --- everything below is host assembly from the one transfer ---
-    hists_np = np.asarray(hists).astype(np.int64)
-    sel_np = np.asarray(sel)
-    totals_np = np.asarray(totals).astype(np.int64)
-    decisions = [coder.step(h) for h in hists_np]
-    for i, d in enumerate(decisions):
-        # the host replay of the selection statistic must land on the
-        # same bank row the device argmin picked (integer-exact)
-        assert d.bank_index == int(sel_np[i])
+    with ot.span("fused.h2d"):
+        work, lengths_d, cwords_d = to_device(
+            "encode", "fused.h2d",
+            jnp.asarray(x.reshape(work_shape), jnp.float32),
+            jnp.asarray(bank.lengths, jnp.int32),
+            jnp.asarray(bank.code_table(), jnp.uint32))
+    with dispatch.measure(op, kernel_impl):
+        out = run(work, eb, lengths_d, cwords_d)
+    om.add(om.PASS_VALUES, n_chunks * chunk_values, side="encode", op=op)
+    om.add(om.PASS_LIVE_VALUES, n, side="encode", op=op)
+    with ot.span("fused.device_wait"):
+        out = jax.block_until_ready(out)
+    (hists, sel, totals, words, block_nbits, oidx, odelta, ocount,
+     lit_idx, lit_q, lit_count, codes2, outl2, delta2, valid2, q,
+     centers) = out
+    # --- everything below is host work on the pulled results ---
+    with ot.span("fused.d2h"):
+        hists_np, sel_np, totals_np, words_np, nbits_np, centers_np = \
+            to_host("encode", "fused.d2h", hists, sel, totals, words,
+                     block_nbits, centers)
+        if stats_on_device:
+            (oidx_np, odelta_np, ocount_np, lit_idx_np, lit_q_np,
+             lit_count_np) = to_host("encode", "fused.d2h", oidx, odelta,
+                                      ocount, lit_idx, lit_q, lit_count)
+            if int(lit_count_np) > lit_idx_np.shape[0]:
+                # more literal candidates than the pass kept: the check
+                # runs dense over the deltas (see _literals)
+                delta2, = to_host("encode", "fused.d2h", delta2)
+        else:
+            outl_np, delta_np, q_np = to_host("encode", "fused.d2h",
+                                               outl2, delta2, q)
+    with ot.span("fused.host_select"):
+        hists_np = hists_np.astype(np.int64)
+        totals_np = totals_np.astype(np.int64)
+        decisions = [coder.step(h) for h in hists_np]
+        for i, d in enumerate(decisions):
+            # the host replay of the selection statistic must land on
+            # the same bank row the device argmin picked (integer-exact)
+            assert d.bank_index == int(sel_np[i])
     if w32 < w32_full and not _bank_fits(totals_np, w32):
         om.add(om.BANK_REPACKS)
         lengths_np, cwords_np = _codebook_tables(decisions)
         with ot.span("fused.bank_overflow_repack"):
-            words, block_nbits = _bank_repack_fn(
+            repacked = _bank_repack_fn(
                 kernel_impl, block_size, w32_full, cands)(
                 codes2, valid2, jnp.asarray(lengths_np),
                 jnp.asarray(cwords_np))
-    centers_np = (np.asarray(centers).astype(np.int64)
-                  if centers is not None else None)
-    if stats_on_device:
-        p1 = _Pass1(None, outl2, delta2, valid2, None, hists_np, n,
-                    n_chunks, chunk_values, True, lit_idx=lit_idx,
-                    lit_q=lit_q, lit_count=lit_count,
-                    predictor=predictor, centers=centers_np)
-        oidx_np, odelta_np = np.asarray(oidx), np.asarray(odelta)
-        ocount_np = np.asarray(ocount)
-        k = oidx_np.shape[1]
-        outliers = []
-        for i in range(n_chunks):
-            c = int(ocount_np[i])
-            if c <= k:
-                outliers.append((oidx_np[i, :c].astype(np.int64),
-                                 odelta_np[i, :c].astype(np.int32)))
-            else:   # overflow: dense host fallback for this chunk
-                m = np.asarray(outl2[i] & valid2[i])
-                oi = np.flatnonzero(m).astype(np.int64)
-                outliers.append((oi, np.asarray(delta2[i])[oi]
-                                 .astype(np.int32)))
-    else:
-        p1 = _Pass1(None, None, None, None, None, hists_np, n, n_chunks,
-                    chunk_values, False,
-                    outl_host=np.asarray(outl2),
-                    delta_host=np.asarray(delta2),
-                    q_host=np.asarray(q),
-                    predictor=predictor, centers=centers_np)
-        outliers = _outliers(p1)
-    chunks = _assemble_chunks(p1, np.asarray(words),
-                              np.asarray(block_nbits), totals_np,
-                              outliers, eb, decisions, block_size)
-    lit_i, lit_v = _literals(p1, x.reshape(-1), eb, ndim, work.shape)
+            with ot.span("fused.d2h"):
+                words_np, nbits_np = to_host("encode", "fused.d2h",
+                                              *repacked)
+    with ot.span("fused.assemble"):
+        if centers_np is not None:
+            centers_np = centers_np.astype(np.int64)
+        if stats_on_device:
+            p1 = _Pass1(None, outl2, delta2, valid2, None, hists_np, n,
+                        n_chunks, chunk_values, True, lit_idx=lit_idx_np,
+                        lit_q=lit_q_np, lit_count=lit_count_np,
+                        predictor=predictor, centers=centers_np)
+            k = oidx_np.shape[1]
+            outliers = []
+            for i in range(n_chunks):
+                c = int(ocount_np[i])
+                if c <= k:
+                    outliers.append((oidx_np[i, :c].astype(np.int64),
+                                     odelta_np[i, :c].astype(np.int32)))
+                else:   # overflow: dense host fallback for this chunk
+                    m, d = to_host("encode", "fused.d2h",
+                                    outl2[i] & valid2[i], delta2[i])
+                    oi = np.flatnonzero(m).astype(np.int64)
+                    outliers.append((oi, d[oi].astype(np.int32)))
+        else:
+            p1 = _Pass1(None, None, None, None, None, hists_np, n,
+                        n_chunks, chunk_values, False, outl_host=outl_np,
+                        delta_host=delta_np, q_host=q_np,
+                        predictor=predictor, centers=centers_np)
+            outliers = _outliers(p1)
+        chunks = _assemble_chunks(p1, words_np, nbits_np, totals_np,
+                                  outliers, eb, decisions, block_size)
+        lit_i, lit_v = _literals(p1, x.reshape(-1), eb, ndim, work.shape)
     return CEAZCompressed(shape=x.shape, dtype=str(x.dtype), ndim=ndim,
                           mode=mode, chunks=chunks,
                           word_bits=x.dtype.itemsize * 8,
@@ -974,11 +1013,11 @@ def _mega_window(seg2: np.ndarray, ebs, bank, block_size: int,
     k_lit = min(cv, max(256, cv // 256))
     run = _mega_window_fn(kernel_impl, w, cv, block_size, w32, cands,
                           k_lit, stats_on_device)
-    with dispatch.measure("ceaz_chunk", kernel_impl) as m:
-        out = m.done(run(jnp.asarray(seg2, jnp.float32),
-                         jnp.asarray(ebs, jnp.float32),
-                         jnp.asarray(bank.lengths, jnp.int32),
-                         jnp.asarray(bank.code_table(), jnp.uint32)))
+    with dispatch.measure("ceaz_chunk", kernel_impl):
+        out = run(jnp.asarray(seg2, jnp.float32),
+                  jnp.asarray(ebs, jnp.float32),
+                  jnp.asarray(bank.lengths, jnp.int32),
+                  jnp.asarray(bank.code_table(), jnp.uint32))
     (hists, sel, totals, words, nbits, ocounts, codes2, outl2, delta2,
      q2, lit_idx, lit_q, lit_count) = out
     # np.array (not asarray): the repair path overwrites rows in place
@@ -1237,8 +1276,8 @@ def batch_compress(shards: Sequence[np.ndarray], eb_rel: float,
             lambda w, e: _value_prequantize(w, e, n_chunks, chunk_values)
         )(work, ebs_j)
         center_fn = dispatch.resolve("dq_center", kernel_impl)
-        with dispatch.measure("dq_center", kernel_impl) as m:
-            centers2 = m.done(jax.vmap(center_fn)(q3, valid3))
+        with dispatch.measure("dq_center", kernel_impl):
+            centers2 = jax.vmap(center_fn)(q3, valid3)
         codes3, outl3, delta3 = jax.vmap(_value_finalize)(q3, centers2,
                                                           valid3)
         q2 = q3.reshape(nshards, -1)[:, :n]

@@ -60,6 +60,9 @@ import numpy as np
 from ..core import dualquant as core_dq
 from ..core.huffman import DEFAULT_MAX_LEN, Codebook, replay_codebooks
 from ..kernels import dispatch
+from ..obs import metrics as om
+from ..obs import trace as ot
+from .fused import to_device, to_host
 
 MAX_CODE_BITS = DEFAULT_MAX_LEN
 _TBL = 1 << MAX_CODE_BITS
@@ -160,11 +163,11 @@ class _ChunkBatch:
     Two run modes share the staging:
 
     * ``run()`` — the hufdec table walk alone (the PR 3 split path);
-      pass 2 + host finish follow per array in ``decompress_one``.
+      pass 2 (``_split_q``) and the host finish follow per array.
     * ``run_mega()`` — the `ceaz_chunk_dec` decode megakernel: walk,
       rank-gather outlier patch and inverse dual-quant in ONE
       dispatched pass over the whole group; only the float64 scale
-      multiply + literal patch remain (``decompress_one_mega``).
+      multiply + literal patch remain (``_finish``).
     """
 
     def __init__(self, block_size: int, kernel_impl: str = "auto"):
@@ -189,7 +192,7 @@ class _ChunkBatch:
         # one flat Lorenzo chain across the comp's rows (the encoder's
         # single whole-array pass) only when the work shape IS flat;
         # higher-rank fields decode per-row deltas here and run the
-        # multi-axis cumsum in decompress_one_mega
+        # multi-axis cumsum in _mega_q
         chained = (not value and c.mode in ("abs", "rel")
                    and len(c.shape) == 1)
         lor1d = not value and (c.mode == "fixed_ratio" or chained)
@@ -240,20 +243,32 @@ class _ChunkBatch:
         return (words2, nbits2, counts, np.concatenate(tables_sym),
                 np.concatenate(tables_len), cb_idx)
 
+    def _upload(self, staged):
+        with ot.span("fused_decode.h2d"):
+            return to_device("decode", "fused_decode.h2d",
+                             *(jnp.asarray(a) for a in staged))
+
+    def _count_pass(self, op: str, nbits2) -> None:
+        """Values the pass was sized for (C_cap x NB_cap blocks) and the
+        real values it carried."""
+        c_cap, nb_cap = nbits2.shape
+        om.add(om.PASS_VALUES, c_cap * nb_cap * self.block_size,
+               side="decode", op=op)
+        om.add(om.PASS_LIVE_VALUES, sum(self.counts), side="decode", op=op)
+
     def run(self):
         """-> device codes (C_cap, NB_cap*block_size) uint16 (padded)."""
-        words2, nbits2, counts, sym_flat, len_flat, cb_idx = self._stage()
+        with ot.span("fused_decode.stage"):
+            staged = self._stage()
+        dev = self._upload(staged)
         decode_blocks = dispatch.resolve("hufdec", self.kernel_impl)
-        with dispatch.measure("hufdec", self.kernel_impl) as m:
-            return m.done(decode_blocks(
-                jnp.asarray(words2), jnp.asarray(nbits2),
-                jnp.asarray(counts), jnp.asarray(sym_flat),
-                jnp.asarray(len_flat), jnp.asarray(cb_idx),
-                self.block_size))
+        with dispatch.measure("hufdec", self.kernel_impl):
+            out = decode_blocks(*dev, self.block_size)
+        self._count_pass("hufdec", staged[1])
+        return out
 
-    def run_mega(self):
-        """-> device q (C_cap, NB_cap*block_size) int32 (padded): the
-        `ceaz_chunk_dec` megakernel over the whole group."""
+    def _stage_mega(self):
+        """`_stage` plus the megakernel's per-row metadata, padded."""
         words2, nbits2, counts, sym_flat, len_flat, cb_idx = self._stage()
         c_cap = len(counts)
         C = len(self.counts)
@@ -267,15 +282,20 @@ class _ChunkBatch:
         islor[:C] = self.islor
         seg0 = np.arange(c_cap, dtype=np.int32)    # padding: own segment
         seg0[:C] = self.seg0
+        return (words2, nbits2, counts, sym_flat, len_flat, cb_idx,
+                odelta2, base, seg0, islor)
+
+    def run_mega(self):
+        """-> device q (C_cap, NB_cap*block_size) int32 (padded): the
+        `ceaz_chunk_dec` megakernel over the whole group."""
+        with ot.span("fused_decode.stage"):
+            staged = self._stage_mega()
+        dev = self._upload(staged)
         fn = dispatch.resolve("ceaz_chunk_dec", self.kernel_impl)
-        with dispatch.measure("ceaz_chunk_dec", self.kernel_impl) as m:
-            return m.done(fn(
-                jnp.asarray(words2), jnp.asarray(nbits2),
-                jnp.asarray(counts), jnp.asarray(sym_flat),
-                jnp.asarray(len_flat), jnp.asarray(cb_idx),
-                jnp.asarray(odelta2), jnp.asarray(base),
-                jnp.asarray(seg0), jnp.asarray(islor),
-                self.block_size))
+        with dispatch.measure("ceaz_chunk_dec", self.kernel_impl):
+            out = fn(*dev, self.block_size)
+        self._count_pass("ceaz_chunk_dec", staged[1])
+        return out
 
 
 def _padded_outliers(chunks) -> Tuple[np.ndarray, np.ndarray]:
@@ -307,34 +327,50 @@ def _work_shape(c) -> tuple:
     return (lead,) + tail
 
 
-def decompress_one(codes_rows, c) -> np.ndarray:
-    """Pass 2 + host finish for one array, given its decoded chunk rows
-    (device-resident, possibly wider than the array's chunk_values)."""
-    cv = int(c.chunks[0].n_values)
-    n = int(c.n_values)
+def _per_chunk(c) -> bool:
+    """Whether the array's chunks are independent rows with their own eb
+    (value-direct and fixed_ratio) rather than one flat field."""
+    return (getattr(c, "predictor", "lorenzo") == "none"
+            or c.mode == "fixed_ratio")
+
+
+def _split_host_args(c):
+    """Host arrays the split route's inverse pass takes beside the
+    decoded rows: padded outliers (and value-direct centre codes)."""
     oidx, odelta = _padded_outliers(c.chunks)
-    rows = codes_rows[:, :cv]
     if getattr(c, "predictor", "lorenzo") == "none":
+        return oidx, odelta, np.asarray([ch.center for ch in c.chunks],
+                                        np.int32)
+    return oidx, odelta
+
+
+def _split_q(codes_rows, c, oidx, odelta, centers=None):
+    """Split route, pass 2 for one array on the device: outlier scatter
+    and inverse dual-quant of its decoded chunk rows (possibly wider
+    than the array's chunk_values)."""
+    cv = int(c.chunks[0].n_values)
+    rows = codes_rows[:, :cv]
+    if centers is not None:
         # value-direct: per-chunk centre add on device, no prefix sum
-        centers = jnp.asarray([ch.center for ch in c.chunks], jnp.int32)
-        q2 = np.asarray(_inverse_value_chunks(rows, jnp.asarray(oidx),
-                                              jnp.asarray(odelta), centers))
-        parts = [q2[i, :ch.n_values] for i, ch in enumerate(c.chunks)]
+        return _inverse_value_chunks(rows, oidx, odelta, centers)
+    if c.mode in ("abs", "rel"):
+        return _inverse_nd(rows, oidx, odelta, c.ndim, int(c.n_values),
+                           _work_shape(c))
+    # fixed_ratio: independent chunks, per-chunk eb
+    return _inverse_1d_chunks(rows, oidx, odelta)
+
+
+def _finish(c, q: np.ndarray) -> np.ndarray:
+    """Host finish for one array from its pulled integer field: per-chunk
+    rows (value-direct, fixed_ratio) or the flat field (a 1-D chain may
+    still be in chunk rows)."""
+    if _per_chunk(c):
+        parts = [q[i, :ch.n_values] for i, ch in enumerate(c.chunks)]
         ebs = np.repeat([2.0 * ch.eb for ch in c.chunks],
                         [ch.n_values for ch in c.chunks])
         return _finish_host(c, np.concatenate(parts), ebs)
-    if c.mode in ("abs", "rel"):
-        q = np.asarray(_inverse_nd(rows, jnp.asarray(oidx),
-                                   jnp.asarray(odelta), c.ndim, n,
-                                   _work_shape(c)))
-        return _finish_host(c, q, np.float64(2.0 * c.chunks[0].eb))
-    # fixed_ratio: independent chunks, per-chunk eb
-    q2 = np.asarray(_inverse_1d_chunks(rows, jnp.asarray(oidx),
-                                       jnp.asarray(odelta)))
-    parts = [q2[i, :ch.n_values] for i, ch in enumerate(c.chunks)]
-    ebs = np.repeat([2.0 * ch.eb for ch in c.chunks],
-                    [ch.n_values for ch in c.chunks])
-    return _finish_host(c, np.concatenate(parts), ebs)
+    return _finish_host(c, q.reshape(-1)[:int(c.n_values)],
+                        np.float64(2.0 * c.chunks[0].eb))
 
 
 @functools.partial(jax.jit, static_argnames=("ndim", "n", "work_shape"))
@@ -348,29 +384,18 @@ def _nd_cumsum(delta2, ndim, n, work_shape):
     return q.reshape(-1)
 
 
-def decompress_one_mega(q_rows, c) -> np.ndarray:
-    """Host finish for one array, given its megakernel-reconstructed q
-    rows (outliers patched and 1-D inverses already applied in-kernel;
-    higher-rank abs/rel rows arrive as deltas and take the multi-axis
-    cumsum here)."""
+def _mega_q(q_rows, c):
+    """Megakernel route, the device rest for one array: its q rows
+    (outliers patched and 1-D inverses applied in-kernel), cut to the
+    array's chunk width; higher-rank abs/rel rows arrive as deltas and
+    take the multi-axis cumsum here."""
     cv = int(c.chunks[0].n_values)
-    n = int(c.n_values)
     rows = q_rows[:, :cv]
-    if (getattr(c, "predictor", "lorenzo") == "none"
-            or c.mode == "fixed_ratio"):
-        # per-chunk rows are final q; per-chunk eb
-        q2 = np.asarray(rows)
-        parts = [q2[i, :ch.n_values] for i, ch in enumerate(c.chunks)]
-        ebs = np.repeat([2.0 * ch.eb for ch in c.chunks],
-                        [ch.n_values for ch in c.chunks])
-        return _finish_host(c, np.concatenate(parts), ebs)
-    if len(c.shape) == 1:
-        # flat Lorenzo chain: the kernel's segment carry already crossed
-        # the chunk boundaries
-        q = np.asarray(rows).reshape(-1)[:n]
-    else:
-        q = np.asarray(_nd_cumsum(rows, c.ndim, n, _work_shape(c)))
-    return _finish_host(c, q, np.float64(2.0 * c.chunks[0].eb))
+    if _per_chunk(c) or len(c.shape) == 1:
+        # per-chunk rows are final q; a flat Lorenzo chain's segment
+        # carry already crossed the chunk boundaries in the kernel
+        return rows
+    return _nd_cumsum(rows, c.ndim, int(c.n_values), _work_shape(c))
 
 
 def decompress_batch(comps: Sequence, block_size: int,
@@ -392,16 +417,29 @@ def decompress_batch(comps: Sequence, block_size: int,
     ``fused_decode_ok`` admits (tests/test_full_grid.py).
     """
     batch = _ChunkBatch(block_size, kernel_impl)
-    for c in comps:
-        batch.add_comp(c, offline, bank=bank)
+    with ot.span("fused_decode.stage"):
+        for c in comps:
+            batch.add_comp(c, offline, bank=bank)
+        extra = [] if megakernel else [_split_host_args(c) for c in comps]
     if not batch.counts:
         return []
     if megakernel:
         q_all = batch.run_mega()
-        return [decompress_one_mega(q_all[r0:r1], c)
-                for c, (r0, r1) in zip(comps, batch.spans)]
-    codes_all = batch.run()
-    out = []
-    for c, (r0, r1) in zip(comps, batch.spans):
-        out.append(decompress_one(codes_all[r0:r1], c))
-    return out
+        with ot.span("fused_decode.device_wait"):
+            qs = jax.block_until_ready(
+                [_mega_q(q_all[r0:r1], c)
+                 for c, (r0, r1) in zip(comps, batch.spans)])
+    else:
+        codes_all = batch.run()
+        with ot.span("fused_decode.h2d"):
+            extra = [to_device("decode", "fused_decode.h2d",
+                               *(jnp.asarray(a) for a in e))
+                     for e in extra]
+        with ot.span("fused_decode.device_wait"):
+            qs = jax.block_until_ready(
+                [_split_q(codes_all[r0:r1], c, *e)
+                 for c, (r0, r1), e in zip(comps, batch.spans, extra)])
+    with ot.span("fused_decode.d2h"):
+        qs = to_host("decode", "fused_decode.d2h", *qs)
+    with ot.span("fused_decode.finish"):
+        return [_finish(c, q) for c, q in zip(comps, qs)]

@@ -57,17 +57,6 @@ def test_spans_record_nesting_and_args(tracer):
     assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-3
 
 
-def test_traced_decorator(tracer):
-    @ot.traced("my.op")
-    def f(a, b):
-        return a + b
-
-    assert f(2, 3) == 5
-    assert [e["name"] for e in tracer.events()] == ["my.op"]
-    ot.disable()
-    assert f(2, 3) == 5                    # disabled path still calls through
-
-
 def test_chrome_export_shape_and_thread_names(tracer, tmp_path):
     def work():
         with ot.span("threaded"):
@@ -206,8 +195,8 @@ def test_from_meta_is_lenient():
 def test_measure_counts_per_op_impl():
     key = om.KERNEL_CALLS + '{impl="jnp",op="hufenc"}'
     before = om.snapshot().get(key, 0)
-    with dispatch.measure("hufenc", "jnp") as m:
-        m.done(np.zeros(3))
+    with dispatch.measure("hufenc", "jnp"):
+        pass
     with dispatch.measure("hufenc", "jnp"):
         pass
     assert om.snapshot()[key] == before + 2
@@ -221,22 +210,6 @@ def test_measure_auto_resolves_concrete_impl():
     with dispatch.measure("hufdec", "auto"):
         pass
     assert om.snapshot()[key] == before + 1
-
-
-def test_opt_in_timing_records_histogram():
-    hkey = om.KERNEL_SECONDS + '{impl="jnp",op="hufenc"}'
-    before = om.snapshot().get(hkey, {"count": 0})["count"] \
-        if isinstance(om.snapshot().get(hkey), dict) else 0
-    assert not dispatch.timing_enabled()   # default hot path is sync-free
-    dispatch.set_timing(True)
-    try:
-        import jax.numpy as jnp
-        with dispatch.measure("hufenc", "jnp") as m:
-            m.done(jnp.arange(8))
-    finally:
-        dispatch.set_timing(False)
-    after = om.snapshot()[hkey]
-    assert after["count"] == before + 1 and after["sum"] >= 0
 
 
 # -- engines: traced overlap + embedded manifest round-trip ------------------
