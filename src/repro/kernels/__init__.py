@@ -8,7 +8,8 @@ explicit BlockSpec VMEM tiling), ops.py (jit'd public wrapper), ref.py
                (+ the radix-select per-chunk centre reduction)
   histogram  — 1024-bin quant-code histogram (one-hot partial sums)
   hufenc     — Huffman encode: serial per-block packer + the fused
-               pipeline's gather-pack (contiguous wire layout)
+               pipeline's contiguous-wire-layout pack (Pallas
+               gather-pack; its jnp twin is a prefix-sum pack)
   hufdec     — canonical-Huffman table decode (block-parallel bit walk)
   bitpack    — fixed-width b-bit pack/unpack (fixed-ratio collective path)
   megakernel — the bank-mode encode hot path as ONE program per chunk

@@ -1,9 +1,10 @@
 """Backend dispatch for the CEAZ inner-loop kernels.
 
 The fused pipeline has exactly two per-value hot loops — the encode-side
-gather-pack (`hufenc`) and the decode-side canonical-table walk
-(`hufdec`). Each has interchangeable implementations with one calling
-convention and a bit-exact output contract:
+bit-pack (`hufenc`: a prefix-sum pack under 'jnp', a gather-pack under
+'pallas') and the decode-side canonical-table walk (`hufdec`). Each has
+interchangeable implementations with one calling convention and a
+bit-exact output contract:
 
   * ``'jnp'``    — pure jax.numpy, XLA-compiled; what ``'auto'`` runs on
     every backend today (and the reference the Pallas sweeps compare

@@ -105,7 +105,7 @@ def _compose_words(ends, starts, lens, vals, w_bit, cands: int):
     replays searchsorted(ends, w_bit, side='right') — #(ends <= w_bit),
     the first symbol covering each word — then the candidate window is
     gathered and summed (bit-disjoint => sum == or). Bit-identical to
-    ref.encode_pack's per-word composition.
+    ref.encode_pack.
     """
     n = ends.shape[0]
     nw = w_bit.shape[0]

@@ -8,10 +8,12 @@ Two entry points, one per packing layout:
     completely independent, which is what makes the allclose sweep
     meaningful.
   * ``encode_pack`` — the `hufenc` dispatch op's 'jnp' implementation
-    (contiguous per-chunk wire layout, the fused pipeline's pass 2). It
-    doubles as the bit-identity reference for the Pallas gather-pack
-    kernel; the staged ``core.huffman.encode`` remains the ground-truth
-    oracle for both.
+    (contiguous per-chunk wire layout, the fused pipeline's pass 2): a
+    symbol-side prefix-sum pack, dense per-symbol work plus one placement
+    per output word, no search and no candidate window. It doubles as
+    the bit-identity reference for the Pallas gather-pack kernel; the
+    staged ``core.huffman.encode`` remains the ground-truth oracle for
+    both.
 """
 from __future__ import annotations
 
@@ -54,46 +56,48 @@ def hufenc(codes: jax.Array, codewords: jax.Array, lengths: jax.Array):
 
 
 # ---------------------------------------------------------------------------
-# Gather-pack (fused-pipeline wire layout): the `hufenc` op's 'jnp' impl
+# Prefix-sum pack (fused-pipeline wire layout): the `hufenc` op's 'jnp' impl
 # ---------------------------------------------------------------------------
 
-def _encode_one(codes, valid, lengths, cwords, block_size, w32, cands):
+def _encode_one(codes, valid, lengths, cwords, block_size, w32):
     """One chunk: symbol codes -> packed u32 bitstream (host-layout).
 
-    Replicates core.huffman.encode bit-for-bit, but scatter-free: for
-    each OUTPUT word, searchsorted on the cumulative bit offsets finds
-    the first overlapping symbol and the `cands`-candidate window is
-    gathered and OR-composed. Gathers vectorize on every backend; the
-    scatter formulation serializes on CPU XLA. The window is walked one
-    candidate at a time, so temporaries stay O(w32) instead of
-    O(w32 * cands) — a paper-scale 2^23-value chunk fits one chip.
+    Replicates core.huffman.encode bit-for-bit, cut at u32 grain and
+    truncated to `w32` words. Symbol-side: every symbol splits its
+    codeword into `hi`, the bits in the word it starts in, and `lo`, the
+    bits that spill into the next word. A codeword of <= 32 bits leaves
+    no word up to the last one without a symbol starting in it, and only
+    a word's last symbol can spill, into the word its successor starts
+    in: so symbol i contributes hi[i] + lo[i-1] to word[i] and nothing
+    elsewhere. Contributions are bit-disjoint, so a word's OR is its sum
+    and the wrapping u32 prefix sum P of those is exact. One placement
+    of P per word, at its last symbol, leaves word k = P[k] - P[k-1];
+    the word after the last one holds only the last symbol's spill.
     """
-    cv = codes.shape[0]
     lens = jnp.where(valid, lengths[codes], 0)
     vals = jnp.where(valid, cwords[codes], 0).astype(jnp.uint32)
     ends = jnp.cumsum(lens)
     starts = (ends - lens).astype(jnp.int32)
 
-    w_bit = jnp.arange(w32, dtype=jnp.int32) * 32
-    first = jnp.searchsorted(ends, w_bit, side="right")   # covers bit w_bit
+    word = starts >> 5
+    left = 32 - (starts & 31) - lens                     # < 0: spills
+    ls = jnp.clip(left, 0, 31).astype(jnp.uint32)
+    rs = jnp.clip(-left, 0, 31).astype(jnp.uint32)
+    hi = jnp.where(left >= 0, vals << ls, vals >> rs)
+    lo_sh = jnp.clip(32 + left, 0, 31).astype(jnp.uint32)
+    lo = jnp.where(left < 0, vals << lo_sh, jnp.uint32(0))
+    psum = jnp.cumsum(hi + jnp.pad(lo[:-1], (1, 0)),    # wraps: exact
+                      dtype=jnp.uint32)
 
-    def add_candidate(j, words):
-        cand = first + j
-        ci = jnp.clip(cand, 0, cv - 1)
-        off = starts[ci] - w_bit
-        ln = lens[ci]
-        v = vals[ci]
-        left = 32 - off - ln
-        live = (cand < cv) & (off < 32) & (off + ln > 0)
-        ls = jnp.clip(left, 0, 31).astype(jnp.uint32)
-        rs = jnp.clip(-left, 0, 31).astype(jnp.uint32)
-        shifted = jnp.where(left >= 0, v << ls, v >> rs)
-        # live contributions are bit-disjoint => sum == or
-        return words + jnp.where(live, shifted, jnp.uint32(0))
+    last = jnp.append(word[1:] != word[:-1], True)      # word's last symbol
+    tgt = jnp.where(last, word, w32)                    # w32: dropped
+    placed = jnp.zeros((w32,), jnp.uint32).at[tgt].set(psum, mode="drop")
+    k = jnp.arange(w32, dtype=jnp.int32)
+    words = jnp.where(k <= word[-1],
+                      placed - jnp.pad(placed[:-1], (1, 0)),
+                      jnp.where(k == word[-1] + 1, lo[-1], jnp.uint32(0)))
 
-    words = jax.lax.fori_loop(0, cands, add_candidate,
-                              jnp.zeros((w32,), jnp.uint32))
-
+    cv = codes.shape[0]
     nblocks = -(-cv // block_size)
     lens_p = jnp.pad(lens, (0, nblocks * block_size - cv))
     block_nbits = lens_p.reshape(nblocks, block_size).sum(axis=1)
@@ -109,10 +113,12 @@ def encode_pack(codes2, valid2, lengths_tbl, cwords_tbl, block_size, w32,
     codebook tables (C, 1024)) -> (words (C, w32) u32, block_nbits
     (C, nblocks) i32) in the contiguous per-chunk wire layout. w32 is
     sized by the caller from the EXACT per-chunk payload bits
-    (hist . lengths, free on the host), bucketed — the gather work
-    tracks the real bit-rate instead of the 16-bit worst case.
+    (hist . lengths, free on the host), bucketed. `cands`, the
+    candidate-window size of the Pallas gather-pack, is part of the op's
+    calling convention; the prefix-sum pack needs no window and ignores
+    it.
     """
+    del cands
     return jax.vmap(
-        lambda c, v, ln, cw: _encode_one(c, v, ln, cw, block_size, w32,
-                                         cands))(
+        lambda c, v, ln, cw: _encode_one(c, v, ln, cw, block_size, w32))(
         codes2, valid2, lengths_tbl, cwords_tbl)

@@ -2,7 +2,7 @@
 
 Composed from the EXISTING stage implementations — core.dualquant for
 the quantizers, the dualquant `chunk_center` reduction, the histogram
-scatter-add and the hufenc gather-pack reference — so its outputs are
+scatter-add and the hufenc jnp pack (`encode_pack`) — so its outputs are
 bitwise-identical to the staged fused pipeline (runtime/fused.py's
 `_bank_pass_fn` core) by construction, and serve as the bit-identity
 fence for the Pallas megakernel.

@@ -165,16 +165,19 @@ def _extract_sparse(mask, values, k):
 # Pass 2: batched Huffman encode + bit-pack + outlier compaction
 # ---------------------------------------------------------------------------
 
-# A Huffman codeword is at most MAX_CODE_BITS=16 bits, so every real
-# symbol occupies >= 1 bit: at most 32 symbols START inside one 32-bit
-# output word, plus one that spills in from the left — 33 candidates in
-# the worst case. The host shrinks the window when the batch's codebooks
-# have a larger minimum code length (bucketed to bound recompiles).
-# The gather-pack itself lives behind the kernel-dispatch layer
-# (kernels/dispatch.py, op 'hufenc'): 'jnp' is the scatter-free
-# searchsorted+gather formulation (kernels/hufenc/ref.py), 'pallas' the
-# explicit VMEM-resident kernel (kernels/hufenc/kernel.py); both are
-# bit-identical and selected via CEAZConfig(kernel_impl=...).
+# The pack lives behind the kernel-dispatch layer (kernels/dispatch.py,
+# op 'hufenc'): 'jnp' is the symbol-side prefix-sum pack
+# (kernels/hufenc/ref.py), 'pallas' the explicit VMEM-resident
+# gather-pack kernel (kernels/hufenc/kernel.py); both are bit-identical
+# and selected via CEAZConfig(kernel_impl=...). The gather-pack composes
+# each output word from a window of candidate symbols: a Huffman
+# codeword is at most MAX_CODE_BITS=16 bits, so every real symbol
+# occupies >= 1 bit, at most 32 symbols START inside one 32-bit output
+# word, plus one that spills in from the left — 33 candidates in the
+# worst case. The host shrinks the window when the batch's codebooks
+# have a larger minimum code length (bucketed to bound recompiles); the
+# prefix-sum pack takes the window as part of the op's calling
+# convention and ignores it.
 _CANDS = 33
 _CAND_BUCKETS = (9, 17, 33)          # min code length >= 4 / >= 2 / >= 1
 

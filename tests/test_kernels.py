@@ -255,6 +255,167 @@ def test_encode_pack_routes_through_tiled(rng):
     np.testing.assert_array_equal(np.asarray(no), np.asarray(nr))
 
 
+# -- jnp prefix-sum pack vs the staged huffman.encode ground truth -----------
+
+def _book(lengths):
+    lengths = np.asarray(lengths, np.uint8)
+    return H.Codebook(lengths=lengths, codes=H._canonize(lengths))
+
+
+def _book_of(lens):
+    """A canonical book of the {symbol: length} in `lens`; every other
+    symbol is unused."""
+    L = np.zeros(1024, int)
+    L[list(lens)] = list(lens.values())
+    return _book(L)
+
+
+def _staged_u32(codes, valid, cb, block_size, w32):
+    """huffman.encode of the valid symbols as MSB-first u32 words cut
+    to w32, and its block bit counts over the whole (padded) row."""
+    lens = np.where(valid, cb.lengths[codes], 0).astype(np.int64)
+    nblocks = -(-codes.size // block_size)
+    nbits = np.pad(lens, (0, nblocks * block_size - codes.size)) \
+        .reshape(nblocks, block_size).sum(axis=1)
+    out = np.zeros(w32, np.uint32)
+    if valid.any():
+        w64, _, _ = H.encode(codes[valid], cb, block_size)
+        u32 = np.stack([w64 >> np.uint64(32),
+                        w64 & np.uint64(0xFFFFFFFF)], 1).reshape(-1)
+        m = min(w32, u32.size)
+        out[:m] = u32[:m]
+    return out, nbits
+
+
+def _words_for(codes, valid, books, spare=1):
+    need = max(int(np.where(v, b.lengths[c], 0).sum())
+               for c, v, b in zip(codes, valid, books))
+    return max(1, -(-need // 32) + spare)
+
+
+def _pack_spill_only_last_word(rng):
+    # seven 5-bit symbols: the last starts at bit 30 and spills 3 bits
+    # into word 1, which no symbol starts in
+    codes = np.full((1, 7), 3, np.int32)
+    books = [_book_of({3: 5, 4: 5})]
+    return codes, np.ones_like(codes, bool), books, 4, 4
+
+
+def _pack_word_boundary_end(rng):
+    # 4-bit symbols: the 8th ends exactly on the first word boundary;
+    # rows end there, carry on past it, and stop there under a mask
+    codes = rng.choice([1, 2], (3, 24)).astype(np.int32)
+    valid = np.ones((3, 24), bool)
+    valid[0, 8:] = False
+    valid[2, 16:] = False
+    books = [_book_of({1: 4, 2: 4})] * 3
+    return codes, valid, books, 8, 4
+
+
+def _pack_one_bit_book(rng):
+    codes = rng.integers(0, 2, (2, 5000)).astype(np.int32)
+    valid = np.ones_like(codes, bool)
+    valid[1, 4321:] = False
+    books = [_book_of({0: 1, 1: 1})] * 2
+    return codes, valid, books, 1024, _words_for(codes, valid, books)
+
+
+def _pack_sixteen_bit_book(rng):
+    # row 0 on the word grid; row 1 shifted off it by one 1-bit symbol,
+    # so a codeword spills into every word after the first
+    L = np.full(1024, 16)
+    L[0] = 1
+    codes = rng.integers(1, 1024, (2, 3000)).astype(np.int32)
+    codes[1, 0] = 0
+    valid = np.ones_like(codes, bool)
+    books = [_book(L)] * 2
+    return codes, valid, books, 1024, _words_for(codes, valid, books)
+
+
+def _pack_prefix_masks(rng):
+    codes, lengths, _ = _pack_case(rng, 3, 3000)
+    valid = np.arange(3000)[None, :] < np.array([[0], [1], [3000]])
+    books = [_book(lengths[0])] * 3
+    return codes, valid, books, 1024, _words_for(codes, valid, books)
+
+
+def _pack_rows_own_books(rng):
+    books, rows = [], []
+    for sigma in (2, 30, 300):
+        row = np.clip(rng.normal(512, sigma, 4000), 0, 1023) \
+            .astype(np.int32)
+        books.append(_book(H.Codebook.from_freqs(
+            np.bincount(row, minlength=1024) + 1).lengths))
+        rows.append(row)
+    codes = np.stack(rows)
+    valid = np.ones_like(codes, bool)
+    valid[0, 3333:] = False
+    return codes, valid, books, 512, _words_for(codes, valid, books)
+
+
+def _pack_fills_w32(rng):
+    # 2-bit symbols: 16 per word, 64 words exactly, no spare word
+    codes = rng.integers(0, 4, (2, 1024)).astype(np.int32)
+    valid = np.ones_like(codes, bool)
+    books = [_book_of({0: 2, 1: 2, 2: 2, 3: 2})] * 2
+    assert _words_for(codes, valid, books, spare=0) == 64
+    return codes, valid, books, 256, 64
+
+
+_PACK_CASES = {
+    "spill_only_last_word": _pack_spill_only_last_word,
+    "word_boundary_end": _pack_word_boundary_end,
+    "one_bit_book": _pack_one_bit_book,
+    "sixteen_bit_book": _pack_sixteen_bit_book,
+    "prefix_masks_0_1_all": _pack_prefix_masks,
+    "rows_own_books": _pack_rows_own_books,
+    "fills_w32": _pack_fills_w32,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PACK_CASES))
+def test_encode_pack_vs_staged_encode(case, rng):
+    """The jnp `hufenc` op (prefix-sum pack) is bit-identical to the
+    staged huffman.encode, row by row, at the wire's u32 grain."""
+    codes, valid, books, block_size, w32 = _PACK_CASES[case](rng)
+    words, nbits = ER.encode_pack(
+        jnp.asarray(codes), jnp.asarray(valid),
+        jnp.asarray(np.stack([b.lengths.astype(np.int32) for b in books])),
+        jnp.asarray(np.stack([b.codes.astype(np.uint32) for b in books])),
+        block_size, w32, 33)
+    for i, cb in enumerate(books):
+        want_w, want_n = _staged_u32(codes[i], valid[i], cb, block_size,
+                                     w32)
+        np.testing.assert_array_equal(np.asarray(words)[i], want_w)
+        np.testing.assert_array_equal(np.asarray(nbits)[i], want_n)
+
+
+def test_encode_pack_bank_overflow_repack_vs_staged_encode(rng):
+    """A row past the bank's 8-bit pack provision re-packs at full
+    capacity through the runtime's retry, bit-identical to the staged
+    encode."""
+    from repro.runtime import fused
+    cv, block_size = 6000, 1024
+    codes = rng.integers(0, 1024, (2, cv)).astype(np.int32)  # 10 bits
+    valid = np.ones_like(codes, bool)
+    valid[1, 5555:] = False
+    cb = _book(np.full(1024, 10))
+    totals = np.array([int(cb.lengths[r[v]].sum())
+                       for r, v in zip(codes, valid)])
+    w32_full = fused._bank_w32(int(cb.lengths.max()), cv)
+    assert not fused._bank_fits(
+        totals, fused._bank_w32(fused.BANK_PROVISION_BITS, cv))
+    words, nbits = fused._bank_repack_fn("jnp", block_size, w32_full, 33)(
+        jnp.asarray(codes), jnp.asarray(valid),
+        jnp.asarray(np.broadcast_to(cb.lengths.astype(np.int32), (2, 1024))),
+        jnp.asarray(np.broadcast_to(cb.codes.astype(np.uint32), (2, 1024))))
+    for i in range(2):
+        want_w, want_n = _staged_u32(codes[i], valid[i], cb, block_size,
+                                     w32_full)
+        np.testing.assert_array_equal(np.asarray(words)[i], want_w)
+        np.testing.assert_array_equal(np.asarray(nbits)[i], want_n)
+
+
 # -- dq_center radix-select kernel -------------------------------------------
 
 def test_dq_center_kernel_vs_ref(rng):
