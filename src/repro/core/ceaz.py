@@ -339,19 +339,21 @@ class CEAZ:
                                              self._coder()))
             coder = BankCoder(self.bank)
             c = self._compress_routed(x, word_bits, fused_ok, coder)
-            om.set_gauge(om.BANK_DRIFT, coder.drift())
-            if coder.drift() > self.cfg.bank_drift_tol:
-                # out-of-distribution input: fall back to the exact
-                # two-pass path for the whole array (drift is replayed on
-                # host from the histogram summaries the bank pass already
-                # produced)
-                om.add(om.BANK_FALLBACKS)
-                with ot.span("ceaz.bank_exact_fallback",
-                             drift=coder.drift()):
-                    return self._note_compressed(
-                        x, self._compress_routed(x, word_bits, fused_ok,
-                                                 self._coder()))
-            return self._note_compressed(x, c)
+            return self._drift_checked(x, c, coder, word_bits, fused_ok)
+
+    def _drift_checked(self, x: np.ndarray, c: CEAZCompressed, coder,
+                       word_bits: int, fused_ok: bool) -> CEAZCompressed:
+        """A bank compress `c` of `x`, or, where its coder drifted past
+        ``cfg.bank_drift_tol`` (out-of-distribution input), the exact
+        two-pass path's compress of the whole array (drift is replayed on
+        the host from the histogram summaries the bank pass produced)."""
+        om.set_gauge(om.BANK_DRIFT, coder.drift())
+        if coder.drift() > self.cfg.bank_drift_tol:
+            om.add(om.BANK_FALLBACKS)
+            with ot.span("ceaz.bank_exact_fallback", drift=coder.drift()):
+                c = self._compress_routed(x, word_bits, fused_ok,
+                                          self._coder())
+        return self._note_compressed(x, c)
 
     @staticmethod
     def _note_compressed(x: np.ndarray, c: CEAZCompressed) -> CEAZCompressed:
@@ -390,13 +392,23 @@ class CEAZ:
             takes per-shard :meth:`compress`, which still routes
             through the fused pipeline when enabled — nothing is split
             out to per-array staged calls.
+            A rank-sharded ``jax.Array`` (see :meth:`rank_sharded`)
+            in bank mode is compressed where it lives instead: each
+            device runs the bank pass on its own brick, at the bound of
+            its own brick's range, and the packed payloads are gathered
+            to the first rank's device (``fused.compress_bank_sharded``);
+            a rank whose coder drifts recompresses alone on the exact
+            path. Its rows compress as they would one by one.
           plan: optional ``ShardingPlan``; when it carries a mesh the
-            batched pass is GSPMD-sharded over its batch axes.
+            batched exact-codebook pass is GSPMD-sharded over its batch
+            axes. Bank mode compresses shard by shard and ignores it.
 
         Returns one :class:`CEAZCompressed` per shard, in order; each
         shard keeps its own adaptive-coder stream, so batching never
         changes the bytes. Raises as :meth:`compress`.
         """
+        if self.rank_sharded(shards):
+            return self._compress_rank_sharded(shards)
         shards = [np.asarray(s) for s in shards]
         out: List[Optional[CEAZCompressed]] = [None] * len(shards)
         preds: dict = {}               # probe once; leftovers reuse it
@@ -435,6 +447,37 @@ class CEAZ:
                           s, self._compress_eb_fused(s, preds[i]))
                       if i in preds else self.compress(s))
                 for i, (c, s) in enumerate(zip(out, shards))]
+
+    def rank_sharded(self, x) -> bool:
+        """Whether :meth:`compress_batch` compresses `x` on its devices:
+        a float32 ``jax.Array`` whose leading axis is split one row (a
+        rank's brick) per device along one mesh axis, under fused
+        error-bounded bank mode with the Lorenzo predictor."""
+        if not (self.cfg.use_fused and self.cfg.mode in ("abs", "rel")
+                and self.cfg.predictor == "lorenzo"
+                and getattr(x, "dtype", None) == np.float32
+                and hasattr(x, "addressable_shards") and self._bank_mode()):
+            return False
+        from ..runtime import fused
+        return fused.rank_mesh_axis(x) is not None
+
+    def _compress_rank_sharded(self, x) -> List[CEAZCompressed]:
+        from ..runtime import fused
+        ranks = x.shape[0]
+        with ot.span("ceaz.compress", shape=list(x.shape), dtype="float32",
+                     mode=self.cfg.mode, ranks=ranks):
+            with ot.span("ceaz.policy"):
+                if self.cfg.mode == "abs":
+                    ebs = [self.cfg.eb] * ranks
+                else:
+                    ebs = [self.cfg.eb * dq.extrema_range(hi, lo)
+                           for hi, lo in fused.rank_extrema(x)]
+            coders = [BankCoder(self.bank) for _ in range(ranks)]
+            comps, bricks = fused.compress_bank_sharded(
+                x, ebs, self.cfg.mode, coders, self._chunk_values(32),
+                self.cfg.block_size, kernel_impl=self.cfg.kernel_impl)
+            return [self._drift_checked(b, c, coder, 32, True)
+                    for b, c, coder in zip(bricks, comps, coders)]
 
     def _coder(self) -> AdaptiveCoder:
         return AdaptiveCoder(self.offline, self.cfg.tau0, self.cfg.tau1,

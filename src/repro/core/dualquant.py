@@ -38,7 +38,12 @@ def value_range(x: np.ndarray) -> float:
     non-finite or constant arrays still get a finite bound. Shared by
     the facade, the rate-control calibration and the batched fused
     path so grouping never changes the bound."""
-    vrange = float(np.max(x)) - float(np.min(x))
+    return extrema_range(float(np.max(x)), float(np.min(x)))
+
+
+def extrema_range(hi: float, lo: float) -> float:
+    """:func:`value_range` from an array's max and min."""
+    vrange = float(hi) - float(lo)
     return vrange if np.isfinite(vrange) and vrange != 0.0 else 1.0
 
 

@@ -7,6 +7,14 @@ all_gather(packed) -> unpack -> reconstruct. Static shapes throughout
 the paper's constant-throughput FPGA requirement), and uniform payload
 sizes mean the gather has no size-stragglers.
 
+`ceaz_gather_stream` of a rank-sharded `jax.Array` (one brick per device
+along a mesh axis) is the error-bounded gather: each device compresses
+its own brick in the bank pass, and `gather_to_aggregator` moves the
+packed payload buffers, at their static provisioned sizes, to the
+aggregator device, which the host then pulls once. Field values never
+cross between devices; the padding the static sizes carry is counted
+(`ceaz_gather_pad_bytes_total`).
+
 `gather_with_deadline` is the host-level straggler-mitigation wrapper used
 by the I/O examples: ranks that miss the deadline are excluded from the
 round and their shards backfilled from the previous round (bounded
@@ -15,6 +23,7 @@ staleness), which is the standard trick for jittery storage paths.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, List, Optional
 
@@ -23,6 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..obs import metrics as om
+from ..obs import trace as ot
 from ..optim.grad_compress import pack_jnp, unpack_jnp
 
 
@@ -81,15 +92,71 @@ def compressed_all_gather(x, mesh: Mesh, axis: str,
                          axis_names={axis})(x)
 
 
+@functools.lru_cache(maxsize=None)
+def _gather_fn(mesh: Mesh, axis: str, n_arrays: int):
+    spec = P(axis)
+
+    def per_rank(*local):
+        flat = [(a if a.dtype == jnp.uint32
+                 else jax.lax.bitcast_convert_type(a, jnp.uint32))
+                .reshape(-1) for a in local]
+        # each device's gathered copy, as its own row of the result
+        return jax.lax.all_gather(jnp.concatenate(flat), axis)[None]
+
+    def ceaz_gather_payload(*arrays):
+        return jax.shard_map(per_rank, mesh=mesh,
+                             in_specs=(spec,) * n_arrays, out_specs=spec,
+                             axis_names={axis})(*arrays)
+
+    return jax.jit(ceaz_gather_payload)
+
+
+def gather_to_aggregator(arrays, mesh: Mesh, axis: str, aggregator):
+    """Gather rank-sharded 32-bit arrays to the aggregator device.
+
+    Each of `arrays` is split along its leading axis over the mesh axis
+    `axis`, rank r's piece on rank r's device. Every rank flattens its
+    pieces into one uint32 buffer and the buffers are all-gathered over
+    `axis`, which at a host's few ranks costs what a gather to one
+    device costs; the host then pulls the aggregator's copy alone.
+    Returns, per rank in axis order, its pieces as host arrays of their
+    own dtype and per-rank shape."""
+    shapes = [(a.sharding.shard_shape(a.shape), a.dtype) for a in arrays]
+    if any(np.dtype(d).itemsize != 4 for _, d in shapes):
+        raise TypeError("gather_to_aggregator moves 32-bit arrays only")
+    with ot.span("collectives.gather", ranks=mesh.shape[axis]):
+        out = _gather_fn(mesh, axis, len(arrays))(*arrays)
+        agg, = [s.data for s in out.addressable_shards
+                if s.device == aggregator]
+        with ot.span("collectives.device_wait"):
+            agg = jax.block_until_ready(agg)
+        with ot.span("collectives.d2h"):
+            host = np.asarray(agg)[0]
+            om.add(om.D2H_BYTES, int(host.nbytes), side="encode",
+                   site="collectives.d2h")
+    rows = []
+    for buf in host:
+        row, off = [], 0
+        for shape, dtype in shapes:
+            n = int(np.prod(shape))
+            row.append(buf[off:off + n].view(dtype).reshape(shape))
+            off += n
+        rows.append(row)
+    return rows
+
+
 def ceaz_gather(shards, eb_rel: float = 1e-4, plan=None,
                 chunk_values: int = 1 << 20, block_size: int = 4096):
     """Host-level compressed gather: the paper's MPI_Gather scenario.
 
-    Every rank's shard is compressed through the device-resident fused
-    pipeline in ONE batched trace (mesh-sharded when `plan` carries a
-    mesh), then only the packed payloads are 'gathered' (returned with
-    wire-size stats). Ranks with unequal shard shapes (the usual
-    smaller-last-rank case) fall back to per-rank fused passes.
+    Every rank's host shard is compressed through the device-resident
+    fused pipeline in ONE batched trace, then only the packed payloads
+    are 'gathered' (returned with wire-size stats). The facade here
+    keeps the exact codebook, so a `plan` carrying a mesh GSPMD-shards
+    that batched pass over its batch axes; in bank mode
+    `CEAZ.compress_batch` compresses host shards one by one and ignores
+    `plan`. Ranks with unequal shard shapes (the usual smaller-last-rank
+    case) fall back to per-rank fused passes.
     Returns (compressed_list, stats) where stats reports raw vs wire
     bytes — the paper's Fig 17 quantity.
     """
@@ -149,35 +216,79 @@ def read_gather_stream(path: str, block_size: Optional[int] = None,
 def ceaz_gather_stream(shards, path: str, eb_rel: float = 1e-4,
                        plan=None, chunk_values: int = 1 << 20,
                        block_size: int = 4096, group: int = 2,
-                       overlap: bool = True):
+                       overlap: bool = True, *, comp=None, origins=None,
+                       decomposition=None):
     """Streaming gather: rank shards land in one indexed stream file.
 
-    The aggregator's view of MPI_Gather + write: as each group of rank
-    shards finishes its fused device compression, its payloads are
-    already committing to the aggregated stream while the next group
-    compresses (two-phase aggregation with the phases overlapped).
-    `shards` may also contain callables — a rank "arriving" is its
-    fetcher being called, so slow ranks overlap the commits of earlier
-    ones. Returns gather stats incl. wire bytes (the Fig 17 quantity).
+    The aggregator's view of MPI_Gather + write, one record per rank
+    (`rank_0000`, `rank_0001`, ... in rank order), under footer meta
+    `kind: "gather"`. `comp` is the facade to compress with; by default
+    one is built from `eb_rel`, `chunk_values` and `block_size` (the
+    exact codebook). `origins[r]`, where given, is rank r's offset in the
+    global field and lands in its record meta beside its shape;
+    `decomposition` (any JSON object) lands in the footer meta.
+
+    Two routes, chosen from what `shards` is:
+
+    * a rank-sharded `jax.Array` (its leading axis split one brick per
+      device along one mesh axis, see `fused.rank_mesh_axis`) under a
+      bank-mode `comp`: `comp.compress_batch(shards)` compresses every
+      brick on its own device in one program and gathers the packed
+      payloads to the aggregator device (`gather_to_aggregator`); the
+      records commit through the async engine.
+    * host shards (a sequence of arrays, or callables that return one —
+      a rank "arriving" is its fetcher being called, so slow ranks
+      overlap the commits of earlier ones): each group of `group` shards
+      is one `compress_batch` call (with `plan`, which only the exact
+      codebook uses) whose payloads commit while the next group
+      compresses (two-phase aggregation with the phases overlapped).
+
+    Returns gather stats incl. wire bytes (the Fig 17 quantity).
     """
     from ..core import CEAZ, CEAZConfig
     from . import engine as E
-    comp = CEAZ(CEAZConfig(mode="rel", eb=eb_rel, use_fused=True,
-                           chunk_bytes=4 * chunk_values,
-                           block_size=block_size))
+    if comp is None:
+        comp = CEAZ(CEAZConfig(mode="rel", eb=eb_rel, use_fused=True,
+                               chunk_bytes=4 * chunk_values,
+                               block_size=block_size))
+    meta = {"kind": "gather", "eb_rel": comp.cfg.eb}
+    if decomposition is not None:
+        meta["decomposition"] = decomposition
+
+    def record_meta(r, shape, dtype, nbytes):
+        m = {"shape": list(shape), "dtype": str(dtype),
+             "raw_nbytes": int(nbytes)}
+        if origins is not None:
+            m["origin"] = [int(o) for o in origins[r]]
+        return m
+
+    sharded = comp.rank_sharded(shards)
+    compress_fn = ((lambda keys, ranks: comp.compress_batch(shards))
+                   if sharded else E.ceaz_compress_fn(comp, plan))
     eng = E.AsyncCompressWriteEngine(
-        path, E.ceaz_compress_fn(comp, plan),
-        sync=not overlap, meta={"kind": "gather", "eb_rel": eb_rel},
-        block_size=block_size)
+        path, compress_fn, sync=not overlap, meta=meta,
+        block_size=comp.cfg.block_size,
+        codebook_bank=(comp.bank.to_meta() if comp.bank is not None
+                       else None),
+        config=comp.cfg)
     with eng:
-        shards = list(shards)
-        for s in range(0, len(shards), max(1, group)):
-            grp = [np.asarray(sh() if callable(sh) else sh)
-                   for sh in shards[s:s + max(1, group)]]
+        if sharded:
+            ranks = shards.shape[0]
+            brick = shards.shape[1:]
+            nbytes = shards.nbytes // ranks
             eng.submit_batch(
-                [f"rank_{s + j:04d}" for j in range(len(grp))], grp,
-                [{"shape": list(a.shape), "dtype": str(a.dtype),
-                  "raw_nbytes": int(a.nbytes)} for a in grp])
+                [f"rank_{r:04d}" for r in range(ranks)], list(range(ranks)),
+                [record_meta(r, brick, shards.dtype, nbytes)
+                 for r in range(ranks)])
+        else:
+            shards = list(shards)
+            for s in range(0, len(shards), max(1, group)):
+                grp = [np.asarray(sh() if callable(sh) else sh)
+                       for sh in shards[s:s + max(1, group)]]
+                eng.submit_batch(
+                    [f"rank_{s + j:04d}" for j in range(len(grp))], grp,
+                    [record_meta(s + j, a.shape, a.dtype, a.nbytes)
+                     for j, a in enumerate(grp)])
     d = eng.stats.as_dict()
     return dict(raw_bytes=d["raw_bytes"], wire_bytes=d["stored_bytes"],
                 ratio=d["raw_bytes"] / max(d["stored_bytes"], 1),

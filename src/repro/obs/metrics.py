@@ -41,7 +41,8 @@ __all__ = [
     "BANK_DRIFT",
     "BANK_FALLBACKS", "BANK_REPACKS", "QUEUE_DEPTH", "CORRUPTION",
     "KERNEL_CALLS", "H2D_BYTES", "D2H_BYTES", "PASS_VALUES",
-    "PASS_LIVE_VALUES",
+    "PASS_LIVE_VALUES", "GATHER_PAYLOAD_BYTES", "GATHER_PAD_BYTES",
+    "GATHER_RANKS",
     "PAGE_HITS", "PAGE_MISSES", "PAGE_EVICTIONS", "PAGE_CACHE_BYTES",
 ]
 
@@ -74,6 +75,12 @@ D2H_BYTES = "ceaz_d2h_bytes_total"
 # labels: side=, op= (the dispatch op of the pass)
 PASS_VALUES = "ceaz_pass_values_total"
 PASS_LIVE_VALUES = "ceaz_pass_live_values_total"
+# rank-sharded bank compression gathered to the aggregator
+# (io/collectives.py): bytes of the gathered payload buffers that the
+# ranks' records read, the rest of those buffers, and ranks gathered
+GATHER_PAYLOAD_BYTES = "ceaz_gather_payload_bytes_total"
+GATHER_PAD_BYTES = "ceaz_gather_pad_bytes_total"
+GATHER_RANKS = "ceaz_gather_ranks_total"
 # decode-on-demand parameter paging (serve/paging.py)
 PAGE_HITS = "ceaz_page_hits_total"                 # cache hits (layer reads)
 PAGE_MISSES = "ceaz_page_misses_total"             # decode-on-demand page-ins

@@ -62,6 +62,9 @@ LEAF_KINDS: Dict[str, str] = {
     "engine.serialize": "host",
     "engine.commit": "host",
     "engine.finalize": "host",          # footer, fsync, rename
+    # rank-sharded write: the gather of the payloads to the aggregator
+    "collectives.device_wait": "wait",  # block on the pass and the gather
+    "collectives.d2h": "transfer",      # pull of the aggregator's buffer
     # read side: read_stream_arrays -> reader.decode_group -> decode
     "reader.prefetch": "host",          # record read, crc, unpickle
     "fused_decode.stage": "host",       # padded host staging

@@ -55,6 +55,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from ..core import dualquant as core_dq
 from ..core.codebook import AdaptiveCoder, BankCoder
@@ -720,6 +721,76 @@ def _bank_repack_fn(kernel_impl: str, block_size: int, w32: int,
     return run
 
 
+@dataclasses.dataclass(frozen=True)
+class _BankLayout:
+    """The static sizing of one bank pass over one array (or one rank's
+    brick): what selects and builds its traced program."""
+    kernel_impl: str
+    predictor: str
+    ndim: int
+    work_shape: tuple
+    n: int
+    n_chunks: int
+    chunk_values: int
+    block_size: int
+    w32: int
+    w32_full: int
+    cands: int
+    k_outlier: int
+    k_literal: int
+    stats_on_device: bool
+
+    @property
+    def mega(self) -> bool:
+        # the megakernel op covers exactly the shapes whose Lorenzo halo
+        # is one raw value — 1-D streams and value-direct; higher-rank
+        # Lorenzo keeps the stage-composed trace (same outputs either way)
+        return self.predictor == "none" or self.ndim == 1
+
+    @property
+    def op(self) -> str:
+        return "ceaz_chunk" if self.mega else "hufenc"
+
+    def pass_fn(self):
+        """The cached jitted bank pass `run(work, eb, lengths, cwords)`."""
+        if self.mega:
+            return _mega_pass_fn(
+                self.kernel_impl, self.predictor, self.n_chunks,
+                self.chunk_values, self.block_size, self.w32, self.cands,
+                self.k_outlier, self.k_literal, self.stats_on_device)
+        return _bank_pass_fn(
+            self.kernel_impl, self.predictor, self.ndim, self.n_chunks,
+            self.chunk_values, self.block_size, self.w32, self.cands,
+            self.k_outlier, self.k_literal, self.stats_on_device)
+
+
+def _bank_layout(bank, shape, chunk_values: int, block_size: int,
+                 kernel_impl: str, predictor: str,
+                 stats_on_device: bool) -> _BankLayout:
+    n = int(np.prod(shape))
+    chunk_values = max(1, min(chunk_values, n))
+    n_chunks, _ = chunk_layout(n, chunk_values)
+    if predictor == "none":
+        ndim, work_shape = 1, (-1,)
+    else:
+        ndim = min(len(shape), 3)
+        work_shape = _work_shape(shape)
+    return _BankLayout(
+        kernel_impl, predictor, ndim, work_shape, n, n_chunks, chunk_values,
+        block_size,
+        _bank_w32(min(int(bank.lengths.max()), BANK_PROVISION_BITS),
+                  chunk_values),
+        _bank_w32(int(bank.lengths.max()), chunk_values),
+        _cand_window(int(bank.lengths.min())), _k_outlier(chunk_values),
+        min(n, max(256, n // 256)), stats_on_device)
+
+
+def _count_pass(lay: _BankLayout, passes: int = 1) -> None:
+    om.add(om.PASS_VALUES, passes * lay.n_chunks * lay.chunk_values,
+           side="encode", op=lay.op)
+    om.add(om.PASS_LIVE_VALUES, passes * lay.n, side="encode", op=lay.op)
+
+
 def compress_error_bounded_bank(x: np.ndarray, eb: float, mode: str,
                                 coder: BankCoder, chunk_values: int,
                                 block_size: int,
@@ -740,68 +811,63 @@ def compress_error_bounded_bank(x: np.ndarray, eb: float, mode: str,
     exceeds the BANK_PROVISION_BITS pack provisioning, only the pack
     re-runs at full capacity (the quantized codes stay device-resident).
     """
-    from ..core.ceaz import CEAZCompressed
     bank = coder.bank
     if stats_on_device is None:
         stats_on_device = _default_stats_on_device()
-    chunk_values = max(1, min(chunk_values, int(x.size)))
-    n = int(x.size)
-    n_chunks, _ = chunk_layout(n, chunk_values)
-    if predictor == "none":
-        ndim, work_shape = 1, (-1,)
-    else:
-        ndim = min(x.ndim, 3)
-        work_shape = x.shape if x.ndim <= 3 else (-1,) + x.shape[-2:]
-    w32 = _bank_w32(min(int(bank.lengths.max()), BANK_PROVISION_BITS),
-                    chunk_values)
-    w32_full = _bank_w32(int(bank.lengths.max()), chunk_values)
-    cands = _cand_window(int(bank.lengths.min()))
-    # the megakernel op covers exactly the shapes whose Lorenzo halo is
-    # one raw value — 1-D streams and value-direct; higher-rank Lorenzo
-    # keeps the stage-composed trace (same outputs either way)
-    use_mega = predictor == "none" or ndim == 1
-    op = "ceaz_chunk" if use_mega else "hufenc"
-    if use_mega:
-        run = _mega_pass_fn(
-            kernel_impl, predictor, n_chunks, chunk_values, block_size,
-            w32, cands, _k_outlier(chunk_values),
-            min(n, max(256, n // 256)), stats_on_device)
-    else:
-        run = _bank_pass_fn(
-            kernel_impl, predictor, ndim, n_chunks, chunk_values,
-            block_size, w32, cands, _k_outlier(chunk_values),
-            min(n, max(256, n // 256)), stats_on_device)
+    lay = _bank_layout(bank, x.shape, chunk_values, block_size,
+                       kernel_impl, predictor, stats_on_device)
+    run = lay.pass_fn()
     with ot.span("fused.h2d"):
         work, lengths_d, cwords_d = to_device(
             "encode", "fused.h2d",
-            jnp.asarray(x.reshape(work_shape), jnp.float32),
+            jnp.asarray(x.reshape(lay.work_shape), jnp.float32),
             jnp.asarray(bank.lengths, jnp.int32),
             jnp.asarray(bank.code_table(), jnp.uint32))
-    with dispatch.measure(op, kernel_impl):
+    with dispatch.measure(lay.op, kernel_impl):
         out = run(work, eb, lengths_d, cwords_d)
-    om.add(om.PASS_VALUES, n_chunks * chunk_values, side="encode", op=op)
-    om.add(om.PASS_LIVE_VALUES, n, side="encode", op=op)
+    _count_pass(lay)
     with ot.span("fused.device_wait"):
         out = jax.block_until_ready(out)
     (hists, sel, totals, words, block_nbits, oidx, odelta, ocount,
      lit_idx, lit_q, lit_count, codes2, outl2, delta2, valid2, q,
      centers) = out
     # --- everything below is host work on the pulled results ---
+    dense = None
     with ot.span("fused.d2h"):
         hists_np, sel_np, totals_np, words_np, nbits_np, centers_np = \
             to_host("encode", "fused.d2h", hists, sel, totals, words,
                      block_nbits, centers)
         if stats_on_device:
-            (oidx_np, odelta_np, ocount_np, lit_idx_np, lit_q_np,
-             lit_count_np) = to_host("encode", "fused.d2h", oidx, odelta,
-                                      ocount, lit_idx, lit_q, lit_count)
-            if int(lit_count_np) > lit_idx_np.shape[0]:
+            stats = to_host("encode", "fused.d2h", oidx, odelta, ocount,
+                            lit_idx, lit_q, lit_count)
+            if int(stats[-1]) > stats[3].shape[0]:
                 # more literal candidates than the pass kept: the check
                 # runs dense over the deltas (see _literals)
                 delta2, = to_host("encode", "fused.d2h", delta2)
         else:
-            outl_np, delta_np, q_np = to_host("encode", "fused.d2h",
-                                               outl2, delta2, q)
+            stats = (None,) * 6
+            dense = to_host("encode", "fused.d2h", outl2, delta2, q)
+    return _bank_finish(
+        x, eb, mode, coder, lay,
+        (hists_np, sel_np, totals_np, words_np, nbits_np, *stats),
+        centers_np, (codes2, outl2, delta2, valid2), dense)
+
+
+def _bank_finish(x: np.ndarray, eb: float, mode: str, coder: BankCoder,
+                 lay: _BankLayout, pulled, centers_np, dev, dense=None):
+    """Host half of a bank pass: selection replay, the provisioning
+    repack, chunk records and the literal check, from the pass's pulled
+    results `pulled` = (hists, sel, totals, words, block_nbits, oidx,
+    odelta, ocount, lit_idx, lit_q, lit_count) (the last six None on the
+    host-stats path, which brings `dense` = (outl2, delta2, q) snapshots
+    instead). `dev` = (codes2, outl2, delta2, valid2) are the pass's
+    device arrays, read only on an overflow; delta2 is already on the
+    host where the literal candidates overflowed."""
+    from ..core.ceaz import CEAZCompressed
+    (hists_np, sel_np, totals_np, words_np, nbits_np, oidx_np, odelta_np,
+     ocount_np, lit_idx_np, lit_q_np, lit_count_np) = pulled
+    codes2, outl2, delta2, valid2 = dev
+    n, n_chunks = lay.n, lay.n_chunks
     with ot.span("fused.host_select"):
         hists_np = hists_np.astype(np.int64)
         totals_np = totals_np.astype(np.int64)
@@ -810,12 +876,12 @@ def compress_error_bounded_bank(x: np.ndarray, eb: float, mode: str,
             # the host replay of the selection statistic must land on
             # the same bank row the device argmin picked (integer-exact)
             assert d.bank_index == int(sel_np[i])
-    if w32 < w32_full and not _bank_fits(totals_np, w32):
+    if lay.w32 < lay.w32_full and not _bank_fits(totals_np, lay.w32):
         om.add(om.BANK_REPACKS)
         lengths_np, cwords_np = _codebook_tables(decisions)
         with ot.span("fused.bank_overflow_repack"):
             repacked = _bank_repack_fn(
-                kernel_impl, block_size, w32_full, cands)(
+                lay.kernel_impl, lay.block_size, lay.w32_full, lay.cands)(
                 codes2, valid2, jnp.asarray(lengths_np),
                 jnp.asarray(cwords_np))
             with ot.span("fused.d2h"):
@@ -824,11 +890,12 @@ def compress_error_bounded_bank(x: np.ndarray, eb: float, mode: str,
     with ot.span("fused.assemble"):
         if centers_np is not None:
             centers_np = centers_np.astype(np.int64)
-        if stats_on_device:
+        if lay.stats_on_device:
             p1 = _Pass1(None, outl2, delta2, valid2, None, hists_np, n,
-                        n_chunks, chunk_values, True, lit_idx=lit_idx_np,
-                        lit_q=lit_q_np, lit_count=lit_count_np,
-                        predictor=predictor, centers=centers_np)
+                        n_chunks, lay.chunk_values, True,
+                        lit_idx=lit_idx_np, lit_q=lit_q_np,
+                        lit_count=lit_count_np, predictor=lay.predictor,
+                        centers=centers_np)
             k = oidx_np.shape[1]
             outliers = []
             for i in range(n_chunks):
@@ -842,19 +909,180 @@ def compress_error_bounded_bank(x: np.ndarray, eb: float, mode: str,
                     oi = np.flatnonzero(m).astype(np.int64)
                     outliers.append((oi, d[oi].astype(np.int32)))
         else:
+            outl_np, delta_np, q_np = dense
             p1 = _Pass1(None, None, None, None, None, hists_np, n,
-                        n_chunks, chunk_values, False, outl_host=outl_np,
-                        delta_host=delta_np, q_host=q_np,
-                        predictor=predictor, centers=centers_np)
+                        n_chunks, lay.chunk_values, False,
+                        outl_host=outl_np, delta_host=delta_np, q_host=q_np,
+                        predictor=lay.predictor, centers=centers_np)
             outliers = _outliers(p1)
         chunks = _assemble_chunks(p1, words_np, nbits_np, totals_np,
-                                  outliers, eb, decisions, block_size)
-        lit_i, lit_v = _literals(p1, x.reshape(-1), eb, ndim, work.shape)
-    return CEAZCompressed(shape=x.shape, dtype=str(x.dtype), ndim=ndim,
+                                  outliers, eb, decisions, lay.block_size)
+        lit_i, lit_v = _literals(p1, x.reshape(-1), eb, lay.ndim,
+                                 lay.work_shape)
+    return CEAZCompressed(shape=x.shape, dtype=str(x.dtype), ndim=lay.ndim,
                           mode=mode, chunks=chunks,
                           word_bits=x.dtype.itemsize * 8,
-                          predictor=predictor,
+                          predictor=lay.predictor,
                           literal_idx=lit_i, literal_val=lit_v)
+
+
+# ---------------------------------------------------------------------------
+# Rank-sharded bank mode: one brick per device along a mesh axis, each
+# device running the bank pass on its own brick; the packed payloads are
+# gathered to the aggregator device (io/collectives.py)
+# ---------------------------------------------------------------------------
+
+def rank_mesh_axis(x):
+    """(mesh, axis) where `x` is a `jax.Array` whose leading axis is split
+    one row per device along one axis of a mesh and no other axis is
+    split; None otherwise."""
+    from jax.sharding import NamedSharding
+    sh = getattr(x, "sharding", None)
+    if not isinstance(sh, NamedSharding) or x.ndim < 2:
+        return None
+    spec = tuple(sh.spec) + (None,) * (x.ndim - len(sh.spec))
+    axis = spec[0]
+    if not isinstance(axis, str) or any(s is not None for s in spec[1:]):
+        return None
+    if sh.mesh.shape[axis] != x.shape[0] or x.shape[0] < 2 \
+            or len(sh.device_set) != x.shape[0]:
+        return None
+    return sh.mesh, axis
+
+
+def rank_shards(a) -> List[jax.Array]:
+    """The per-device pieces of a rank-sharded array, in rank order."""
+    shards = sorted(a.addressable_shards,
+                    key=lambda s: s.index[0].start or 0)
+    return [s.data for s in shards]
+
+
+@functools.lru_cache(maxsize=None)
+def _rank_range_fn(mesh, axis: str):
+    spec = P(axis)
+
+    def per_rank(x):
+        return jnp.stack([jnp.max(x), jnp.min(x)])[None]
+
+    def ceaz_rank_range(x):
+        return jax.shard_map(per_rank, mesh=mesh, in_specs=spec,
+                             out_specs=spec, axis_names={axis})(x)
+
+    return jax.jit(ceaz_rank_range)
+
+
+def rank_extrema(x) -> np.ndarray:
+    """(R, 2) host float64 (max, min) of each rank's brick, each reduced
+    on its own device: only these scalars cross to the host."""
+    mesh, axis = rank_mesh_axis(x)
+    out, = to_host("encode", "fused.d2h", _rank_range_fn(mesh, axis)(x))
+    return out.astype(np.float64)
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_pass_fn(lay: _BankLayout, mesh, axis: str):
+    """The bank pass of `lay` over every rank's brick at once: one
+    program, each device running `lay.pass_fn()` on its own brick with
+    its own bound. Outputs are rank-sharded along `axis` (rank r's rows
+    on rank r's device); a scalar output becomes one row per rank."""
+    run = lay.pass_fn()
+    spec = P(axis)
+
+    def per_rank(x, eb, lengths, cwords):
+        out = run(x[0].reshape(lay.work_shape), eb[0], lengths, cwords)
+        return tuple(None if a is None else (a if a.ndim else a[None])
+                     for a in out)
+
+    def ceaz_gather_pass(x, ebs, lengths, cwords):
+        return jax.shard_map(per_rank, mesh=mesh,
+                             in_specs=(spec, spec, P(), P()),
+                             out_specs=spec, axis_names={axis})(
+            x, ebs, lengths, cwords)
+
+    return jax.jit(ceaz_gather_pass)
+
+
+def compress_bank_sharded(x, ebs: Sequence[float], mode: str,
+                          coders: Sequence[BankCoder], chunk_values: int,
+                          block_size: int, kernel_impl: str = "auto"):
+    """Lorenzo bank compression of every rank's brick of a rank-sharded
+    float32 array (see :func:`rank_mesh_axis`), each on its own device.
+
+    One program runs the bank pass of :func:`compress_error_bounded_bank`
+    on every device at once, rank r at absolute bound ``ebs[r]`` with
+    ``coders[r]``; the packed payloads (summaries, words, block bit
+    counts, outlier and literal-candidate buffers) are then gathered over
+    the mesh axis to the aggregator, rank 0's device, and pulled from it
+    in one transfer (:func:`repro.io.collectives.gather_to_aggregator`).
+    No field value crosses between devices. Each rank's record is
+    finished on the host exactly as the single-array path finishes it,
+    so rank r's record equals ``compress_error_bounded_bank`` of its
+    brick. The host keeps the dense literal check, so the raw bricks are
+    pulled too (each from its own device). Returns (records, the raw
+    bricks on the host as one (R, ...) array)."""
+    from jax.sharding import NamedSharding
+    from ..io import collectives
+    mesh, axis = rank_mesh_axis(x)
+    ranks = x.shape[0]
+    bank = coders[0].bank
+    lay = _bank_layout(bank, x.shape[1:], chunk_values, block_size,
+                       kernel_impl, "lorenzo", True)
+    with ot.span("fused.h2d"):
+        ebs_d, lengths_d, cwords_d = to_device(
+            "encode", "fused.h2d",
+            jax.device_put(np.asarray(ebs, np.float32),
+                           NamedSharding(mesh, P(axis))),
+            jax.device_put(np.asarray(bank.lengths, np.int32),
+                           NamedSharding(mesh, P())),
+            jax.device_put(np.asarray(bank.code_table(), np.uint32),
+                           NamedSharding(mesh, P())))
+    with dispatch.measure(lay.op, kernel_impl):
+        out = _sharded_pass_fn(lay, mesh, axis)(x, ebs_d, lengths_d,
+                                                cwords_d)
+    _count_pass(lay, ranks)
+    (hists, sel, totals, words, block_nbits, oidx, odelta, ocount,
+     lit_idx, lit_q, lit_count, codes2, outl2, delta2, valid2, _q,
+     _centers) = out
+    payload = (hists, sel, totals, words, block_nbits, oidx, odelta,
+               ocount, lit_idx, lit_q, lit_count)
+    rows = collectives.gather_to_aggregator(
+        payload, mesh, axis, rank_shards(x)[0].devices().pop())
+    om.add(om.GATHER_RANKS, ranks)
+    for r in rows:
+        live = _payload_live_bytes(r)
+        om.add(om.GATHER_PAYLOAD_BYTES, live)
+        om.add(om.GATHER_PAD_BYTES, sum(a.nbytes for a in r) - live)
+    with ot.span("fused.d2h"):
+        x_host, = to_host("encode", "fused.d2h", x)
+    dev = [rank_shards(a) for a in (codes2, outl2, delta2, valid2)]
+    comps = []
+    for r, (coder, row) in enumerate(zip(coders, rows)):
+        row = list(row)
+        row[-1] = row[-1][0]                    # lit_count: one per rank
+        codes_r, outl_r, delta_r, valid_r = (d[r] for d in dev)
+        if int(row[-1]) > row[-3].shape[0]:
+            with ot.span("fused.d2h"):
+                delta_r, = to_host("encode", "fused.d2h", delta_r)
+        comps.append(_bank_finish(
+            x_host[r], float(ebs[r]), mode, coder, lay, tuple(row), None,
+            (codes_r, outl_r, delta_r, valid_r)))
+    return comps, x_host
+
+
+def _payload_live_bytes(row) -> int:
+    """Bytes of one rank's gathered payload that its record reads: the
+    summaries and block bit counts whole, each chunk's words up to its
+    payload (the kept prefix of :func:`_assemble_chunks`), the outlier
+    and literal-candidate buffers up to their counts."""
+    (hists, sel, totals, words, nbits, oidx, odelta, ocount, lit_idx,
+     lit_q, lit_count) = row
+    w32 = words.shape[1]
+    kept = np.minimum(2 * ((totals.astype(np.int64) + 63) // 64 + 1), w32)
+    n_out = np.minimum(ocount, oidx.shape[1]).astype(np.int64)
+    n_lit = min(int(lit_count[0]), lit_idx.shape[0])
+    return 4 * int(hists.size + sel.size + totals.size + kept.sum()
+                   + nbits.size + 2 * n_out.sum() + ocount.size
+                   + 2 * n_lit + lit_count.size)
 
 
 def _spec_window(speculation) -> int:

@@ -157,3 +157,168 @@ def test_compressed_all_gather():
         print('compressed all-gather OK')
     """), n_devices=4)
     assert "compressed all-gather OK" in out
+
+
+# -- error-bounded gather of rank-sharded bricks ------------------------------
+# One subprocess with 4 host devices compresses a 2x2 decomposition of a
+# seeded 64x64x16 field (bricks of 32x32x16, 8 KiB chunks, so a brick
+# spans eight chunks), once in distribution and once with brick 2
+# replaced by sparse spikes (out of the bank's envelope), through
+# `ceaz_gather_stream`; each test reads one part of what it reports.
+GATHER_SCRIPT = textwrap.dedent("""
+    import json, os, re, tempfile
+    import jax, numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.core import CEAZ, CEAZConfig
+    from repro.io import collectives, engine
+    from repro.obs import metrics as om
+    from repro.runtime import fused
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ('ranks',))
+    cfg = dict(mode='rel', eb=1e-3, use_fused=True, chunk_bytes=1 << 13,
+               block_size=1024)
+    comp = CEAZ(CEAZConfig(codebook='bank', **cfg))
+    exact = CEAZ(CEAZConfig(codebook='exact', **cfg))
+    rng = np.random.default_rng(15)
+    field = np.cumsum(np.cumsum(rng.standard_normal((64, 64, 16)), 0), 1)
+    field = (field / 40 + np.linspace(0, 3, 16)).astype(np.float32)
+    origins = [(0, 0, 0), (0, 32, 0), (32, 0, 0), (32, 32, 0)]
+    bricks = np.stack([field[i:i + 32, j:j + 32] for i, j, _ in origins])
+    ood = bricks.copy()
+    ood[2] = (rng.random(ood[2].shape) < 0.01).astype(np.float32)
+    FIELDS = ('words', 'block_nbits', 'outlier_idx', 'outlier_delta',
+              'bank_index', 'eb', 'n_values')
+
+    def same(a, b):
+        return (a.shape == b.shape and len(a.chunks) == len(b.chunks)
+                and all(np.array_equal(getattr(ca, f), getattr(cb, f))
+                        for ca, cb in zip(a.chunks, b.chunks)
+                        for f in FIELDS)
+                and np.array_equal(a.literal_idx, b.literal_idx)
+                and np.array_equal(a.literal_val, b.literal_val))
+
+    report = {}
+    tmp = tempfile.mkdtemp()
+    for case, host in (('in', bricks), ('ood', ood)):
+        x = jax.device_put(host, NamedSharding(mesh, P('ranks')))
+        before = om.snapshot()
+        path = os.path.join(tmp, case + '.ceazs')
+        collectives.ceaz_gather_stream(
+            x, path, comp=comp, origins=origins,
+            decomposition={'grid': [2, 2, 1], 'global_shape': [64, 64, 16]})
+        d = om.diff(om.snapshot(), before)
+        with engine.StreamReader(path) as rd:
+            recs = [(rd.records[i], rd.read_object(i))
+                    for i in range(len(rd))]
+            meta = rd.meta
+        arrays = engine.read_stream_arrays(path)
+        rep = report[case] = {}
+        rep['keys'] = [r['key'] for r, _ in recs]
+        rep['shapes'] = [r['shape'] for r, _ in recs]
+        rep['origins'] = [r['origin'] for r, _ in recs]
+        rep['footer'] = {k: meta.get(k) for k in ('kind', 'decomposition')}
+        rep['bank_in_footer'] = 'codebook_bank' in meta
+        rep['equal_single'] = [same(c, comp.compress(b))
+                               for (_, c), b in zip(recs, host)]
+        rep['equal_exact'] = [same(c, exact.compress(b))
+                              for (_, c), b in zip(recs, host)]
+        rep['bank_coded'] = [all(ch.bank_index >= 0 for ch in c.chunks)
+                             for _, c in recs]
+        rep['err_over_bound'] = [
+            float(np.abs(a.astype(np.float64) - b).max()
+                  / (1e-3 * (float(b.max()) - float(b.min()))))
+            for a, b in zip(arrays, host)]
+        rep['counters'] = {k: v for k, v in d.items()
+                           if 'gather' in k or 'fallback' in k
+                           or 'collectives' in k}
+
+    # the two programs, as compiled: which collectives each holds
+    x = jax.device_put(bricks, NamedSharding(mesh, P('ranks')))
+    lay = fused._bank_layout(comp.bank, bricks.shape[1:],
+                             comp._chunk_values(32), 1024, 'auto',
+                             'lorenzo', True)
+    everywhere = NamedSharding(mesh, P())
+    args = (x, jax.device_put(np.ones(4, np.float32),
+                              NamedSharding(mesh, P('ranks'))),
+            jax.device_put(comp.bank.lengths.astype(np.int32), everywhere),
+            jax.device_put(comp.bank.code_table().astype(np.uint32),
+                           everywhere))
+    pass_fn = fused._sharded_pass_fn(lay, mesh, 'ranks')
+    out = pass_fn(*args)
+    coll = re.compile(r'= (\\S+) (all-gather|all-reduce|reduce-scatter|'
+                      r'collective-permute|all-to-all)(?:-start)?\\(')
+    report['pass_collectives'] = coll.findall(
+        pass_fn.lower(*args).compile().as_text())
+    payload = out[:11]
+    hlo = collectives._gather_fn(mesh, 'ranks', 11).lower(
+        *payload).compile().as_text()
+    report['gather_collectives'] = sorted(set(coll.findall(hlo)))
+    report['gather_operand_u32'] = sum(
+        int(np.prod(a.sharding.shard_shape(a.shape))) for a in payload)
+    report['gather_fn_in_pass'] = pass_fn.__name__
+    print('REPORT ' + json.dumps(report))
+""")
+
+
+@pytest.fixture(scope="module")
+def gather_report():
+    import json
+    out = run_with_devices(GATHER_SCRIPT, n_devices=4, timeout=300)
+    line, = [ln for ln in out.splitlines() if ln.startswith("REPORT ")]
+    return json.loads(line[len("REPORT "):])
+
+
+@pytest.mark.parametrize("case", ["in", "ood"])
+def test_gather_records_equal_single_device_compress(gather_report, case):
+    """Each rank's record of the gathered stream holds the payload of
+    `CEAZ.compress` of its brick alone on one device."""
+    assert gather_report[case]["equal_single"] == [True] * 4
+
+
+@pytest.mark.parametrize("case", ["in", "ood"])
+def test_gather_decoded_bricks_within_their_own_bound(gather_report, case):
+    errs = gather_report[case]["err_over_bound"]
+    assert all(e <= 1.0 for e in errs), errs
+
+
+def test_gather_drifting_rank_falls_back_alone(gather_report):
+    """Brick 2 made spikes trips the drift guard on its rank only: its
+    record is the exact path's compress of it, the others stay bank."""
+    assert gather_report["in"]["bank_coded"] == [True] * 4
+    ood = gather_report["ood"]
+    assert ood["bank_coded"] == [True, True, False, True]
+    assert ood["equal_exact"][2]
+    assert ood["counters"]["ceaz_bank_exact_fallbacks_total"] == 1
+
+
+@pytest.mark.parametrize("case", ["in", "ood"])
+def test_gather_counters_add_up_to_the_pulled_buffer(gather_report, case):
+    c = gather_report[case]["counters"]
+    pulled = c['ceaz_d2h_bytes_total{side="encode",site="collectives.d2h"}']
+    assert c["ceaz_gather_ranks_total"] == 4
+    assert 0 < c["ceaz_gather_payload_bytes_total"] < pulled
+    assert (c["ceaz_gather_payload_bytes_total"]
+            + c["ceaz_gather_pad_bytes_total"]) == pulled
+    # the collective's result is every rank's payload buffer
+    assert pulled == 4 * 4 * gather_report["gather_operand_u32"]
+
+
+def test_gather_stream_layout(gather_report):
+    rep = gather_report["in"]
+    assert rep["keys"] == [f"rank_{r:04d}" for r in range(4)]
+    assert rep["shapes"] == [[32, 32, 16]] * 4
+    assert rep["origins"] == [[0, 0, 0], [0, 32, 0], [32, 0, 0],
+                              [32, 32, 0]]
+    assert rep["footer"] == {"kind": "gather", "decomposition": {
+        "grid": [2, 2, 1], "global_shape": [64, 64, 16]}}
+    assert rep["bank_in_footer"]
+
+
+def test_gather_only_payload_crosses_devices(gather_report):
+    """The bank pass program holds no collective; the gather program
+    holds one all-gather, of one uint32 buffer per rank."""
+    assert gather_report["gather_fn_in_pass"] == "ceaz_gather_pass"
+    assert gather_report["pass_collectives"] == []
+    n = gather_report["gather_operand_u32"]
+    (shape, op), = gather_report["gather_collectives"]
+    assert op == "all-gather" and shape.startswith(f"u32[4,{n}]"), shape

@@ -128,3 +128,48 @@ def test_tpu_hacc_bank_pass_fits(one_chip):
     assert total < V5E_HBM_BYTES, mem
     # a paper-scale chunk should leave most of the chip free
     assert total < np.int64(V5E_HBM_BYTES) // 8, mem
+
+
+def test_tpu_rank_sharded_gather_compiles_and_fits(topo, one_chip):
+    """The rank-sharded bank pass over four 256x256x128 bricks, one per
+    described chip of a v5e:2x2 (the NYX 16-rank deployment's host
+    share), and the gather of its payloads compile and fit each chip;
+    the pass holds no collective and the gather one collective, over one
+    uint32 buffer per rank."""
+    import re
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.core.codebook import default_codebook_bank
+    from repro.io import collectives
+    bank = default_codebook_bank()
+    mesh = Mesh(np.array(topo.devices[:4]), ("r",))
+    brick = (256, 256, 128)
+    lay = fused._bank_layout(bank, brick, CHUNK, BLOCK,
+                             dispatch.auto_impl("hufenc", "tpu"), "lorenzo",
+                             True)
+    ranked = NamedSharding(mesh, P("r"))
+    rep = NamedSharding(mesh, P())
+    s = lambda shape, dt, sh: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+    books = bank.lengths.shape
+    args = (s((4,) + brick, jnp.float32, ranked), s((4,), jnp.float32, ranked),
+            s(books, jnp.int32, rep), s(books, jnp.uint32, rep))
+    pass_fn = fused._sharded_pass_fn(lay, mesh, "r")
+    compiled = pass_fn.lower(*args).compile()
+    coll = re.compile(r"= (\S+) (all-gather|all-reduce|reduce-scatter|"
+                      r"collective-permute|all-to-all)(?:-start)?\(")
+    assert coll.findall(compiled.as_text()) == []
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert total < V5E_HBM_BYTES // 4, mem
+    payload = [s(o.shape, o.dtype, ranked)
+               for o in jax.eval_shape(pass_fn, *args)[:11]]
+    n = sum(int(np.prod(p.shape)) // 4 for p in payload)
+    gathered = collectives._gather_fn(mesh, "r", 11).lower(
+        *payload).compile().as_text()
+    # the TPU compiler may lower the all-gather as an all-reduce of each
+    # rank's buffer placed in a zeroed (4, n) array: the same bytes
+    (shape, op), = coll.findall(gathered)
+    dims = [int(d) for d in re.match(r"u32\[([\d,]+)\]", shape)
+            .group(1).split(",")]
+    assert op in ("all-gather", "all-reduce"), op
+    assert int(np.prod(dims)) == 4 * n, (shape, n)
