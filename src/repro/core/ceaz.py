@@ -20,7 +20,7 @@ Two modes:
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import numpy as np
 
@@ -37,8 +37,6 @@ from .ratecontrol import FixedRatioController, bitrate_from_ratio
 CHUNK_HEADER_BITS = 128
 BLOCK_COUNT_BITS = 32
 OUTLIER_BITS = 64          # 32-bit position + 32-bit delta
-
-value_range = dq.value_range       # re-export: the facade's bound scale
 
 
 @dataclasses.dataclass
@@ -237,9 +235,17 @@ class CEAZ:
 
     # -- helpers -------------------------------------------------------------
     def _abs_eb(self, x: np.ndarray) -> float:
+        return self._bound(x)[0]
+
+    def _bound(self, x: np.ndarray) -> Tuple[float, Optional[float]]:
+        """(absolute bound, max |x|) of `x`: in rel mode both come from
+        the one reduction of its max and min; in abs mode max |x| is
+        None (the fused route reduces it where it needs it)."""
         if self.cfg.mode == "abs":
-            return self.cfg.eb
-        return self.cfg.eb * value_range(x)
+            return self.cfg.eb, None
+        hi, lo = float(np.max(x)), float(np.min(x))
+        return (self.cfg.eb * dq.extrema_range(hi, lo),
+                max(abs(hi), abs(lo)))
 
     def _dual_quantize(self, x: np.ndarray, eb: float, ndim: int):
         if self.cfg.backend == "pallas":
@@ -369,10 +375,11 @@ class CEAZ:
         """mode/predictor routing for one array, under a given coder."""
         if self.cfg.mode in ("abs", "rel"):
             with ot.span("ceaz.policy"):
-                eb = self._abs_eb(x)
+                eb, amax = self._bound(x)
                 pred = self._pick_predictor(x, eb)
             if use_fused:
-                return self._compress_eb_fused(x, pred, coder=coder, eb=eb)
+                return self._compress_eb_fused(x, pred, coder=coder, eb=eb,
+                                               amax=amax)
             if pred == "none":
                 return self._compress_eb_direct(x, word_bits, coder=coder)
             return self._compress_eb(x, word_bits, coder=coder)
@@ -467,15 +474,15 @@ class CEAZ:
         with ot.span("ceaz.compress", shape=list(x.shape), dtype="float32",
                      mode=self.cfg.mode, ranks=ranks):
             with ot.span("ceaz.policy"):
-                if self.cfg.mode == "abs":
-                    ebs = [self.cfg.eb] * ranks
-                else:
-                    ebs = [self.cfg.eb * dq.extrema_range(hi, lo)
-                           for hi, lo in fused.rank_extrema(x)]
+                extrema = fused.rank_extrema(x)
+                ebs = [self.cfg.eb if self.cfg.mode == "abs"
+                       else self.cfg.eb * dq.extrema_range(hi, lo)
+                       for hi, lo in extrema]
             coders = [BankCoder(self.bank) for _ in range(ranks)]
             comps, bricks = fused.compress_bank_sharded(
                 x, ebs, self.cfg.mode, coders, self._chunk_values(32),
-                self.cfg.block_size, kernel_impl=self.cfg.kernel_impl)
+                self.cfg.block_size, kernel_impl=self.cfg.kernel_impl,
+                amaxes=[max(abs(hi), abs(lo)) for hi, lo in extrema])
             return [self._drift_checked(b, c, coder, 32, True)
                     for b, c, coder in zip(bricks, comps, coders)]
 
@@ -502,28 +509,34 @@ class CEAZ:
 
     def _compress_eb_fused(self, x: np.ndarray,
                            predictor: str = "lorenzo",
-                           coder=None, eb: Optional[float] = None
-                           ) -> CEAZCompressed:
+                           coder=None, eb: Optional[float] = None,
+                           amax: Optional[float] = None) -> CEAZCompressed:
         """Policy stays here; all per-value work runs device-resident.
         With a BankCoder the whole encode runs as ONE traced pass
         (quantize -> select -> encode -> pack, no host tree build).
-        `eb` is the absolute bound when the caller already has it."""
+        `eb` and `amax` are the absolute bound and max |x| when the
+        caller already has them (see :meth:`_bound`); max |x| sizes the
+        pass's literal candidates and never changes the bytes."""
         from ..runtime import fused
         coder = coder if coder is not None else self._coder()
-        if eb is None:
+        if eb is None or amax is None:
             with ot.span("ceaz.policy"):
-                eb = self._abs_eb(x)
+                if eb is None:
+                    eb, amax = self._bound(x)
+                if amax is None:
+                    amax = max(abs(float(np.max(x))), abs(float(np.min(x))))
         if isinstance(coder, BankCoder):
             return fused.compress_error_bounded_bank(
                 x, eb, self.cfg.mode, coder,
                 self._chunk_values(x.dtype.itemsize * 8),
                 self.cfg.block_size, kernel_impl=self.cfg.kernel_impl,
-                predictor=predictor)
+                predictor=predictor, amax=amax)
         return fused.compress_error_bounded(
             x, eb, self.cfg.mode, coder,
             self._chunk_values(x.dtype.itemsize * 8), self.cfg.block_size,
             adaptive=self.cfg.adaptive, exact_build=self.cfg.exact_build,
-            kernel_impl=self.cfg.kernel_impl, predictor=predictor)
+            kernel_impl=self.cfg.kernel_impl, predictor=predictor,
+            amax=amax)
 
     def _value_quantize(self, chunk: np.ndarray, eb: float):
         """Per-chunk value-direct quantization, backend-selected: the

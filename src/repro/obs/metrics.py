@@ -42,7 +42,7 @@ __all__ = [
     "BANK_FALLBACKS", "BANK_REPACKS", "QUEUE_DEPTH", "CORRUPTION",
     "KERNEL_CALLS", "H2D_BYTES", "D2H_BYTES", "PASS_VALUES",
     "PASS_LIVE_VALUES", "GATHER_PAYLOAD_BYTES", "GATHER_PAD_BYTES",
-    "GATHER_RANKS",
+    "GATHER_RANKS", "LITERAL_CHECKS", "LITERAL_DENSE_CHECKS",
     "PAGE_HITS", "PAGE_MISSES", "PAGE_EVICTIONS", "PAGE_CACHE_BYTES",
 ]
 
@@ -81,6 +81,11 @@ PASS_LIVE_VALUES = "ceaz_pass_live_values_total"
 GATHER_PAYLOAD_BYTES = "ceaz_gather_payload_bytes_total"
 GATHER_PAD_BYTES = "ceaz_gather_pad_bytes_total"
 GATHER_RANKS = "ceaz_gather_ranks_total"
+# the fused encode's literal check (runtime/fused.py), label side="encode":
+# every check, and those whose device candidates overflowed their
+# capacity, so the check ran densely over every value on the host
+LITERAL_CHECKS = "ceaz_literal_checks_total"
+LITERAL_DENSE_CHECKS = "ceaz_literal_dense_checks_total"
 # decode-on-demand parameter paging (serve/paging.py)
 PAGE_HITS = "ceaz_page_hits_total"                 # cache hits (layer reads)
 PAGE_MISSES = "ceaz_page_misses_total"             # decode-on-demand page-ins

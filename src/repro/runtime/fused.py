@@ -69,6 +69,11 @@ from ..obs import trace as ot
 # bits.
 MAX_CODE_BITS = DEFAULT_MAX_LEN
 _EPS32 = float(np.finfo(np.float32).eps)
+# The device passes have only the f32 reconstruction: a value is a
+# literal CANDIDATE when its f32 error lies within GUARD_ULPS ulp of
+# |rec| + |x| under the bound, and the host replays the float64 check on
+# the candidates alone (see _literals).
+GUARD_ULPS = 16
 
 
 def chunk_layout(n: int, chunk_values: int) -> Tuple[int, int]:
@@ -139,11 +144,44 @@ def _device_stats(codes2, valid2, q, work_flat, eb, k_literal):
                             codes2.shape)
     hists = jnp.zeros((n_chunks, NUM_SYMBOLS), jnp.int32) \
         .at[cidx, codes2].add(valid2.astype(jnp.int32))
-    rec = q.astype(jnp.float32) * (2.0 * eb)
-    margin = 16.0 * _EPS32 * (jnp.abs(rec) + jnp.abs(work_flat)) + 1e-38
-    cand = jnp.abs(rec - work_flat) > (eb - margin)
-    lit_idx, lit_q, lit_count = _extract_sparse(cand, q, k_literal)
+    lit_idx, lit_q, lit_count = _extract_sparse(
+        _literal_candidates(q, work_flat, eb), q, k_literal)
     return hists, lit_idx, lit_q, lit_count
+
+
+def _literal_candidates(q, x_flat, eb):
+    """Mask of the values whose f32 reconstruction q * 2eb lies within
+    the guard band (GUARD_ULPS ulp of |rec| + |x|) under the bound `eb`,
+    or past it: a superset of the float64 literal set."""
+    rec = q.astype(jnp.float32) * (2.0 * eb)
+    band = GUARD_ULPS * _EPS32 * (jnp.abs(rec) + jnp.abs(x_flat)) + 1e-38
+    return jnp.abs(rec - x_flat) > (eb - band)
+
+
+def literal_capacity(n: int, eb: Optional[float] = None,
+                     amax: Optional[float] = None) -> int:
+    """Static capacity of the literal-candidate compaction of an
+    n-value pass at absolute bound `eb` over data with max |x| `amax`.
+
+    The quantization errors spread over [0, eb], and the guard band is
+    about 2 * GUARD_ULPS * eps32 * |x| wide, so at most a share
+    2 * GUARD_ULPS * eps32 * amax / eb of the values are candidates. The
+    capacity is n >> s for the largest s <= 8 whose 2^-s covers that
+    share (few buckets, so few compiled passes), never under the floor
+    min(n, max(256, n // 256)) and never over n. Without `eb` and `amax`
+    (or with eb <= 0, or a non-finite share) it is the floor. Data whose
+    errors bunch near the bound can still overflow: :func:`_literals`
+    then checks densely."""
+    floor = min(n, max(256, n // 256))
+    if eb is None or amax is None or not eb > 0:
+        return floor
+    share = 2 * GUARD_ULPS * _EPS32 * float(amax) / float(eb)
+    if not np.isfinite(share):
+        return floor
+    s = 8
+    while s > 0 and 2.0 ** -s < share:
+        s -= 1
+    return min(n, max(floor, n >> s))
 
 
 def _extract_sparse(mask, values, k):
@@ -151,7 +189,8 @@ def _extract_sparse(mask, values, k):
 
     -> (idx (k,) int32 ascending, vals (k,), count). Entries past the
     first k survivors are dropped; callers compare count against k and
-    fall back to a dense host pass on overflow.
+    fall back to a dense host pass on overflow. For literal candidates
+    `k` is :func:`literal_capacity`, sized from the guard band's yield.
     """
     n = mask.shape[0]
     pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
@@ -268,7 +307,10 @@ def _host_hists(codes_host: np.ndarray, n: int) -> np.ndarray:
 
 
 def _run_pass1(work: jnp.ndarray, eb: float, ndim: int, chunk_values: int,
-               stats_on_device: Optional[bool] = None) -> _Pass1:
+               stats_on_device: Optional[bool] = None,
+               amax: Optional[float] = None) -> _Pass1:
+    """Pass 1 of one array; `amax` (max |x|, where the caller has it)
+    sizes the literal candidates (:func:`literal_capacity`)."""
     if stats_on_device is None:
         stats_on_device = _default_stats_on_device()
     n = int(work.size)
@@ -276,9 +318,9 @@ def _run_pass1(work: jnp.ndarray, eb: float, ndim: int, chunk_values: int,
     codes2, outl2, delta2, valid2, q = _quantize_pass(
         work, eb, ndim, n_chunks, chunk_values)
     if stats_on_device:
-        k_lit = min(n, max(256, n // 256))
         hists, lit_idx, lit_q, lit_count = _device_stats(
-            codes2, valid2, q, work.reshape(-1), eb, k_lit)
+            codes2, valid2, q, work.reshape(-1), eb,
+            literal_capacity(n, eb, amax))
         return _Pass1(codes2, outl2, delta2, valid2, q, np.asarray(hists),
                       n, n_chunks, chunk_values, True,
                       lit_idx=lit_idx, lit_q=lit_q, lit_count=lit_count)
@@ -316,7 +358,8 @@ def _value_finalize(q2, centers, valid2):
 
 def _run_value_pass1(work: jnp.ndarray, eb: float, chunk_values: int,
                      stats_on_device: Optional[bool] = None,
-                     kernel_impl: str = "auto") -> _Pass1:
+                     kernel_impl: str = "auto",
+                     amax: Optional[float] = None) -> _Pass1:
     """Value-direct twin of :func:`_run_pass1`: same _Pass1 contract,
     with per-chunk device centre codes instead of Lorenzo prediction.
     The integer field the literal check replays is q itself (the
@@ -332,9 +375,9 @@ def _run_value_pass1(work: jnp.ndarray, eb: float, chunk_values: int,
     q = q2.reshape(-1)[:n]
     centers_np = np.asarray(centers).astype(np.int64)
     if stats_on_device:
-        k_lit = min(n, max(256, n // 256))
         hists, lit_idx, lit_q, lit_count = _device_stats(
-            codes2, valid2, q, work.reshape(-1), eb, k_lit)
+            codes2, valid2, q, work.reshape(-1), eb,
+            literal_capacity(n, eb, amax))
         return _Pass1(codes2, outl2, delta2, valid2, q, np.asarray(hists),
                       n, n_chunks, chunk_values, True,
                       lit_idx=lit_idx, lit_q=lit_q, lit_count=lit_count,
@@ -352,11 +395,15 @@ def _literals(p1: _Pass1, x_flat: np.ndarray, eb: float, ndim: int,
 
     Host-stats path: direct dense check on the snapshot. Device-stats
     path: replay the float64 formula on the device's candidate positions
-    only (dense fallback when candidates overflow capacity). Values are
-    gathered from the caller's ORIGINAL array, and the reconstruction is
-    rounded through the ORIGINAL dtype (f32 or f64) exactly as the
-    staged reference's dequantize does."""
+    only. The candidate buffer is sized from the guard band's expected
+    yield (:func:`literal_capacity`); when the candidates overflow it
+    anyway, the check runs densely over every value on the host, which
+    is exact too and counts under ceaz_literal_dense_checks_total. Values
+    are gathered from the caller's ORIGINAL array, and the
+    reconstruction is rounded through the ORIGINAL dtype (f32 or f64)
+    exactly as the staged reference's dequantize does."""
     out_dtype = x_flat.dtype
+    om.add(om.LITERAL_CHECKS, side="encode")
     if not p1.stats_on_device:
         q = p1.q_host.astype(np.int64)
         rec = (q.astype(np.float64) * (2.0 * eb)).astype(out_dtype)
@@ -373,6 +420,7 @@ def _literals(p1: _Pass1, x_flat: np.ndarray, eb: float, ndim: int,
                        - x_flat[idx].astype(np.float64)) > eb)
         idx = idx[viol]
     else:       # candidate capacity overflow: exact dense pass on the host
+        om.add(om.LITERAL_DENSE_CHECKS, side="encode")
         if p1.predictor == "none":
             delta = np.asarray(p1.delta2).astype(np.int64)
             q = (delta + p1.centers[:, None]).reshape(-1)[:p1.n]
@@ -515,7 +563,8 @@ def compress_error_bounded(x: np.ndarray, eb: float, mode: str,
                            exact_build: bool = False,
                            stats_on_device: Optional[bool] = None,
                            kernel_impl: str = "auto",
-                           predictor: str = "lorenzo"):
+                           predictor: str = "lorenzo",
+                           amax: Optional[float] = None):
     """Fused abs/rel compression of a float array (any dtype/predictor).
 
     Returns a CEAZCompressed bit-compatible with the staged jax-backend
@@ -525,7 +574,8 @@ def compress_error_bounded(x: np.ndarray, eb: float, mode: str,
     each value against its chunk's device-computed centre code. Float64
     inputs quantize through the same f32 device pass (the staged jax
     backend's semantics); the float64 bound is restored by the literal
-    channel.
+    channel. `amax`, max |x| where the caller has it, sizes the literal
+    candidates (:func:`literal_capacity`); it never changes the bytes.
     """
     from ..core.ceaz import CEAZCompressed
     # capping at the stream length keeps chunk boundaries identical and
@@ -535,12 +585,13 @@ def compress_error_bounded(x: np.ndarray, eb: float, mode: str,
         ndim = 1
         work = jnp.asarray(x.reshape(-1), jnp.float32)
         p1 = _run_value_pass1(work, eb, chunk_values, stats_on_device,
-                              kernel_impl)
+                              kernel_impl, amax)
     else:
         ndim = min(x.ndim, 3)
         work_shape = x.shape if x.ndim <= 3 else (-1,) + x.shape[-2:]
         work = jnp.asarray(x.reshape(work_shape), jnp.float32)
-        p1 = _run_pass1(work, eb, ndim, chunk_values, stats_on_device)
+        p1 = _run_pass1(work, eb, ndim, chunk_values, stats_on_device,
+                        amax)
     decisions = _policy(p1.hists, coder, adaptive, exact_build)
     with ot.span("fused.encode_pass2", n_chunks=p1.n_chunks):
         enc = _encode_all(p1, decisions, block_size, kernel_impl)
@@ -632,12 +683,8 @@ def _bank_pass_fn(kernel_impl: str, predictor: str, ndim: int,
         oidx, odelta, ocount = jax.vmap(
             lambda m, d: _extract_sparse(m, d, k_outlier))(
             outl2 & valid2, delta2)
-        work_flat = work.reshape(-1)
-        rec = q.astype(jnp.float32) * (2.0 * eb)
-        margin = 16.0 * _EPS32 * (jnp.abs(rec) + jnp.abs(work_flat)) \
-            + 1e-38
-        cand = jnp.abs(rec - work_flat) > (eb - margin)
-        lit_idx, lit_q, lit_count = _extract_sparse(cand, q, k_literal)
+        lit_idx, lit_q, lit_count = _extract_sparse(
+            _literal_candidates(q, work.reshape(-1), eb), q, k_literal)
         return (hists, sel, totals, words, block_nbits,
                 oidx, odelta, ocount, lit_idx, lit_q, lit_count,
                 codes2, outl2, delta2, valid2, q, centers)
@@ -695,10 +742,8 @@ def _mega_pass_fn(kernel_impl: str, predictor: str, n_chunks: int,
         oidx, odelta, ocount = jax.vmap(
             lambda m, d: _extract_sparse(m, d, k_outlier))(
             outl2 & valid2, delta2)
-        rec = q.astype(jnp.float32) * (2.0 * eb)
-        margin = 16.0 * _EPS32 * (jnp.abs(rec) + jnp.abs(flat)) + 1e-38
-        cand = jnp.abs(rec - flat) > (eb - margin)
-        lit_idx, lit_q, lit_count = _extract_sparse(cand, q, k_literal)
+        lit_idx, lit_q, lit_count = _extract_sparse(
+            _literal_candidates(q, flat, eb), q, k_literal)
         return (hists, sel, totals, words, block_nbits,
                 oidx, odelta, ocount, lit_idx, lit_q, lit_count,
                 codes2, outl2, delta2, valid2, q, centers_out)
@@ -765,8 +810,14 @@ class _BankLayout:
 
 
 def _bank_layout(bank, shape, chunk_values: int, block_size: int,
-                 kernel_impl: str, predictor: str,
-                 stats_on_device: bool) -> _BankLayout:
+                 kernel_impl: str, predictor: str, stats_on_device: bool,
+                 bounds: Sequence[Tuple[float, float]] = ()) -> _BankLayout:
+    """The static sizing of a bank pass over arrays of `shape`.
+
+    `bounds` holds an (eb, max |x|) pair for each array the one program
+    compresses (one, or one a rank); the literal-candidate capacity is
+    the largest :func:`literal_capacity` over them, and its floor
+    without them."""
     n = int(np.prod(shape))
     chunk_values = max(1, min(chunk_values, n))
     n_chunks, _ = chunk_layout(n, chunk_values)
@@ -782,7 +833,8 @@ def _bank_layout(bank, shape, chunk_values: int, block_size: int,
                   chunk_values),
         _bank_w32(int(bank.lengths.max()), chunk_values),
         _cand_window(int(bank.lengths.min())), _k_outlier(chunk_values),
-        min(n, max(256, n // 256)), stats_on_device)
+        max(literal_capacity(n, eb, amax) for eb, amax in bounds)
+        if bounds else literal_capacity(n), stats_on_device)
 
 
 def _count_pass(lay: _BankLayout, passes: int = 1) -> None:
@@ -796,7 +848,8 @@ def compress_error_bounded_bank(x: np.ndarray, eb: float, mode: str,
                                 block_size: int,
                                 stats_on_device: Optional[bool] = None,
                                 kernel_impl: str = "auto",
-                                predictor: str = "lorenzo"):
+                                predictor: str = "lorenzo",
+                                amax: Optional[float] = None):
     """Single-pass fused compression against an offline codebook bank.
 
     Unlike :func:`compress_error_bounded`, the per-chunk codebook comes
@@ -810,12 +863,15 @@ def compress_error_bounded_bank(x: np.ndarray, eb: float, mode: str,
     device argmin bitwise (asserted). When a chunk's exact payload
     exceeds the BANK_PROVISION_BITS pack provisioning, only the pack
     re-runs at full capacity (the quantized codes stay device-resident).
+    `amax`, max |x| where the caller has it, sizes the literal candidates
+    (:func:`literal_capacity`); it never changes the bytes.
     """
     bank = coder.bank
     if stats_on_device is None:
         stats_on_device = _default_stats_on_device()
     lay = _bank_layout(bank, x.shape, chunk_values, block_size,
-                       kernel_impl, predictor, stats_on_device)
+                       kernel_impl, predictor, stats_on_device,
+                       () if amax is None else ((eb, amax),))
     run = lay.pass_fn()
     with ot.span("fused.h2d"):
         work, lengths_d, cwords_d = to_device(
@@ -1004,7 +1060,8 @@ def _sharded_pass_fn(lay: _BankLayout, mesh, axis: str):
 
 def compress_bank_sharded(x, ebs: Sequence[float], mode: str,
                           coders: Sequence[BankCoder], chunk_values: int,
-                          block_size: int, kernel_impl: str = "auto"):
+                          block_size: int, kernel_impl: str = "auto",
+                          amaxes: Sequence[float] = ()):
     """Lorenzo bank compression of every rank's brick of a rank-sharded
     float32 array (see :func:`rank_mesh_axis`), each on its own device.
 
@@ -1017,7 +1074,9 @@ def compress_bank_sharded(x, ebs: Sequence[float], mode: str,
     No field value crosses between devices. Each rank's record is
     finished on the host exactly as the single-array path finishes it,
     so rank r's record equals ``compress_error_bounded_bank`` of its
-    brick. The host keeps the dense literal check, so the raw bricks are
+    brick. `amaxes`, each brick's max |x|, size the one program's
+    literal candidates for the rank that needs the most. The host's
+    literal check reads each brick's values, so the raw bricks are
     pulled too (each from its own device). Returns (records, the raw
     bricks on the host as one (R, ...) array)."""
     from jax.sharding import NamedSharding
@@ -1026,7 +1085,7 @@ def compress_bank_sharded(x, ebs: Sequence[float], mode: str,
     ranks = x.shape[0]
     bank = coders[0].bank
     lay = _bank_layout(bank, x.shape[1:], chunk_values, block_size,
-                       kernel_impl, "lorenzo", True)
+                       kernel_impl, "lorenzo", True, tuple(zip(ebs, amaxes)))
     with ot.span("fused.h2d"):
         ebs_d, lengths_d, cwords_d = to_device(
             "encode", "fused.h2d",
@@ -1164,7 +1223,9 @@ def _window_pass1(seg2: np.ndarray, ebs, stats_on_device: bool):
     valid_all = valid3.reshape(w, cv)
     p1s: List[_Pass1] = []
     if stats_on_device:
-        k_lit = min(cv, max(256, cv // 256))
+        # each chunk has its own speculative bound and no extrema: the
+        # capacity's floor
+        k_lit = literal_capacity(cv)
         st = jax.vmap(lambda c, v, q, wk, e: _device_stats(
             c, v, q, wk, e, k_lit))(codes3, valid3, q2, work, ebs_j)
         hists = np.asarray(st[0])
@@ -1241,9 +1302,8 @@ def _mega_window(seg2: np.ndarray, ebs, bank, block_size: int,
     w, cv = seg2.shape
     w32 = _bank_w32(int(bank.lengths.max()), cv)
     cands = _cand_window(int(bank.lengths.min()))
-    k_lit = min(cv, max(256, cv // 256))
     run = _mega_window_fn(kernel_impl, w, cv, block_size, w32, cands,
-                          k_lit, stats_on_device)
+                          literal_capacity(cv), stats_on_device)
     with dispatch.measure("ceaz_chunk", kernel_impl):
         out = run(jnp.asarray(seg2, jnp.float32),
                   jnp.asarray(ebs, jnp.float32),
@@ -1492,8 +1552,9 @@ def batch_compress(shards: Sequence[np.ndarray], eb_rel: float,
         stacked = jnp.asarray(stack_np)
     nshards = stacked.shape[0]
     ndim = 1 if predictor == "none" else min(stacked.ndim - 1, 3)
-    ebs = [eb_rel * core_dq.value_range(s) if mode == "rel" else eb_rel
-           for s in shards]
+    extrema = [(float(np.max(s)), float(np.min(s))) for s in shards]
+    ebs = [eb_rel * core_dq.extrema_range(hi, lo) if mode == "rel"
+           else eb_rel for hi, lo in extrema]
 
     # pass 1 vmapped over the shard axis (per-shard eb)
     n = int(stacked[0].size)
@@ -1526,7 +1587,9 @@ def batch_compress(shards: Sequence[np.ndarray], eb_rel: float,
 
     p1s: List[_Pass1] = []
     if stats_on_device:
-        k_lit = min(n, max(256, n // 256))
+        # one traced pass for every shard: the largest shard's capacity
+        k_lit = max(literal_capacity(n, eb, max(abs(hi), abs(lo)))
+                    for eb, (hi, lo) in zip(ebs, extrema))
         st = jax.vmap(lambda c, v, q, w, e: _device_stats(
             c, v, q, w.reshape(-1), e, k_lit))(
             codes3, valid3, q2, work, ebs_j)

@@ -161,3 +161,99 @@ def test_float64_falls_back_to_staged(offline_cb, rng):
     rec = comp.decompress(c)
     assert c.word_bits == 64
     assert np.abs(rec - x64).max() <= 1e-5 * (x64.max() - x64.min())
+
+
+def _encode_counter(name):
+    from repro.obs import metrics as om
+    return om.counter(name, side="encode").value()
+
+
+@pytest.fixture(scope="module")
+def proxy_fields():
+    """Proxy fields normalized as the SDRBench fields are: at rel 1e-4
+    their guard band holds 1-3% of the values, over the 1/256 floor."""
+    return {"hacc": F.hacc_proxy(seed=0), "nyx": F.nyx_proxy(seed=5)}
+
+
+@pytest.mark.parametrize("codebook", ["exact", "bank"])
+@pytest.mark.parametrize("name", ["hacc", "nyx"])
+@pytest.mark.parametrize("capacity", ["sized", "floor"])
+def test_literal_candidates_sized_from_the_band(offline_cb, proxy_fields,
+                                                stats_on_device, monkeypatch,
+                                                codebook, name, capacity):
+    """At rel 1e-4 the band yields more candidates than the 1/256 floor
+    and fewer than the capacity sized from it: the literal check replays
+    the candidates only. Held to the floor, the candidates overflow and
+    the check runs densely on the host, once. Either way the literals,
+    and the whole stream, equal the staged reference's."""
+    from repro.obs import metrics as om
+    x = proxy_fields[name]
+    n = x.size
+    used = []
+    sized = fused.literal_capacity
+
+    def spy(n_, eb=None, amax=None):
+        k = sized(n_, eb, amax) if capacity == "sized" \
+            else sized(n_)
+        used.append(k)
+        return k
+    monkeypatch.setattr(fused, "literal_capacity", spy)
+    staged, fusedc = _pair(offline_cb, "rel", 1 << 20, 4096, eb=1e-4,
+                           codebook=codebook)
+    cs = staged.compress(x)
+    checks = _encode_counter(om.LITERAL_CHECKS)
+    dense = _encode_counter(om.LITERAL_DENSE_CHECKS)
+    cf = fusedc.compress(x)
+    _assert_bit_identical(cs, cf)
+    assert len(cs.literal_idx) > 0
+    assert _encode_counter(om.LITERAL_CHECKS) == checks + 1
+    overflows = stats_on_device and capacity == "floor"
+    assert _encode_counter(om.LITERAL_DENSE_CHECKS) == dense + overflows
+    if stats_on_device:
+        assert used == [n // 16 if capacity == "sized" else n // 256]
+
+
+def test_literal_candidates_bunched_at_the_bound_overflow(offline_cb,
+                                                          stats_on_device):
+    """Values on the half grid of the quantizer err by the bound itself,
+    so every one is a candidate, past any capacity the band's spread
+    predicts: the dense host check still gives the staged literals."""
+    from repro.obs import metrics as om
+    eb = 1e-3
+    x = ((np.arange(1 << 16) % 1000 + 0.5) * (2 * eb)).astype(np.float32)
+    staged, fusedc = _pair(offline_cb, "abs", 1 << 18, 4096, eb=eb)
+    cs = staged.compress(x)
+    dense = _encode_counter(om.LITERAL_DENSE_CHECKS)
+    cf = fusedc.compress(x)
+    _assert_bit_identical(cs, cf)
+    assert len(cs.literal_idx) > x.size // 16
+    assert _encode_counter(om.LITERAL_DENSE_CHECKS) \
+        == dense + stats_on_device
+    assert np.abs(fusedc.decompress(cf).astype(np.float64) - x).max() <= eb
+
+
+def test_literal_capacity_rule():
+    cap = fused.literal_capacity
+    # the cells' fields at rel 1e-4, max |x| about their range: n // 16
+    for n, rng_ in [(8779809, 256.0), (256 ** 3, 1.0)]:
+        assert cap(n, 1e-4 * rng_, 0.998 * rng_) == n // 16
+    # a 256x256x128 brick at rel 1e-3: the floor, n // 256
+    n = 256 * 256 * 128
+    assert cap(n, 1e-3, 0.9999) == cap(n, 1e-3, 1.0) == n // 256
+    # never under the floor, never over n; unknown extrema: the floor
+    for n in (1, 7, 255, 256, 4096, 65537, 8779809):
+        floor = min(n, max(256, n // 256))
+        assert cap(n) == cap(n, 1.0, None) == floor
+        assert cap(n, 0.0, 1.0) == cap(n, 1e-3, float("nan")) == floor
+        for eb, amax in [(1.0, 0.0), (1.0, 1.0), (1e-4, 1.0), (1e-7, 1.0),
+                         (1e-30, 1e30), (1e-4, 3e3)]:
+            assert floor <= cap(n, eb, amax) <= n
+    assert cap(4096, 1e-7, 1.0) == 4096
+    # six fields of one proxy: one bucket, so one compiled pass
+    for make, n in [(F.hacc_proxy, 8779809), (F.nyx_proxy, 256 ** 3)]:
+        caps = set()
+        for seed in range(6):
+            x = make(seed=seed)
+            hi, lo = float(x.max()), float(x.min())
+            caps.add(cap(n, 1e-4 * (hi - lo), max(abs(hi), abs(lo))))
+        assert caps == {n // 16}, (make.__name__, caps)

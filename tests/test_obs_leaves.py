@@ -334,12 +334,17 @@ def test_device_stats_pull_dense_deltas_in_d2h_on_literal_overflow(
     """With the pass's statistics on the device (the path a TPU takes),
     more literal candidates than the pass keeps send the literal check
     to the dense deltas: that pull belongs to `fused.d2h` and its bytes
-    count there, and the stream is the host-statistics path's."""
+    count there, the check counts as dense, and the stream is the
+    host-statistics path's. The capacity is held at its 1/256 floor,
+    which this field's guard band overflows."""
     from conftest import assert_streams_bit_identical
     x = _field(SHAPES[kind])
     comp = _comp()
     ref = comp.compress(x)
     monkeypatch.setattr(F, "_default_stats_on_device", lambda: True)
+    floor = F.literal_capacity
+    monkeypatch.setattr(F, "literal_capacity",
+                        lambda n, eb=None, amax=None: floor(n))
     pulls = []
     orig = F.to_host
 
@@ -356,4 +361,5 @@ def test_device_stats_pull_dense_deltas_in_d2h_on_literal_overflow(
     assert [(n_chunks, CHUNK_VALUES)] in pulls      # the dense deltas
     assert run.delta(om.D2H_BYTES, side="encode", site="fused.d2h") \
         >= 4 * n_chunks * CHUNK_VALUES
+    assert run.delta(om.LITERAL_DENSE_CHECKS, side="encode") == 1
     assert len(run.spans("fused.d2h")) == 1

@@ -113,12 +113,15 @@ def test_tpu_auto_route_compiles_and_fits(op, field, one_chip):
 def test_tpu_hacc_bank_pass_fits(one_chip):
     """The whole single-pass bank encode of a HACC field (quantize ->
     select -> pack -> escapes, one traced program) at the default chunk
-    compiles for a v5e and fits its HBM."""
+    compiles for a v5e and fits its HBM, with the literal-candidate
+    capacity a field at rel 1e-4 gets (n // 16)."""
     n = CHUNK
+    k_literal = fused.literal_capacity(n, 1e-4, 1.0)
+    assert k_literal == n // 16
     run = fused._mega_pass_fn(
         dispatch.auto_impl("ceaz_chunk", "tpu"), "lorenzo", 1, n, BLOCK,
         fused._bank_w32(fused.BANK_PROVISION_BITS, n), 33,
-        fused._k_outlier(n), max(256, n // 256), True)
+        fused._k_outlier(n), k_literal, True)
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     mem = run.lower(s((n,), jnp.float32), s((), jnp.float32),
                     s((BOOKS, 1024), jnp.int32),
@@ -135,7 +138,9 @@ def test_tpu_rank_sharded_gather_compiles_and_fits(topo, one_chip):
     described chip of a v5e:2x2 (the NYX 16-rank deployment's host
     share), and the gather of its payloads compile and fit each chip;
     the pass holds no collective and the gather one collective, over one
-    uint32 buffer per rank."""
+    uint32 buffer per rank. At rel 1e-3 of a brick's range (1.0, with
+    max |x| at most the range) the literal candidates keep the 1/256
+    floor, so that buffer stays 4,262,918 words a rank."""
     import re
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from repro.core.codebook import default_codebook_bank
@@ -145,7 +150,8 @@ def test_tpu_rank_sharded_gather_compiles_and_fits(topo, one_chip):
     brick = (256, 256, 128)
     lay = fused._bank_layout(bank, brick, CHUNK, BLOCK,
                              dispatch.auto_impl("hufenc", "tpu"), "lorenzo",
-                             True)
+                             True, ((1e-3, 1.0),) * 4)
+    assert lay.k_literal == int(np.prod(brick)) // 256
     ranked = NamedSharding(mesh, P("r"))
     rep = NamedSharding(mesh, P())
     s = lambda shape, dt, sh: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
@@ -164,6 +170,7 @@ def test_tpu_rank_sharded_gather_compiles_and_fits(topo, one_chip):
     payload = [s(o.shape, o.dtype, ranked)
                for o in jax.eval_shape(pass_fn, *args)[:11]]
     n = sum(int(np.prod(p.shape)) // 4 for p in payload)
+    assert n == 4_262_918
     gathered = collectives._gather_fn(mesh, "r", 11).lower(
         *payload).compile().as_text()
     # the TPU compiler may lower the all-gather as an all-reduce of each
