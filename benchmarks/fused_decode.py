@@ -5,16 +5,12 @@ the symmetric read path on the proxy corpus:
 
   * staged — the host reference decompressor: python loop over chunks,
     numpy table decode per chunk (`use_fused=False`);
-  * split  — runtime/fused_decode.py at its PR 3 stage boundaries: ONE
-    batched jit Huffman-decode pass over all chunks + device
-    outlier-scatter/inverse-quant passes (`decode_megakernel='split'`);
-  * mega   — the default fused route: the `ceaz_chunk_dec` decode
-    megakernel, walk + outlier patch + inverse dual-quant in one
-    launch (PR 9).
+  * mega   — the fused route: the `ceaz_chunk_dec` decode megakernel,
+    walk + outlier patch + inverse dual-quant in one launch.
 
-All decode the SAME compressed streams and are bit-identical
+Both decode the SAME compressed streams and are bit-identical
 (tests/test_fused_decode.py, tests/test_full_grid.py), so the
-comparison is pure throughput. Both fused columns must dominate staged
+comparison is pure throughput. The fused column must dominate staged
 — asserted at the end, since the nightly CI lane runs this as the
 decode-throughput acceptance gate. jit compilation is warmed before
 timing.
@@ -38,9 +34,7 @@ def run():
     offline_cb = default_offline_codebook()
     variants = {
         "staged": _comp(offline_cb, backend="jax", use_fused=False),
-        "split": _comp(offline_cb, use_fused=True,
-                       decode_megakernel="split"),
-        "mega": _comp(offline_cb, use_fused=True),   # the default route
+        "mega": _comp(offline_cb, use_fused=True),
     }
     rows = []
     totals = {k: [0.0, 0] for k in variants}
@@ -59,20 +53,14 @@ def run():
     tp = {k: v[1] / v[0] / 1e6 for k, v in totals.items()}
     speedup = tp["mega"] / tp["staged"]
     rows.append(dict(kind="summary", **{f"tp_{k}": v for k, v in tp.items()},
-                     fused_over_staged=speedup,
-                     split_over_staged=tp["split"] / tp["staged"],
-                     mega_over_split=tp["mega"] / tp["split"]))
+                     fused_over_staged=speedup))
     emit("fused_decode", rows,
          us_per_call=float(totals["mega"][0] * 1e6 / max(len(rows) - 1, 1)),
          derived=(f"mega={tp['mega']:.0f}MB/s;"
-                  f"split={tp['split']:.0f}MB/s;"
                   f"staged={tp['staged']:.0f}MB/s;"
                   f"speedup={speedup:.2f}x"))
     assert speedup >= 1.0, (
         f"megakernel decode slower than staged ({speedup:.2f}x)")
-    assert tp["split"] / tp["staged"] >= 1.0, (
-        f"split fused decode slower than staged "
-        f"({tp['split'] / tp['staged']:.2f}x)")
     return rows
 
 

@@ -45,7 +45,7 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 REL_EB = 1e-4
 FR_TARGET = 10.0
 FR_BAND = 0.15          # the paper's fixed-ratio acceptance (Fig 13)
-# the dispatch ops the one-chip phases run (hufdec only on split decodes)
+# the dispatch ops the one-chip phases run (no product path calls hufdec)
 ROUTED_OPS = ("ceaz_chunk", "ceaz_chunk_dec", "dq_center", "hufenc")
 
 

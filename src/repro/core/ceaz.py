@@ -118,11 +118,14 @@ class CEAZConfig:
 
     * ``use_fused`` — route eligible work through the device-resident
       fused pipeline (``runtime/fused.py`` / ``runtime/fused_decode.py``)
-      instead of the host-staged reference. Both paths are bit-identical
-      for the streams the fused path covers (float32 + Lorenzo).
-    * ``kernel_impl`` — which implementation of the fused pipeline's two
-      inner loops (encode gather-pack, decode table walk) to resolve
-      from the kernel-dispatch registry (``kernels/dispatch.py``).
+      instead of the host-staged reference. The fused path covers the
+      whole dtype x predictor x mode matrix and is bit-identical to the
+      staged path with ``backend='jax'``; its decode is one batched
+      device pass (the `ceaz_chunk_dec` op).
+    * ``kernel_impl`` — which implementation of the fused pipeline's
+      dispatch ops (the encode pack and chunk megakernel, the decode
+      chunk megakernel, the value-direct centre) to resolve from the
+      kernel-dispatch registry (``kernels/dispatch.py``).
 
     See ``docs/ARCHITECTURE.md`` for the full dtype x predictor x mode
     fallback matrix.
@@ -156,22 +159,13 @@ class CEAZConfig:
     # path is tested against. Output bytes NEVER depend on this knob;
     # a misprediction costs wasted device work, not different bits.
     speculation: int | str = "auto"
-    # Inner-loop implementation for the fused pipeline's two hot loops,
-    # resolved through kernels/dispatch.py: 'jnp' (XLA-compiled
+    # Implementation of the fused pipeline's dispatch ops, resolved
+    # through kernels/dispatch.py: 'jnp' (XLA-compiled
     # jax.numpy), 'pallas' (explicit kernels; interpreted off-TPU) or
     # 'auto' (the per-backend table in kernels/dispatch.py: jnp on every
     # backend today). An unknown name raises ValueError at first
     # compress/decompress.
     kernel_impl: str = "auto"
-    # Decode-side megakernel (kernels/megakernel/decode_kernel.py):
-    # 'auto'/'mega' run eligible fused decodes through `ceaz_chunk_dec`
-    # (Huffman walk + outlier patch + inverse dual-quant as ONE
-    # dispatched pass per group); 'split' forces the three-stage PR 3
-    # path (hufdec walk, then per-array scatter + inverse jits). Both
-    # are bit-identical (tests/test_full_grid.py); 'split' exists as
-    # the differential fence's second oracle and an escape hatch. An
-    # unknown name raises ValueError at first decompress.
-    decode_megakernel: str = "auto"
     # Codebook policy (docs/CODEBOOK_BANK.md): 'exact' keeps the
     # chi-driven adaptive coder (host tree builds between the fused
     # passes); 'bank' selects per chunk from an offline CodebookBank —
@@ -680,19 +674,13 @@ class CEAZ:
                 from ..runtime import fused_decode as FD
                 fused_idx = [i for i, c in enumerate(comps)
                              if FD.fused_decode_ok(c, self.offline)]
-                dmk = self.cfg.decode_megakernel
-                if dmk not in ("auto", "mega", "split"):
-                    raise ValueError(
-                        f"unknown decode_megakernel {dmk!r}; choose "
-                        "from ('auto', 'mega', 'split')")
                 if fused_idx:
                     for i in fused_idx:
                         self._check_block_size(comps[i])
                     dec = FD.decompress_batch(
                         [comps[i] for i in fused_idx],
                         self.cfg.block_size, self.offline,
-                        kernel_impl=self.cfg.kernel_impl, bank=self.bank,
-                        megakernel=dmk != "split")
+                        kernel_impl=self.cfg.kernel_impl, bank=self.bank)
                     for i, a in zip(fused_idx, dec):
                         out[i] = a
             res = [a if a is not None else self._decompress_staged(c)

@@ -10,7 +10,8 @@ compression, where selection is a pure argmin over precomputed tables
   kernel.py — the fused Pallas program (and the word-tiled composition
               for chunks past the single-program VMEM limit)
   ref.py    — the jnp twin composed from the existing stage ops
-              (bit-identity reference)
+              (bit-identity reference), and the bank back end
+              (histogram, selection, pack) the fused bank pass shares
   ops.py    — the `ceaz_chunk` dispatch-op wrapper
 
 See docs/ARCHITECTURE.md ("Encode megakernel") for the dataflow.

@@ -1,11 +1,14 @@
 """jnp references for the `ceaz_chunk` / `ceaz_chunk_dec` megakernel ops.
 
 Composed from the EXISTING stage implementations — core.dualquant for
-the quantizers, the dualquant `chunk_center` reduction, the histogram
-scatter-add and the hufenc jnp pack (`encode_pack`) — so its outputs are
-bitwise-identical to the staged fused pipeline (runtime/fused.py's
-`_bank_pass_fn` core) by construction, and serve as the bit-identity
-fence for the Pallas megakernel.
+the quantizers, the dualquant `chunk_center` reduction, and the bank
+back end below (`chunk_histograms` -> `select_bank` -> `bank_encode`
+with the hufenc jnp pack) — so its outputs are bitwise-identical to the
+staged fused pipeline by construction, and serve as the bit-identity
+fence for the Pallas megakernel. The back end is written once here: the
+fused runtime's bank pass (runtime/fused.py `_bank_pass_fn`) runs the
+same `bank_encode` after its N-D quantize pass, and `_device_stats` the
+same `chunk_histograms`.
 
 The decode twin (`ceaz_chunk_dec`, bottom of this module) composes the
 hufdec lockstep walk with `patch_and_inverse`, the shared outlier-patch
@@ -83,6 +86,15 @@ def _quantize_rows(work2, prev2, valid2, ebs, predictor):
     return q2, codes2, outl2, delta2, centers
 
 
+def chunk_histograms(codes2, valid2):
+    """(C, NUM_SYMBOLS) i32 per-row histograms of the valid codes, one
+    scatter-add over the (C, cv) rows."""
+    cidx = jnp.broadcast_to(
+        jnp.arange(codes2.shape[0], dtype=jnp.int32)[:, None], codes2.shape)
+    return jnp.zeros((codes2.shape[0], NUM_SYMBOLS), jnp.int32) \
+        .at[cidx, codes2].add(valid2.astype(jnp.int32))
+
+
 def select_bank(hists, bank_lengths):
     """(sel, totals): exact-integer argmin_k of hist . lengths_k. The
     statistic is small (<= 16 * cv) so int32 is exact; first-occurrence
@@ -92,6 +104,20 @@ def select_bank(hists, bank_lengths):
     sel = jnp.argmin(costs, axis=1).astype(jnp.int32)
     totals = jnp.take_along_axis(costs, sel[:, None], axis=1)[:, 0]
     return sel, totals
+
+
+def bank_encode(codes2, valid2, bank_lengths, bank_cwords, block_size: int,
+                w32: int, cands: int, encode_pack=hufenc_ref.encode_pack):
+    """The bank back end over quantized (C, cv) code rows: histogram ->
+    bank selection -> gather the selected rows -> Huffman pack through
+    `encode_pack` (a `hufenc` implementation). -> (hists, sel, totals,
+    words, block_nbits)."""
+    hists = chunk_histograms(codes2, valid2)
+    sel, totals = select_bank(hists, bank_lengths)
+    words, block_nbits = encode_pack(
+        codes2, valid2, bank_lengths[sel], bank_cwords[sel],
+        block_size, w32, cands)
+    return hists, sel, totals, words, block_nbits
 
 
 @functools.partial(jax.jit,
@@ -105,17 +131,9 @@ def ceaz_chunk(work2, prev2, valid2, ebs, bank_lengths, bank_cwords,
     q2, codes2, outl2, delta2, centers = _quantize_rows(
         jnp.asarray(work2), jnp.asarray(prev2), valid2,
         jnp.asarray(ebs), predictor)
-    C = codes2.shape[0]
-    cidx = jnp.broadcast_to(
-        jnp.arange(C, dtype=jnp.int32)[:, None], codes2.shape)
-    hists = jnp.zeros((C, NUM_SYMBOLS), jnp.int32) \
-        .at[cidx, codes2].add(valid2.astype(jnp.int32))
-    bank_lengths = jnp.asarray(bank_lengths, jnp.int32)
-    bank_cwords = jnp.asarray(bank_cwords, jnp.uint32)
-    sel, totals = select_bank(hists, bank_lengths)
-    words, block_nbits = hufenc_ref.encode_pack(
-        codes2, valid2, bank_lengths[sel], bank_cwords[sel],
-        block_size, w32, cands)
+    hists, sel, totals, words, block_nbits = bank_encode(
+        codes2, valid2, jnp.asarray(bank_lengths, jnp.int32),
+        jnp.asarray(bank_cwords, jnp.uint32), block_size, w32, cands)
     return (q2, codes2, outl2, delta2, centers, hists, sel, totals,
             words, block_nbits)
 

@@ -23,6 +23,15 @@ streaming structure (and cuSZ's fused GPU kernels):
             formulation or the Pallas VMEM-resident kernel, selected by
             CEAZConfig(kernel_impl=...)).
 
+With an offline codebook bank (``codebook='bank'``) the two passes
+collapse into ONE traced pass with no host work between quantize and
+pack, built by :func:`_bank_pass_fn` from the pass's static layout
+(:class:`_BankLayout`). Its quantize front end follows the shape —
+whole chunk rows through the `ceaz_chunk` dispatch op for 1-D and
+value-direct fields, the global N-D Lorenzo pass otherwise — and its
+back end (histogram, bank selection, pack) is
+``kernels/megakernel/ref.bank_encode`` on every route.
+
 Bit-exactness contract: given the same quantization backend, the fused
 path produces payloads (words, block_nbits, outliers, literals)
 BIT-IDENTICAL to ``core.ceaz.CEAZ`` with ``use_fused=False,
@@ -61,6 +70,7 @@ from ..core import dualquant as core_dq
 from ..core.codebook import AdaptiveCoder, BankCoder
 from ..core.huffman import DEFAULT_MAX_LEN, NUM_SYMBOLS, Codebook
 from ..kernels import dispatch
+from ..kernels.megakernel import ref as mega_ref
 from ..obs import metrics as om
 from ..obs import trace as ot
 
@@ -139,11 +149,7 @@ def _device_stats(codes2, valid2, q, work_flat, eb, k_literal):
     at each candidate — the host replays the float64 formula on just
     those to recover the staged path's exact literal set.
     """
-    n_chunks = codes2.shape[0]
-    cidx = jnp.broadcast_to(jnp.arange(n_chunks, dtype=jnp.int32)[:, None],
-                            codes2.shape)
-    hists = jnp.zeros((n_chunks, NUM_SYMBOLS), jnp.int32) \
-        .at[cidx, codes2].add(valid2.astype(jnp.int32))
+    hists = mega_ref.chunk_histograms(codes2, valid2)
     lit_idx, lit_q, lit_count = _extract_sparse(
         _literal_candidates(q, work_flat, eb), q, k_literal)
     return hists, lit_idx, lit_q, lit_count
@@ -632,11 +638,8 @@ def _bank_fits(totals: np.ndarray, w32: int) -> bool:
 
 
 @functools.lru_cache(maxsize=None)
-def _bank_pass_fn(kernel_impl: str, predictor: str, ndim: int,
-                  n_chunks: int, chunk_values: int, block_size: int,
-                  w32: int, cands: int, k_outlier: int, k_literal: int,
-                  stats_on_device: bool):
-    """Build (and cache) the fused single-pass trace for one work shape.
+def _bank_pass_fn(lay: _BankLayout):
+    """Build (and cache) the fused single-pass trace for one layout.
 
     The returned jitted function runs quantize -> per-chunk histogram ->
     bank selection (argmin over hist . lengths_k) -> gather the selected
@@ -645,108 +648,67 @@ def _bank_pass_fn(kernel_impl: str, predictor: str, ndim: int,
     the whole result tuple in a single transfer. The selection statistic
     is integer and small (<= 16 * chunk_values per entry), so the host
     drift replay in ``core.codebook.BankCoder`` reproduces the device
-    argmin bitwise. Outlier / literal-candidate compaction joins the
-    trace only on real accelerators (``stats_on_device``); on CPU hosts
+    argmin bitwise.
+
+    The quantize front end follows the layout (``lay.mega``): shapes
+    whose Lorenzo halo is one raw value (1-D streams, value-direct) cut
+    the field into chunk rows and run the whole chunk through the
+    `ceaz_chunk` dispatch op (the halo re-quantizes exactly the q[i-1]
+    the global pass used; see kernels/megakernel/ref.py); higher-rank
+    Lorenzo quantizes the global field (:func:`_quantize_pass`) and
+    runs the same bank back end (``mega_ref.bank_encode``) with the
+    `hufenc` op. Outlier / literal-candidate compaction joins the trace
+    only on real accelerators (``lay.stats_on_device``); on CPU hosts
     the dense snapshots are cheaper than XLA scatters, exactly as in
     :func:`_run_pass1`.
     """
-    encode_pack = dispatch.resolve("hufenc", kernel_impl)
-    center_fn = (dispatch.resolve("dq_center", kernel_impl)
-                 if predictor == "none" else None)
-
-    @jax.jit
-    def run(work, eb, bank_lengths, bank_cwords):
-        if predictor == "none":
-            q2, valid2 = _value_prequantize(work, eb, n_chunks,
-                                            chunk_values)
-            centers = center_fn(q2, valid2)
-            codes2, outl2, delta2 = _value_finalize(q2, centers, valid2)
-            q = q2.reshape(-1)[:work.size]
-        else:
-            codes2, outl2, delta2, valid2, q = _quantize_pass(
-                work, eb, ndim, n_chunks, chunk_values)
-            centers = None
-        cidx = jnp.broadcast_to(
-            jnp.arange(n_chunks, dtype=jnp.int32)[:, None], codes2.shape)
-        hists = jnp.zeros((n_chunks, NUM_SYMBOLS), jnp.int32) \
-            .at[cidx, codes2].add(valid2.astype(jnp.int32))
-        costs = jnp.einsum("cs,ks->ck", hists, bank_lengths)
-        sel = jnp.argmin(costs, axis=1).astype(jnp.int32)
-        totals = jnp.take_along_axis(costs, sel[:, None], axis=1)[:, 0]
-        words, block_nbits = encode_pack(
-            codes2, valid2, bank_lengths[sel], bank_cwords[sel],
-            block_size, w32, cands)
-        if not stats_on_device:
-            return (hists, sel, totals, words, block_nbits,
-                    None, None, None, None, None, None,
-                    codes2, outl2, delta2, valid2, q, centers)
-        oidx, odelta, ocount = jax.vmap(
-            lambda m, d: _extract_sparse(m, d, k_outlier))(
-            outl2 & valid2, delta2)
-        lit_idx, lit_q, lit_count = _extract_sparse(
-            _literal_candidates(q, work.reshape(-1), eb), q, k_literal)
-        return (hists, sel, totals, words, block_nbits,
-                oidx, odelta, ocount, lit_idx, lit_q, lit_count,
-                codes2, outl2, delta2, valid2, q, centers)
-
-    return run
-
-
-@functools.lru_cache(maxsize=None)
-def _mega_pass_fn(kernel_impl: str, predictor: str, n_chunks: int,
-                  chunk_values: int, block_size: int, w32: int,
-                  cands: int, k_outlier: int, k_literal: int,
-                  stats_on_device: bool):
-    """:func:`_bank_pass_fn` twin built on the `ceaz_chunk` megakernel
-    dispatch op: quantize -> histogram -> bank-select -> pack run as ONE
-    op (one Pallas program per chunk under 'pallas') instead of a trace
-    composed from the stage ops. Same return contract, bit-identical
-    outputs. Only the shapes whose Lorenzo halo is a single raw value
-    qualify — 1-D streams and value-direct — because the op quantizes
-    each chunk row from a one-value halo, which reproduces global
-    Lorenzo bitwise only in 1-D (the halo re-quantizes exactly the
-    q[i-1] the global pass used; see kernels/megakernel/ref.py).
-    Higher-rank Lorenzo keeps using `_bank_pass_fn`.
-    """
-    ceaz_op = dispatch.resolve("ceaz_chunk", kernel_impl)
-    op_pred = "value" if predictor == "none" else "lorenzo"
+    n_chunks, chunk_values = lay.n_chunks, lay.chunk_values
+    if lay.mega:
+        ceaz_op = dispatch.resolve("ceaz_chunk", lay.kernel_impl)
+        op_pred = "value" if lay.predictor == "none" else "lorenzo"
+    else:
+        encode_pack = dispatch.resolve("hufenc", lay.kernel_impl)
 
     @jax.jit
     def run(work, eb, bank_lengths, bank_cwords):
         flat = work.reshape(-1)
-        n = flat.shape[0]
-        pad = n_chunks * chunk_values - n
-        work2 = jnp.pad(flat, (0, pad)).reshape(n_chunks, chunk_values)
-        valid2 = (jnp.arange(n_chunks * chunk_values, dtype=jnp.int32)
-                  < n).reshape(n_chunks, chunk_values)
-        ci = jnp.arange(n_chunks, dtype=jnp.int32)
-        if op_pred == "lorenzo":
-            # row i's halo: the RAW predecessor of its first value
-            # (row 0 gets the stream head's zero-pad)
-            prev2 = jnp.where(
-                ci == 0, jnp.float32(0),
-                flat[jnp.maximum(ci * chunk_values - 1, 0)])[:, None]
+        if lay.mega:
+            pad = n_chunks * chunk_values - lay.n
+            work2 = jnp.pad(flat, (0, pad)).reshape(n_chunks, chunk_values)
+            valid2 = (jnp.arange(n_chunks * chunk_values, dtype=jnp.int32)
+                      < lay.n).reshape(n_chunks, chunk_values)
+            ci = jnp.arange(n_chunks, dtype=jnp.int32)
+            if op_pred == "lorenzo":
+                # row i's halo: the RAW predecessor of its first value
+                # (row 0 gets the stream head's zero-pad)
+                prev2 = jnp.where(
+                    ci == 0, jnp.float32(0),
+                    flat[jnp.maximum(ci * chunk_values - 1, 0)])[:, None]
+            else:
+                prev2 = jnp.zeros((n_chunks, 1), jnp.float32)
+            ebs = jnp.broadcast_to(jnp.asarray(eb, jnp.float32), (n_chunks,))
+            (q2, codes2, outl2, delta2, centers, hists, sel, totals, words,
+             block_nbits) = ceaz_op(work2, prev2, valid2, ebs, bank_lengths,
+                                    bank_cwords, lay.block_size, lay.w32,
+                                    lay.cands, op_pred)
+            q = q2.reshape(-1)[:lay.n]
+            if op_pred == "lorenzo":
+                centers = None
         else:
-            prev2 = jnp.zeros((n_chunks, 1), jnp.float32)
-        ebs = jnp.broadcast_to(jnp.asarray(eb, jnp.float32), (n_chunks,))
-        (q2, codes2, outl2, delta2, centers, hists, sel, totals, words,
-         block_nbits) = ceaz_op(work2, prev2, valid2, ebs, bank_lengths,
-                                bank_cwords, block_size, w32, cands,
-                                op_pred)
-        q = q2.reshape(-1)[:n]
-        centers_out = centers if op_pred == "value" else None
-        if not stats_on_device:
-            return (hists, sel, totals, words, block_nbits,
-                    None, None, None, None, None, None,
-                    codes2, outl2, delta2, valid2, q, centers_out)
-        oidx, odelta, ocount = jax.vmap(
-            lambda m, d: _extract_sparse(m, d, k_outlier))(
-            outl2 & valid2, delta2)
-        lit_idx, lit_q, lit_count = _extract_sparse(
-            _literal_candidates(q, flat, eb), q, k_literal)
-        return (hists, sel, totals, words, block_nbits,
-                oidx, odelta, ocount, lit_idx, lit_q, lit_count,
-                codes2, outl2, delta2, valid2, q, centers_out)
+            codes2, outl2, delta2, valid2, q = _quantize_pass(
+                work, eb, lay.ndim, n_chunks, chunk_values)
+            centers = None
+            hists, sel, totals, words, block_nbits = mega_ref.bank_encode(
+                codes2, valid2, bank_lengths, bank_cwords, lay.block_size,
+                lay.w32, lay.cands, encode_pack)
+        stats = (None,) * 6
+        if lay.stats_on_device:
+            oidx, odelta, ocount = _extract_outliers(outl2, delta2, valid2,
+                                                     lay.k_outlier)
+            stats = (oidx, odelta, ocount, *_extract_sparse(
+                _literal_candidates(q, flat, eb), q, lay.k_literal))
+        return (hists, sel, totals, words, block_nbits, *stats,
+                codes2, outl2, delta2, valid2, q, centers)
 
     return run
 
@@ -789,7 +751,7 @@ class _BankLayout:
     def mega(self) -> bool:
         # the megakernel op covers exactly the shapes whose Lorenzo halo
         # is one raw value — 1-D streams and value-direct; higher-rank
-        # Lorenzo keeps the stage-composed trace (same outputs either way)
+        # Lorenzo quantizes the global field (same outputs either way)
         return self.predictor == "none" or self.ndim == 1
 
     @property
@@ -798,15 +760,7 @@ class _BankLayout:
 
     def pass_fn(self):
         """The cached jitted bank pass `run(work, eb, lengths, cwords)`."""
-        if self.mega:
-            return _mega_pass_fn(
-                self.kernel_impl, self.predictor, self.n_chunks,
-                self.chunk_values, self.block_size, self.w32, self.cands,
-                self.k_outlier, self.k_literal, self.stats_on_device)
-        return _bank_pass_fn(
-            self.kernel_impl, self.predictor, self.ndim, self.n_chunks,
-            self.chunk_values, self.block_size, self.w32, self.cands,
-            self.k_outlier, self.k_literal, self.stats_on_device)
+        return _bank_pass_fn(self)
 
 
 def _bank_layout(bank, shape, chunk_values: int, block_size: int,

@@ -5,31 +5,22 @@ module is its symmetric inverse. The staged reference decompressor
 (``core.ceaz.CEAZ.decompress``) walks chunks in a host python loop and
 runs the canonical-Huffman table decode in numpy, one chunk at a time —
 exactly the chunk-sequential host bounce cuSZ/FZ-GPU show the read path
-cannot afford. Here the three per-value stages run as jit-compiled
-batched passes:
+cannot afford. Here the per-value stages run as ONE batched device
+pass, the `ceaz_chunk_dec` dispatch op (kernels/megakernel):
 
-  pass 1  — canonical-Huffman table decode of EVERY chunk in the batch
-            (across arrays: the batch dimension is the union of all
-            chunks of all arrays in the group). Each chunk decodes its
-            blocks in parallel lanes — the multi-pipeline FPGA decoder
-            with (n_chunks x n_blocks) lanes instead of n_blocks.
-  pass 2  — outlier scatter (code 0 escapes -> stored deltas) and the
-            inverse dual-quant (multi-axis inclusive cumsum) per array,
-            codes staying device-resident between the passes.
+  device  — over EVERY chunk in the batch (across arrays: the batch
+            dimension is the union of all chunks of all arrays in the
+            group): the canonical-Huffman table walk, each chunk's
+            blocks in parallel lanes (the multi-pipeline FPGA decoder
+            with (n_chunks x n_blocks) lanes instead of n_blocks), the
+            outlier patch (code 0 escapes -> stored deltas) and the 1-D
+            inverse dual-quant. Higher-rank abs/rel fields take their
+            multi-axis cumsum in a follow-up jit (``_nd_cumsum``).
   host    — ONLY the final scale multiply (the staged reference computes
             it through float64, which jax does not carry by default) and
             the literal patch: one vectorized elementwise op each, at
             memory bandwidth. Everything bit-width-heavy (table walk,
-            scatter, prefix sums) never touches host numpy.
-
-Since PR 9 the default route collapses passes 1+2 into the
-`ceaz_chunk_dec` decode megakernel (kernels/megakernel): walk, outlier
-patch and inverse dual-quant in ONE dispatched pass over the whole
-group — one kernel launch per group instead of three stages — with the
-split path above retained behind ``CEAZConfig(decode_megakernel=
-'split')`` and as the differential fence's second oracle. Higher-rank
-abs/rel fields take their multi-axis cumsum in a follow-up jit
-(``_nd_cumsum``); the host finish is unchanged.
+            patch, prefix sums) never touches host numpy.
 
 Bit-exactness contract: for float32 Lorenzo streams the output is
 BIT-IDENTICAL to the staged reference in every mode (abs/rel/
@@ -57,7 +48,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import dualquant as core_dq
 from ..core.huffman import DEFAULT_MAX_LEN, Codebook, replay_codebooks
 from ..kernels import dispatch
 from ..obs import metrics as om
@@ -67,56 +57,12 @@ from .fused import to_device, to_host
 MAX_CODE_BITS = DEFAULT_MAX_LEN
 _TBL = 1 << MAX_CODE_BITS
 
-# Pass 1 — the batched block-parallel canonical-Huffman table walk —
-# lives behind the kernel-dispatch layer (kernels/dispatch.py, op
-# 'hufdec'): 'jnp' is the lockstep vectorized walk this module ran
-# inline before PR 4 (kernels/hufdec/ref.py), 'pallas' the explicit
-# VMEM-resident kernel (kernels/hufdec/kernel.py). Both are bit-exact;
-# CEAZConfig(kernel_impl=...) selects, 'auto' resolves per backend.
-
-
-# ---------------------------------------------------------------------------
-# Pass 2: outlier scatter + inverse dual-quant (device-resident)
-# ---------------------------------------------------------------------------
-
-def _scatter_outliers(codes2, oidx2, odelta2):
-    """codes -> deltas with the escape symbols replaced by their stored
-    values. Padding entries carry an out-of-range index (mode='drop')."""
-    delta2 = codes2.astype(jnp.int32) - core_dq.RADIUS
-    cidx = jnp.broadcast_to(
-        jnp.arange(delta2.shape[0], dtype=jnp.int32)[:, None], oidx2.shape)
-    return delta2.at[cidx, oidx2].set(odelta2, mode="drop")
-
-
-@functools.partial(jax.jit, static_argnames=("ndim", "n", "work_shape"))
-def _inverse_nd(codes2, oidx2, odelta2, ndim, n, work_shape):
-    """abs/rel: one Lorenzo field cut into chunks -> flat integer q.
-
-    The cumsum crosses chunk boundaries exactly as the encoder's single
-    whole-array quantization pass did.
-    """
-    delta2 = _scatter_outliers(codes2, oidx2, odelta2)
-    delta = delta2.reshape(-1)[:n].reshape(work_shape)
-    q = delta
-    for ax in range(ndim):
-        q = jnp.cumsum(q, axis=ax, dtype=jnp.int32)
-    return q.reshape(-1)
-
-
-@jax.jit
-def _inverse_1d_chunks(codes2, oidx2, odelta2):
-    """fixed_ratio: every chunk is an independent 1-D stream."""
-    delta2 = _scatter_outliers(codes2, oidx2, odelta2)
-    return jnp.cumsum(delta2, axis=1, dtype=jnp.int32)
-
-
-@jax.jit
-def _inverse_value_chunks(codes2, oidx2, odelta2, centers):
-    """value-direct: per-chunk centre add, no prefix sum. int32 adds
-    wrap exactly inversely to the encoder's wrapped deltas, so q is
-    recovered bit-exactly within the quantizer's +-2e9 envelope."""
-    delta2 = _scatter_outliers(codes2, oidx2, odelta2)
-    return delta2 + centers[:, None].astype(jnp.int32)
+# The pass lives behind the kernel-dispatch layer (kernels/dispatch.py,
+# op 'ceaz_chunk_dec'): 'jnp' composes the lockstep hufdec walk with
+# the shared patch/inverse tail (kernels/megakernel/ref.py), 'pallas'
+# is the one-program-per-chunk kernel (kernels/megakernel/
+# decode_kernel.py). Both are bit-exact; CEAZConfig(kernel_impl=...)
+# selects, 'auto' resolves per backend.
 
 
 # ---------------------------------------------------------------------------
@@ -160,14 +106,10 @@ def fused_decode_ok(c, offline: Codebook) -> bool:
 class _ChunkBatch:
     """Host staging of one group's chunks for the batched decode pass.
 
-    Two run modes share the staging:
-
-    * ``run()`` — the hufdec table walk alone (the PR 3 split path);
-      pass 2 (``_split_q``) and the host finish follow per array.
-    * ``run_mega()`` — the `ceaz_chunk_dec` decode megakernel: walk,
-      rank-gather outlier patch and inverse dual-quant in ONE
-      dispatched pass over the whole group; only the float64 scale
-      multiply + literal patch remain (``_finish``).
+    ``run()`` runs the `ceaz_chunk_dec` decode megakernel: walk,
+    rank-gather outlier patch and inverse dual-quant in ONE dispatched
+    pass over the whole group; only the float64 scale multiply +
+    literal patch remain (``_finish``).
     """
 
     def __init__(self, block_size: int, kernel_impl: str = "auto"):
@@ -210,8 +152,8 @@ class _ChunkBatch:
         self.spans.append((row0, len(self.counts)))
 
     def _stage(self):
-        """Pad the staged chunks to capacity buckets and stack the
-        unique decode tables (shared by both run modes)."""
+        """Pad the staged chunks to capacity buckets, stack the unique
+        decode tables and pad the megakernel's per-row metadata."""
         C = len(self.counts)
         c_cap = _bucket_pow2(C)
         nb_cap = _bucket_pow2(max(len(b) for b in self.nbits))
@@ -240,40 +182,8 @@ class _ChunkBatch:
         while len(tables_sym) < k_cap:
             tables_sym.append(np.zeros(_TBL, np.uint16))
             tables_len.append(np.zeros(_TBL, np.uint8))
-        return (words2, nbits2, counts, np.concatenate(tables_sym),
-                np.concatenate(tables_len), cb_idx)
-
-    def _upload(self, staged):
-        with ot.span("fused_decode.h2d"):
-            return to_device("decode", "fused_decode.h2d",
-                             *(jnp.asarray(a) for a in staged))
-
-    def _count_pass(self, op: str, nbits2) -> None:
-        """Values the pass was sized for (C_cap x NB_cap blocks) and the
-        real values it carried."""
-        c_cap, nb_cap = nbits2.shape
-        om.add(om.PASS_VALUES, c_cap * nb_cap * self.block_size,
-               side="decode", op=op)
-        om.add(om.PASS_LIVE_VALUES, sum(self.counts), side="decode", op=op)
-
-    def run(self):
-        """-> device codes (C_cap, NB_cap*block_size) uint16 (padded)."""
-        with ot.span("fused_decode.stage"):
-            staged = self._stage()
-        dev = self._upload(staged)
-        decode_blocks = dispatch.resolve("hufdec", self.kernel_impl)
-        with dispatch.measure("hufdec", self.kernel_impl):
-            out = decode_blocks(*dev, self.block_size)
-        self._count_pass("hufdec", staged[1])
-        return out
-
-    def _stage_mega(self):
-        """`_stage` plus the megakernel's per-row metadata, padded."""
-        words2, nbits2, counts, sym_flat, len_flat, cb_idx = self._stage()
-        c_cap = len(counts)
-        C = len(self.counts)
-        k = _bucket_pow2(max(1, max(len(d) for d in self.odelta)))
-        odelta2 = np.zeros((c_cap, k), np.int32)
+        k_out = _bucket_pow2(max(1, max(len(d) for d in self.odelta)))
+        odelta2 = np.zeros((c_cap, k_out), np.int32)
         for i, d in enumerate(self.odelta):
             odelta2[i, :len(d)] = d.astype(np.int32)
         base = np.zeros(c_cap, np.int32)
@@ -282,33 +192,29 @@ class _ChunkBatch:
         islor[:C] = self.islor
         seg0 = np.arange(c_cap, dtype=np.int32)    # padding: own segment
         seg0[:C] = self.seg0
-        return (words2, nbits2, counts, sym_flat, len_flat, cb_idx,
-                odelta2, base, seg0, islor)
+        return (words2, nbits2, counts, np.concatenate(tables_sym),
+                np.concatenate(tables_len), cb_idx, odelta2, base, seg0,
+                islor)
 
-    def run_mega(self):
+    def run(self):
         """-> device q (C_cap, NB_cap*block_size) int32 (padded): the
         `ceaz_chunk_dec` megakernel over the whole group."""
         with ot.span("fused_decode.stage"):
-            staged = self._stage_mega()
-        dev = self._upload(staged)
+            staged = self._stage()
+        with ot.span("fused_decode.h2d"):
+            dev = to_device("decode", "fused_decode.h2d",
+                            *(jnp.asarray(a) for a in staged))
         fn = dispatch.resolve("ceaz_chunk_dec", self.kernel_impl)
         with dispatch.measure("ceaz_chunk_dec", self.kernel_impl):
             out = fn(*dev, self.block_size)
-        self._count_pass("ceaz_chunk_dec", staged[1])
+        # values the pass was sized for (C_cap x NB_cap blocks) and the
+        # real values it carried
+        c_cap, nb_cap = staged[1].shape
+        om.add(om.PASS_VALUES, c_cap * nb_cap * self.block_size,
+               side="decode", op="ceaz_chunk_dec")
+        om.add(om.PASS_LIVE_VALUES, sum(self.counts), side="decode",
+               op="ceaz_chunk_dec")
         return out
-
-
-def _padded_outliers(chunks) -> Tuple[np.ndarray, np.ndarray]:
-    """(C, K) outlier index/delta arrays; padding indices point one past
-    the chunk so the device scatter drops them."""
-    k = max(1, max(len(ch.outlier_idx) for ch in chunks))
-    oidx = np.full((len(chunks), k), 1 << 30, np.int32)
-    odelta = np.zeros((len(chunks), k), np.int32)
-    for i, ch in enumerate(chunks):
-        m = len(ch.outlier_idx)
-        oidx[i, :m] = ch.outlier_idx.astype(np.int32)
-        odelta[i, :m] = ch.outlier_delta.astype(np.int32)
-    return oidx, odelta
 
 
 def _finish_host(c, q: np.ndarray, eb_per_value: np.ndarray) -> np.ndarray:
@@ -334,32 +240,6 @@ def _per_chunk(c) -> bool:
             or c.mode == "fixed_ratio")
 
 
-def _split_host_args(c):
-    """Host arrays the split route's inverse pass takes beside the
-    decoded rows: padded outliers (and value-direct centre codes)."""
-    oidx, odelta = _padded_outliers(c.chunks)
-    if getattr(c, "predictor", "lorenzo") == "none":
-        return oidx, odelta, np.asarray([ch.center for ch in c.chunks],
-                                        np.int32)
-    return oidx, odelta
-
-
-def _split_q(codes_rows, c, oidx, odelta, centers=None):
-    """Split route, pass 2 for one array on the device: outlier scatter
-    and inverse dual-quant of its decoded chunk rows (possibly wider
-    than the array's chunk_values)."""
-    cv = int(c.chunks[0].n_values)
-    rows = codes_rows[:, :cv]
-    if centers is not None:
-        # value-direct: per-chunk centre add on device, no prefix sum
-        return _inverse_value_chunks(rows, oidx, odelta, centers)
-    if c.mode in ("abs", "rel"):
-        return _inverse_nd(rows, oidx, odelta, c.ndim, int(c.n_values),
-                           _work_shape(c))
-    # fixed_ratio: independent chunks, per-chunk eb
-    return _inverse_1d_chunks(rows, oidx, odelta)
-
-
 def _finish(c, q: np.ndarray) -> np.ndarray:
     """Host finish for one array from its pulled integer field: per-chunk
     rows (value-direct, fixed_ratio) or the flat field (a 1-D chain may
@@ -376,8 +256,9 @@ def _finish(c, q: np.ndarray) -> np.ndarray:
 @functools.partial(jax.jit, static_argnames=("ndim", "n", "work_shape"))
 def _nd_cumsum(delta2, ndim, n, work_shape):
     """Multi-axis inverse-Lorenzo for megakernel delta-passthrough rows
-    (higher-rank abs/rel fields) — the `_inverse_nd` cumsum alone, the
-    patch already applied in-kernel."""
+    (higher-rank abs/rel fields): the cumsum crosses chunk boundaries
+    exactly as the encoder's single whole-array quantization pass did;
+    the outlier patch is already applied in-kernel."""
     q = delta2.reshape(-1)[:n].reshape(work_shape)
     for ax in range(ndim):
         q = jnp.cumsum(q, axis=ax, dtype=jnp.int32)
@@ -385,7 +266,7 @@ def _nd_cumsum(delta2, ndim, n, work_shape):
 
 
 def _mega_q(q_rows, c):
-    """Megakernel route, the device rest for one array: its q rows
+    """The device rest for one array: its q rows
     (outliers patched and 1-D inverses applied in-kernel), cut to the
     array's chunk width; higher-rank abs/rel rows arrive as deltas and
     take the multi-axis cumsum here."""
@@ -401,44 +282,30 @@ def _mega_q(q_rows, c):
 def decompress_batch(comps: Sequence, block_size: int,
                      offline: Codebook,
                      kernel_impl: str = "auto",
-                     bank=None, megakernel: bool = False) -> List[np.ndarray]:
+                     bank=None) -> List[np.ndarray]:
     """Fused decode of a group of CEAZCompressed streams.
 
-    All chunks of all arrays share ONE batched device pass: with
-    `megakernel` the `ceaz_chunk_dec` decode megakernel (walk + outlier
-    patch + inverse dual-quant in one kernel residency), otherwise the
-    split PR 3 path (hufdec walk, then per-array scatter + inverse
-    jits). `kernel_impl` selects the pass implementation through the
-    dispatch registry. Bank-mode chunks resolve their codebooks through
-    `bank` / the process bank registry (see
-    ``core.huffman.replay_codebooks``). Callers must pre-filter
+    All chunks of all arrays share ONE batched device pass, the
+    `ceaz_chunk_dec` decode megakernel (walk + outlier patch + inverse
+    dual-quant in one kernel residency). `kernel_impl` selects the pass
+    implementation through the dispatch registry. Bank-mode chunks
+    resolve their codebooks through `bank` / the process bank registry
+    (see ``core.huffman.replay_codebooks``). Callers must pre-filter
     eligibility with ``fused_decode_ok`` — the ``CEAZ.decompress_batch``
-    facade does. Both paths are bit-identical on everything
-    ``fused_decode_ok`` admits (tests/test_full_grid.py).
+    facade does. The output is bit-identical to the staged decoder on
+    everything ``fused_decode_ok`` admits (tests/test_full_grid.py).
     """
     batch = _ChunkBatch(block_size, kernel_impl)
     with ot.span("fused_decode.stage"):
         for c in comps:
             batch.add_comp(c, offline, bank=bank)
-        extra = [] if megakernel else [_split_host_args(c) for c in comps]
     if not batch.counts:
         return []
-    if megakernel:
-        q_all = batch.run_mega()
-        with ot.span("fused_decode.device_wait"):
-            qs = jax.block_until_ready(
-                [_mega_q(q_all[r0:r1], c)
-                 for c, (r0, r1) in zip(comps, batch.spans)])
-    else:
-        codes_all = batch.run()
-        with ot.span("fused_decode.h2d"):
-            extra = [to_device("decode", "fused_decode.h2d",
-                               *(jnp.asarray(a) for a in e))
-                     for e in extra]
-        with ot.span("fused_decode.device_wait"):
-            qs = jax.block_until_ready(
-                [_split_q(codes_all[r0:r1], c, *e)
-                 for c, (r0, r1), e in zip(comps, batch.spans, extra)])
+    q_all = batch.run()
+    with ot.span("fused_decode.device_wait"):
+        qs = jax.block_until_ready(
+            [_mega_q(q_all[r0:r1], c)
+             for c, (r0, r1) in zip(comps, batch.spans)])
     with ot.span("fused_decode.d2h"):
         qs = to_host("decode", "fused_decode.d2h", *qs)
     with ot.span("fused_decode.finish"):

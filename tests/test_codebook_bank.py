@@ -112,12 +112,10 @@ def test_bank_encode_is_one_pass(monkeypatch):
                 return run(*ra, **rkw)
             return counted
         return spying_pass
-    # either bank pass counts as THE pass: 1-D/value-direct shapes ride
-    # the ceaz_chunk megakernel, higher-rank Lorenzo the staged trace
+    # the one bank pass builder, whichever quantize front end the
+    # layout picks (ceaz_chunk rows or the global N-D pass)
     monkeypatch.setattr(fused, "_bank_pass_fn",
                         spy(fused._bank_pass_fn.__wrapped__))
-    monkeypatch.setattr(fused, "_mega_pass_fn",
-                        spy(fused._mega_pass_fn.__wrapped__))
     monkeypatch.setattr(fused, "_run_pass1",
                         lambda *a, **kw: forbidden.append("_run_pass1"))
     monkeypatch.setattr(fused, "_run_value_pass1",

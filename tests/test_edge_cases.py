@@ -128,26 +128,23 @@ def test_last_chunk_shorter_than_block(mode, kw, tail):
 
 
 # -- decode megakernel edges (PR 9) ------------------------------------------
-# `_check_pair` above already routes fused decode through the megakernel
-# (decode_megakernel defaults to 'auto'); this section pins the mega
-# route against BOTH oracles — the staged decoder and the PR 3 split
-# fused decode — exactly at the megakernel's own seams: degenerate
-# chunk grains, word-tile boundaries of the tiled walk regime, and
-# all-outlier chunks where every code is the escape symbol.
+# `_check_pair` above already routes fused decode through the megakernel;
+# this section pins it against the staged decoder exactly at the
+# megakernel's own seams: degenerate chunk grains, word-tile boundaries
+# of the tiled walk regime, and all-outlier chunks where every code is
+# the escape symbol.
 
 def _check_decode_edges(x, kernel_impl="jnp", **kw):
-    """One stream, three decode routes, byte-equal outputs."""
+    """One stream, staged and fused decode, byte-equal outputs."""
     staged, fused = _pair(kernel_impl=kernel_impl, **kw)
     c = fused.compress(x)
     want = staged._decompress_staged(c)
-    for dmk in ("split", "mega"):
-        comp = CEAZ(CEAZConfig(backend="jax", use_fused=True,
-                               kernel_impl=kernel_impl,
-                               decode_megakernel=dmk, **kw),
-                    offline_codebook=OFFLINE)
-        got = comp.decompress(c)
-        assert got.dtype == want.dtype and got.shape == want.shape
-        assert np.array_equal(want, got, equal_nan=True), dmk
+    comp = CEAZ(CEAZConfig(backend="jax", use_fused=True,
+                           kernel_impl=kernel_impl, **kw),
+                offline_codebook=OFFLINE)
+    got = comp.decompress(c)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert np.array_equal(want, got, equal_nan=True)
     return c, want
 
 

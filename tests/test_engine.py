@@ -547,27 +547,25 @@ def test_fuzz_truncation_at_every_section_boundary(tmp_path):
         assert np.abs(a - b).max() <= 1e-4 * (b.max() - b.min())
 
 
-# -- decode differential-fuzz fence: staged / fused / megakernel agree -------
+# -- decode differential-fuzz fence: staged / megakernel agree ---------------
 #
-# PR 9 adds a third decode route (the ceaz_chunk_dec megakernel). A
-# corrupted stream must be judged IDENTICALLY by all three — the fence
-# that keeps a route from silently decoding garbage the others reject.
+# A corrupted stream must be judged IDENTICALLY by both decode routes,
+# the staged host loop and the fused ceaz_chunk_dec megakernel — the
+# fence that keeps a route from silently decoding garbage the other
+# rejects.
 
 _CORPUS = os.path.join(os.path.dirname(__file__), "corpus",
                        "decode_fuzz_corpus.json")
 
 
 def _decode_impl_comps():
-    """The three decode routes every corrupted stream must judge
-    identically: staged (per-chunk host loop), fused split (the PR 3
-    stage-boundary ops) and the PR 9 decode megakernel."""
+    """The two decode routes every corrupted stream must judge
+    identically: staged (per-chunk host loop) and the fused decode
+    megakernel."""
     base = dict(mode="rel", eb=1e-4, adaptive=False, chunk_bytes=1 << 13)
     return [
         ("staged", CEAZ(CEAZConfig(use_fused=False, **base))),
-        ("split", CEAZ(CEAZConfig(use_fused=True,
-                                  decode_megakernel="split", **base))),
-        ("mega", CEAZ(CEAZConfig(use_fused=True,
-                                 decode_megakernel="mega", **base))),
+        ("mega", CEAZ(CEAZConfig(use_fused=True, **base))),
     ]
 
 
@@ -612,8 +610,8 @@ def _apply_corpus_case(data, records, case):
 def test_decode_differential_fuzz_fence(tmp_path):
     """Seed corpus + derandomized random flips: every mutation must get
     the SAME verdict ('corrupt', here — payload CRCs catch all of these)
-    from staged, fused-split and megakernel decode, and the pristine
-    stream must decode byte-identically through all three."""
+    from staged and megakernel decode, and the pristine stream must
+    decode byte-identically through both."""
     path, shards, comp = _ceaz_stream(tmp_path)
     with E.StreamReader(path) as r:
         records = list(r.records)

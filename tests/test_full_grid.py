@@ -211,26 +211,23 @@ def test_bank_megakernel_past_program_limit():
 
 
 # -- decode megakernel column -------------------------------------------------
-# PR 9: the read side has three routes — staged (the oracle), fused
-# 'split' (the PR 3 stage-boundary ops) and the ceaz_chunk_dec
-# megakernel (jnp twin / Pallas interpret). Every grid cell must decode
-# to the SAME BYTES through all of them, from the same stream.
+# The read side has two routes — staged (the oracle) and the fused
+# ceaz_chunk_dec megakernel (jnp twin / Pallas interpret). Every grid
+# cell must decode to the SAME BYTES through both, from the same stream.
 
-DECODE_ROUTES = [("jnp", "split"), ("jnp", "mega"), ("pallas", "mega")]
+DECODE_ROUTES = ["jnp", "pallas"]
 
 
 def _check_decode_routes(x, mode, kw, predictor, want, c):
-    for kernel_impl, dmk in DECODE_ROUTES:
+    for kernel_impl in DECODE_ROUTES:
         comp = CEAZ(CEAZConfig(mode=mode, predictor=predictor,
                                chunk_bytes=1 << 14, block_size=1024,
                                backend="jax", use_fused=True,
-                               kernel_impl=kernel_impl,
-                               decode_megakernel=dmk, **kw),
+                               kernel_impl=kernel_impl, **kw),
                     offline_codebook=OFFLINE)
         got = comp.decompress(c)
         assert got.dtype == want.dtype and got.shape == want.shape
-        assert np.array_equal(want, got), (mode, predictor, kernel_impl,
-                                           dmk)
+        assert np.array_equal(want, got), (mode, predictor, kernel_impl)
 
 
 @pytest.mark.parametrize("dtype", [np.float32, np.float64],
@@ -256,15 +253,6 @@ def test_decode_impl_grid_2d_lorenzo(mode, kw):
     c = enc.compress(x)
     _check_decode_routes(x, mode, kw, "lorenzo",
                          staged._decompress_staged(c), c)
-
-
-def test_unknown_decode_megakernel_raises():
-    comp = CEAZ(CEAZConfig(mode="abs", eb=1e-3, use_fused=True,
-                           decode_megakernel="warp"),
-                offline_codebook=OFFLINE)
-    c = comp.compress(np.ones(4096, np.float32))
-    with pytest.raises(ValueError, match="decode_megakernel"):
-        comp.decompress(c)
 
 
 # -- adaptive speculation -----------------------------------------------------

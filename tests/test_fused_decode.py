@@ -102,18 +102,12 @@ def test_batch_shares_one_decode_pass(offline_cb, monkeypatch):
     single batched Huffman-decode launch."""
     from repro.runtime import fused_decode as FD
     calls = []
-    orig_split, orig_mega = FD._ChunkBatch.run, FD._ChunkBatch.run_mega
+    orig = FD._ChunkBatch.run
 
-    def spy_split(self):
+    def spy(self):
         calls.append(len(self.counts))
-        return orig_split(self)
-
-    def spy_mega(self):
-        calls.append(len(self.counts))
-        return orig_mega(self)
-    # one launch total, whichever decode route is configured
-    monkeypatch.setattr(FD._ChunkBatch, "run", spy_split)
-    monkeypatch.setattr(FD._ChunkBatch, "run_mega", spy_mega)
+        return orig(self)
+    monkeypatch.setattr(FD._ChunkBatch, "run", spy)
     comp = CEAZ(CEAZConfig(mode="rel", eb=1e-4, use_fused=True,
                            chunk_bytes=1 << 15),
                 offline_codebook=offline_cb)

@@ -489,6 +489,35 @@ def test_ceaz_chunk_megakernel_vs_ref(predictor, cv, rng):
                                       err_msg=name)
 
 
+def test_bank_encode_back_end(rng):
+    """The bank back end shared by the `ceaz_chunk` twin and the N-D
+    bank pass: its histograms count each row's valid codes exactly (a
+    host bincount), `sel` is the argmin of hist . lengths_k, and the
+    pack is the same whichever `hufenc` implementation it is given."""
+    from repro.kernels.megakernel import ref as MR
+    C, cv = 3, 4096
+    codes2 = np.clip(rng.normal(512, 30, (C, cv)), 0, 1023).astype(np.int32)
+    valid2 = np.ones((C, cv), bool)
+    valid2[-1, cv - 777:] = False
+    codes2[~valid2] = 0
+    bl, bc = _bank_tables(rng)
+    w32 = 2 * ((int(bl.max()) * cv + 63) // 64 + 1)
+    outs = [MR.bank_encode(jnp.asarray(codes2), jnp.asarray(valid2),
+                           jnp.asarray(bl), jnp.asarray(bc), 1024, w32, 33,
+                           pack)
+            for pack in (ER.encode_pack,
+                         lambda *a: EO.encode_pack(*a, interpret=True))]
+    hists, sel, totals = (np.asarray(a) for a in outs[0][:3])
+    for i in range(C):
+        want = np.bincount(codes2[i][valid2[i]], minlength=1024)
+        np.testing.assert_array_equal(hists[i], want)
+    costs = hists.astype(np.int64) @ bl.T.astype(np.int64)
+    np.testing.assert_array_equal(sel, costs.argmin(axis=1))
+    np.testing.assert_array_equal(totals, costs.min(axis=1))
+    for a, b in zip(*outs):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_ceaz_chunk_dispatch_registration():
     """Both impls resolve through the registry; 'auto' picks the jnp
     twin on every backend (the Pallas megakernel does not compile for

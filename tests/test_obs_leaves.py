@@ -110,18 +110,18 @@ def load(request, tmp_path_factory):
     E.write_stream(path, [x], _comp())
     E.read_stream_arrays(path)                          # compile outside
     staged = []
-    orig = FD._ChunkBatch._stage_mega
+    orig = FD._ChunkBatch._stage
 
     def spy(self):
         out = orig(self)
         staged.append(out)
         return out
 
-    FD._ChunkBatch._stage_mega = spy
+    FD._ChunkBatch._stage = spy
     try:
         run = _traced(lambda: {"out": E.read_stream_arrays(path)})
     finally:
-        FD._ChunkBatch._stage_mega = orig
+        FD._ChunkBatch._stage = orig
     run.info.update(shape=shape, x=x, staged=staged, kind=request.param)
     return run
 

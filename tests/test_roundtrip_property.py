@@ -1,9 +1,8 @@
 """Property-based round-trip over the FULL compression matrix.
 
 Hypothesis draws jointly from mode x dtype(f32/f64) x predictor
-(lorenzo/none/auto) x kernel_impl(jnp/pallas-interpret) x decode route
-(split stage ops / ceaz_chunk_dec megakernel) x data kind, asserting
-for every example:
+(lorenzo/none/auto) x kernel_impl(jnp/pallas-interpret) x data kind,
+asserting for every example:
 
   * round-trip honors the error bound (staged reference decode);
   * staged and fused compression are bit-identical, field by field;
@@ -69,12 +68,9 @@ def cases(draw):
     predictor = draw(st.sampled_from(["lorenzo", "none", "auto"]))
     kernel_impl = draw(st.sampled_from(["jnp", "pallas"]))
     speculation = draw(st.sampled_from(["off", 2, "auto"]))
-    # PR 9: the decode-route axis — the split stage-boundary ops vs the
-    # ceaz_chunk_dec megakernel must be interchangeable everywhere
-    decode_megakernel = draw(st.sampled_from(["split", "mega"]))
     kw = dict(mode=mode, predictor=predictor, chunk_bytes=1 << 12,
               block_size=512, backend="jax", kernel_impl=kernel_impl,
-              speculation=speculation, decode_megakernel=decode_megakernel)
+              speculation=speculation)
     if mode == "fixed_ratio":
         kw["target_ratio"] = draw(st.sampled_from([6.0, 10.0]))
     else:

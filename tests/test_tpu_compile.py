@@ -118,10 +118,12 @@ def test_tpu_hacc_bank_pass_fits(one_chip):
     n = CHUNK
     k_literal = fused.literal_capacity(n, 1e-4, 1.0)
     assert k_literal == n // 16
-    run = fused._mega_pass_fn(
-        dispatch.auto_impl("ceaz_chunk", "tpu"), "lorenzo", 1, n, BLOCK,
-        fused._bank_w32(fused.BANK_PROVISION_BITS, n), 33,
-        fused._k_outlier(n), k_literal, True)
+    w32 = fused._bank_w32(fused.BANK_PROVISION_BITS, n)
+    lay = fused._BankLayout(
+        dispatch.auto_impl("ceaz_chunk", "tpu"), "lorenzo", 1, (n,), n, 1,
+        n, BLOCK, w32, w32, 33, fused._k_outlier(n), k_literal, True)
+    assert lay.mega                 # the ceaz_chunk row-halo front end
+    run = lay.pass_fn()
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     mem = run.lower(s((n,), jnp.float32), s((), jnp.float32),
                     s((BOOKS, 1024), jnp.int32),
